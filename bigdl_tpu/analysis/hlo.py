@@ -428,7 +428,10 @@ def collective_counts(program) -> Dict[str, Dict[str, int]]:
 
     Async ``-start`` forms count once under their base op (the ``-done``
     twin is never counted), including the tuple-typed result spelling
-    real TPU schedules emit. Accepts HLO text, a parsed
+    real TPU schedules emit. The TPU compiler writes a reduce-scatter
+    as a ``fusion`` that calls an ``all-reduce-scatter.N`` computation
+    (all-reduce + dynamic-slice inside): the fusion counts as one
+    ``reduce-scatter`` where it stands. Accepts HLO text, a parsed
     :class:`HloModule`, or a compiled program object."""
     module = _as_module(program)
     counts = {op: {"total": 0, "entry": 0} for op in COLLECTIVE_OPS}
@@ -436,6 +439,10 @@ def collective_counts(program) -> Dict[str, Dict[str, int]]:
         for op in comp.ops:
             base = op.opcode[:-6] if op.opcode.endswith("-start") \
                 else op.opcode
+            if base == "fusion" and (op.called or {}).get(
+                    "calls", "").lstrip("%").startswith(
+                        "all-reduce-scatter"):
+                base = "reduce-scatter"
             if base not in counts:
                 continue
             counts[base]["total"] += 1
@@ -445,9 +452,10 @@ def collective_counts(program) -> Dict[str, Dict[str, int]]:
 
 
 def reduce_scatter_evidence(counts: Dict[str, Dict[str, int]]) -> bool:
-    """True when the program reduce-scatters gradients: a literal
-    ``reduce-scatter`` op (TPU), or the CPU lowering's
-    all-reduce + dynamic-slice pair."""
+    """True when the program reduce-scatters gradients: a
+    ``reduce-scatter`` (literal, or the TPU compiler's fused
+    ``all-reduce-scatter``), or the CPU lowering's all-reduce +
+    dynamic-slice pair."""
     if counts["reduce-scatter"]["total"] > 0:
         return True
     return (counts["all-reduce"]["total"] > 0

@@ -3,7 +3,7 @@
 Builds and **lowers** (never executes) the package's representative
 compiled programs — train/eval steps, a ``steps_per_sync`` window, a
 ZeRO-2 step on the CPU mesh, a bf16-policy step, a sequence-parallel
-window (where ``jax.shard_map`` exists), and the generation
+window, and the generation
 prefill/decode pairs (single-shot and chunked-prefill engines) — into
 :class:`~bigdl_tpu.analysis.hlo.ProgramSpec`
 records the check registry runs over. ``python -m bigdl_tpu.tools.check
@@ -337,9 +337,8 @@ def _seq_parallel_window_spec(budget=None) -> Optional[ProgramSpec]:
     contract — the ring collectives (``collective-permute`` /
     ``all-to-all``, both in the entry-collective check's
     COMMUNICATION_OPS) trace inside the scan body, so the windowed
-    dispatch boundary stays collective-free. None (with a note) when
-    the process cannot run it: single device, or a jax build without
-    ``jax.shard_map``."""
+    dispatch boundary stays collective-free. None (with a note) in a
+    single-device process."""
     import jax
     from jax.sharding import PartitionSpec as P
 
@@ -348,10 +347,9 @@ def _seq_parallel_window_spec(budget=None) -> Optional[ProgramSpec]:
     from bigdl_tpu.optim.optimizer import (build_train_step,
                                            make_host_window)
     from bigdl_tpu.parallel import SeqParallelConfig, make_mesh
-    from bigdl_tpu.parallel.sequence import sequence_parallel_available
 
     ndev = min(len(jax.devices()), 8)
-    if ndev < 2 or not sequence_parallel_available():
+    if ndev < 2:
         return None
     mesh = make_mesh([ndev], ["seq"], jax.devices()[:ndev])
     model = _tiny_lm()
@@ -472,10 +470,10 @@ def enumerate_programs(hbm_budget: Optional[int] = None
     if sp is not None:
         specs.append(sp)
     else:
-        notes.append("seq-parallel window leg skipped (needs "
-                     "jax.shard_map and a multi-device process; the "
-                     "entry-collective contract for ring/Ulysses "
-                     "collectives is verified where both exist)")
+        notes.append("seq-parallel window leg skipped (needs a "
+                     "multi-device process; the entry-collective "
+                     "contract for ring/Ulysses collectives is "
+                     "verified where there is one)")
     specs.append(_serving_eval_spec(budget))
     specs.extend(_generation_specs(budget))
     return specs, notes

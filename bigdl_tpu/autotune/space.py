@@ -229,12 +229,6 @@ def _train_constraints(cfg: Dict[str, object], space: TrainSpace,
         if sp > ndev:
             return (f"seq_parallel={sp} needs a {sp}-device sequence "
                     f"mesh, process has {ndev}")
-        from bigdl_tpu.parallel.sequence import (
-            sequence_parallel_available)
-        if not sequence_parallel_available():
-            return (f"seq_parallel={sp} needs jax.shard_map, absent "
-                    f"in this jax build (the policy would quietly "
-                    f"no-op and measure the dense program twice)")
         if cfg["zero_stage"] > 0:
             return (f"seq_parallel={sp} with zero_stage="
                     f"{cfg['zero_stage']}: the default measure "

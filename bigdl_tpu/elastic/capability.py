@@ -1,15 +1,14 @@
 """Environment capability probes — ONE auditable reason per exclusion.
 
-Two long-standing tier-1 exclusions are environmental, not bugs: some
-jax builds lack ``jax.shard_map`` (the sequence/pipeline-parallel
-surface), and some CPU runtimes rendezvous fine but cannot EXECUTE
-cross-process collectives ("Multiprocess computations aren't
-implemented on the CPU backend"). Tests and the chaos host-kill leg
-used to discover these by crashing; these probes discover them ONCE,
-cache the verdict for the process, and hand back a precise reason
-string — so a skip reads "env: <exact missing capability>" instead of
-a stack trace, and a runtime that DOES support the surface runs the
-real tests with no code change.
+One long-standing tier-1 exclusion is environmental, not a bug: some
+CPU runtimes rendezvous fine but cannot EXECUTE cross-process
+collectives ("Multiprocess computations aren't implemented on the CPU
+backend"). Tests and the chaos host-kill leg used to discover this by
+crashing; the probe discovers it ONCE, caches the verdict for the
+process, and hands back a precise reason string — so a skip reads
+"env: <exact missing capability>" instead of a stack trace, and a
+runtime that DOES support the surface runs the real tests with no code
+change.
 """
 from __future__ import annotations
 
@@ -25,6 +24,7 @@ from typing import Tuple
 _PROBE_SRC = """\
 import sys
 import jax
+# the probe asks about the CPU backend, and the chip is the parent's
 jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=sys.argv[1],
                            num_processes=2, process_id=int(sys.argv[2]))
@@ -41,21 +41,6 @@ v = float(jax.device_get(y.addressable_shards[0].data))
 assert v == 2.0, v
 print("PROBE_OK")
 """
-
-
-def shard_map_available() -> bool:
-    """Whether this jax exposes ``jax.shard_map`` (the spelling the
-    ring/Ulysses/pipeline parallel layers compile through)."""
-    import jax
-    return hasattr(jax, "shard_map")
-
-
-def shard_map_reason() -> str:
-    """The precise skip reason when :func:`shard_map_available` is
-    False."""
-    import jax
-    return (f"env: jax {jax.__version__} has no jax.shard_map "
-            "(sequence/pipeline parallelism needs it)")
 
 
 @functools.lru_cache(maxsize=None)
@@ -106,4 +91,4 @@ def multiprocess_cpu(timeout_s: float = 120.0) -> Tuple[bool, str]:
                    f"collectives ({detail})")
 
 
-__all__ = ["multiprocess_cpu", "shard_map_available", "shard_map_reason"]
+__all__ = ["multiprocess_cpu"]

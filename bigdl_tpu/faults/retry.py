@@ -58,19 +58,41 @@ TRANSIENT_TYPES: Tuple[type, ...] = (
 )
 
 
+#: status codes of a ``jax.errors.JaxRuntimeError`` that say the
+#: PROGRAM is wrong or does not fit — a compile the TPU compiler
+#: refused, a device out of memory: the retry would replay it
+_FATAL_XLA_STATUS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT",
+                     "UNIMPLEMENTED", "FAILED_PRECONDITION")
+
+
+def _is_deterministic_xla_error(exc: BaseException) -> bool:
+    """A compile error or a device out-of-memory, as XLA reports them:
+    ``JaxRuntimeError`` is a ``RuntimeError`` (transient by type), but
+    its message leads with the status code."""
+    import jax
+
+    if not isinstance(exc, jax.errors.JaxRuntimeError):
+        return False
+    text = str(exc)
+    return text.startswith(_FATAL_XLA_STATUS) \
+        or "Mosaic failed to compile" in text
+
+
 def classify(exc: BaseException) -> str:
     """``"fatal"`` or ``"transient"`` for one exception.
 
     Fatal types win over transient ones (a ``NotImplementedError`` IS
     a ``RuntimeError``); an exception carrying ``bigdl_fatal = True``
     (e.g. ``CheckpointCorrupt`` escaping a quarantine-impossible
-    resume) is fatal regardless of its base class; unknown exception
+    resume) is fatal regardless of its base class, and so is an XLA
+    runtime error whose status says the program itself is at fault (a
+    refused compile, a device out of memory); unknown exception
     types default to transient — the reference retried everything, and
     a retry that re-raises is strictly more informative than a
     fast-fail on a recoverable blip."""
     if getattr(exc, "bigdl_fatal", False):
         return "fatal"
-    if isinstance(exc, FATAL_TYPES):
+    if isinstance(exc, FATAL_TYPES) or _is_deterministic_xla_error(exc):
         return "fatal"
     if isinstance(exc, TRANSIENT_TYPES):
         return "transient"

@@ -178,6 +178,10 @@ class ProcessReplica:
         self._seq = 0
         self._streams: Dict[int, TokenStream] = {}
         env = dict(os.environ)
+        # the chip is the parent's (a process that has touched jax
+        # holds it, and a child that wanted it would fail or hang): a
+        # subprocess replica serves from the CPU. Replicas that need
+        # chips are in-process ``Replica``s, one per device.
         env.setdefault("JAX_PLATFORMS", "cpu")
         if telemetry_dir:
             # the worker arms its flight recorder and ships identity-
@@ -348,6 +352,7 @@ def _worker(argv) -> int:
     args = ap.parse_args(argv)
 
     import jax
+    # the chip is the parent's — see ProcessReplica.__init__
     jax.config.update("jax_platforms", "cpu")
 
     from bigdl_tpu import telemetry

@@ -5,11 +5,11 @@ SURVEY.md §1); ours is a *runtime policy*: a :class:`KernelConfig`
 names which pallas kernels the dispatch layer
 (:mod:`bigdl_tpu.kernels.dispatch`) may select, everything else runs
 the pure-jnp reference path. The default is resolved lazily from the
-backend — **decode + int8 on on real TPU** (pure wins over work the
-reference cannot skip), **flash opt-in even there** (the measured
-einsum numbers in ``nn/attention`` still win at the lengths it can
-hold), **everything off on CPU** — and the ``BIGDL_KERNELS`` env var
-overrides it without touching code:
+backend — **decode + int8 on on real TPU** (they skip work the
+reference cannot skip), **flash opt-in even there** (XLA's fused einsum
+stays the default at the lengths it can hold until a measurement on
+today's code says otherwise — ROADMAP A5), **everything off on CPU** —
+and the ``BIGDL_KERNELS`` env var overrides it without touching code:
 
 - ``BIGDL_KERNELS=1`` / ``on`` / ``all`` — every kernel on;
 - ``BIGDL_KERNELS=0`` / ``off`` — every kernel off;
@@ -19,7 +19,11 @@ overrides it without touching code:
 ``interpret`` (``None`` = auto) runs the kernels through the pallas
 interpreter instead of Mosaic — auto means *interpret everywhere but
 real TPU*, which is how tier-1 on CPU executes the real kernel bodies
-(docs/kernels.md "Interpret-mode testing").
+(docs/kernels.md "Interpret-mode testing"). On a TPU backend nothing
+resolves to the interpreter unless ``KernelConfig(interpret=True)`` was
+passed explicitly, and the first resolution of the default policy logs
+the backend it found and whether kernels compile or interpret — a JAX
+that fell back to the CPU does not pass for a chip in silence.
 
 The active config is read at TRACE time: a compiled program bakes in
 the kernel choice that was active when it was built (the serving
@@ -29,6 +33,7 @@ an already-compiled program — build a fresh engine/service to switch).
 from __future__ import annotations
 
 import contextlib
+import logging
 import os
 import threading
 from dataclasses import dataclass
@@ -36,6 +41,8 @@ from typing import Iterator, Optional
 
 __all__ = ["KernelConfig", "configure", "get_config", "use", "enabled",
            "interpret_mode", "active_label"]
+
+logger = logging.getLogger("bigdl_tpu")
 
 #: the ops a config can enable, in the order the env parser accepts
 _OPS = ("flash", "decode", "int8")
@@ -61,8 +68,9 @@ class KernelConfig:
     block_k: int = 128
     #: compiled-mode VMEM working-set budget (MiB) for one flash
     #: program; ``None`` reads ``BIGDL_VMEM_BUDGET_MB`` and falls back
-    #: to the measured 12 MiB default (dispatch module docstring has
-    #: the budget math)
+    #: to the 12 MiB default — inside the 16 MiB of scoped VMEM the
+    #: v5e compiler grants one program (tests/test_chip_compile.py;
+    #: dispatch module docstring has the budget math)
     vmem_budget_mb: Optional[int] = None
     #: whether shapes past the VMEM budget route to the blockwise
     #: long-context flash kernel (key dimension tiled through VMEM)
@@ -73,8 +81,8 @@ class KernelConfig:
     def all_on(cls, **kw) -> "KernelConfig":
         """Every kernel enabled — ``BIGDL_KERNELS=1`` and the test/
         bench on-legs. (The real-TPU *default* is decode + int8 only;
-        flash stays opt-in there until the bench KERNELS trajectory
-        justifies the flip — see the module docstring.)"""
+        flash stays opt-in there until a measurement justifies the
+        flip — see the module docstring.)"""
         return cls(flash_attention=True, decode_attention=True,
                    int8_matmul=True, **kw)
 
@@ -146,19 +154,28 @@ _CONFIG: Optional[KernelConfig] = None  # None = resolve default lazily
 
 
 def _default() -> KernelConfig:
+    import jax
+    backend = jax.default_backend()
     env = os.environ.get("BIGDL_KERNELS")
     if env is not None:
-        return KernelConfig.from_env(env)
-    import jax
-    if jax.default_backend() == "tpu":
-        # decode + int8 are pure wins (they replace work the einsum
-        # path cannot skip); flash stays OPT-IN on TPU because the
-        # measured numbers in nn/attention (_FLASH_SCORE_BYTES note)
-        # show XLA's fused einsum winning wall-clock at every length
-        # it can hold — promote it via BIGDL_KERNELS=1/flash once the
-        # bench KERNELS trajectory on real TPU justifies the flip
-        return KernelConfig(decode_attention=True, int8_matmul=True)
-    return KernelConfig.off()
+        cfg = KernelConfig.from_env(env)
+    elif backend == "tpu":
+        # decode + int8 replace work the einsum path cannot skip;
+        # flash stays OPT-IN on TPU (XLA's fused einsum is the default
+        # at every length it can hold) — promote it via
+        # BIGDL_KERNELS=1/flash once a measurement justifies the flip
+        cfg = KernelConfig(decode_attention=True, int8_matmul=True)
+    else:
+        cfg = KernelConfig.off()
+    if cfg.any_enabled:
+        on = [op for op, flag in zip(_OPS, (cfg.flash_attention,
+                                            cfg.decode_attention,
+                                            cfg.int8_matmul)) if flag]
+        logger.info(
+            "kernel policy: %s on backend %r, %s", "+".join(on), backend,
+            "in the pallas INTERPRETER (no TPU backend)"
+            if cfg.resolve_interpret() else "compiled by Mosaic")
+    return cfg
 
 
 def get_config() -> KernelConfig:

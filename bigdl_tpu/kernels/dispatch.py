@@ -27,7 +27,7 @@ import jax.numpy as jnp
 
 import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu.kernels import config as _config
-from bigdl_tpu.kernels.common import fit_block
+from bigdl_tpu.kernels.common import fit_block, sublanes
 
 __all__ = ["attention", "decode_attention", "paged_decode_attention",
            "int8_matmul", "taken_in_thread"]
@@ -84,7 +84,7 @@ def _flash_vmem_bytes(q, block_q: int) -> int:
     the backward keeps jax.grad from OOMing at shapes the forward
     alone would have accepted."""
     s, d = q.shape[-2], q.shape[-1]
-    bq = fit_block(s, block_q)
+    bq = fit_block(s, block_q, align=sublanes(q.dtype))
     kv_inputs = 2 * s * d * q.dtype.itemsize
     kv_f32 = 2 * s * d * 4        # in-kernel f32 casts of K and V
     scratch = 2 * s * d * 4       # dK/dV accumulators

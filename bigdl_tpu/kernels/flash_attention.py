@@ -40,7 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from bigdl_tpu.kernels.common import fit_block, tpu_compiler_params
+from bigdl_tpu.kernels.common import (fit_block, sublanes,
+                                      tpu_compiler_params)
 
 __all__ = ["flash_attention", "blockwise_flash_attention", "fit_block"]
 
@@ -57,9 +58,31 @@ def _mask_for(i, block_q, s, causal, seg_q, seg_k):
         cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, s), 1)
         mask = cols <= rows
     if seg_q is not None:
-        seg = seg_q[:, None] == seg_k[None, :]
+        seg = seg_q == seg_k      # [bq, 1] column vs [1, S|bk] row
         mask = seg if mask is None else mask & seg
     return mask
+
+
+def _segment_planes(segment_ids):
+    """The ``[B, S]`` segment ids as the two operands the kernels
+    read: a ``[B, S, 1]`` column plane (query side) and a ``[B, 1, S]``
+    row plane (key side), so the same-segment mask is one 2-D
+    broadcast compare — Mosaic takes no rank-1 vector, and a block's
+    last two dims must be tile-aligned or the whole array's."""
+    seg = segment_ids.astype(jnp.int32)
+    return [seg[:, :, None], seg[:, None, :]]
+
+
+def _segment_specs(block_q, block_k, q_tile, k_tile):
+    """Block specs for :func:`_segment_planes`' two operands.
+    ``q_tile`` / ``k_tile`` pick the query / key tile index out of the
+    grid ids that follow ``(batch, head)``."""
+    return [
+        pl.BlockSpec((1, block_q, 1),
+                     lambda b_, h_, *ids: (b_, q_tile(*ids), 0)),
+        pl.BlockSpec((1, 1, block_k),
+                     lambda b_, h_, *ids: (b_, 0, k_tile(*ids))),
+    ]
 
 
 def _fwd_kernel(*refs, causal: bool, block_q: int, sm_scale: float,
@@ -90,8 +113,7 @@ def _fwd_kernel(*refs, causal: bool, block_q: int, sm_scale: float,
                               (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     o_ref[0, 0] = jnp.where(l > 0, acc / l, 0.0).astype(o_ref.dtype)
-    lse_ref[0, 0] = jnp.where(l[:, 0] > 0, m[:, 0] + jnp.log(l[:, 0]),
-                              _NEG_INF)
+    lse_ref[0, 0] = jnp.where(l > 0, m + jnp.log(l), _NEG_INF)
 
 
 def _bwd_kernel(*refs, causal: bool, block_q: int, sm_scale: float,
@@ -116,7 +138,7 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, sm_scale: float,
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)                   # [bq, D]
     o = o_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                                     # [bq]
+    lse = lse_ref[0, 0]                                     # [bq, 1]
     s = jax.lax.dot_general(q * sm_scale, k,
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -125,10 +147,10 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, sm_scale: float,
         s = jnp.where(mask, s, _NEG_INF)
     # softmax weights straight from the saved log-sum-exp; masked (and
     # fully-masked: -inf - -inf = nan) lanes zeroed exactly
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)
     if mask is not None:
         p = jnp.where(mask, p, 0.0)
-    p = jnp.where(jnp.isfinite(lse)[:, None], p, 0.0)
+    p = jnp.where(jnp.isfinite(lse), p, 0.0)
     dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -159,12 +181,9 @@ def _fwd_call(q, k, v, segment_ids, causal, sm_scale, block_q,
     ]
     args = [q, k, v]
     if segmented:
-        in_specs += [
-            pl.BlockSpec((1, block_q), lambda b_, h_, i: (b_, i)),
-            pl.BlockSpec((1, s), lambda b_, h_, i: (b_, 0)),
-        ]
-        args += [segment_ids.astype(jnp.int32),
-                 segment_ids.astype(jnp.int32)]
+        # the key side is the whole row: one block, index 0
+        in_specs += _segment_specs(block_q, s, lambda i: i, lambda i: 0)
+        args += _segment_planes(segment_ids)
     kernel = functools.partial(_fwd_kernel, causal=causal,
                                block_q=block_q, sm_scale=sm_scale,
                                segmented=segmented)
@@ -175,11 +194,12 @@ def _fwd_call(q, k, v, segment_ids, causal, sm_scale, block_q,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, h_, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, i: (b_, h_, i)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b_, h_, i: (b_, h_, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
@@ -198,16 +218,13 @@ def _bwd_call(q, k, v, o, do, lse, segment_ids, causal, sm_scale,
                         lambda b_, h_, i: (b_, h_, i, 0))
     full = pl.BlockSpec((1, 1, s, d), lambda b_, h_, i: (b_, h_, 0, 0))
     in_specs = [tile, full, full, tile, tile,
-                pl.BlockSpec((1, 1, block_q),
-                             lambda b_, h_, i: (b_, h_, i))]
+                pl.BlockSpec((1, 1, block_q, 1),
+                             lambda b_, h_, i: (b_, h_, i, 0))]
     args = [q, k, v, o, do, lse]
     if segmented:
-        in_specs += [
-            pl.BlockSpec((1, block_q), lambda b_, h_, i: (b_, i)),
-            pl.BlockSpec((1, s), lambda b_, h_, i: (b_, 0)),
-        ]
-        args += [segment_ids.astype(jnp.int32),
-                 segment_ids.astype(jnp.int32)]
+        # the key side is the whole row: one block, index 0
+        in_specs += _segment_specs(block_q, s, lambda i: i, lambda i: 0)
+        args += _segment_planes(segment_ids)
     kernel = functools.partial(_bwd_kernel, causal=causal,
                                block_q=block_q, sm_scale=sm_scale,
                                segmented=segmented, q_tiles=q_tiles)
@@ -244,15 +261,21 @@ def _flash(q, k, v, segment_ids, causal, sm_scale, block_q, interpret):
 
 def _flash_fwd(q, k, v, segment_ids, causal, sm_scale, block_q,
                interpret):
+    # the kernels read and write the log-sum-exp as a [B, H, S, 1]
+    # column (a (block_q, 1) tile is a legal TPU block; a rank-1
+    # (block_q,) strip of [B, H, S] is not), but a trailing dim of 1
+    # pads to 128 lanes in HBM — so the saved residual is the compact
+    # [B, H, S] and the backward re-expands it
     out, lse = _fwd_call(q, k, v, segment_ids, causal, sm_scale,
                          block_q, interpret)
-    return out, (q, k, v, out, lse, segment_ids)
+    return out, (q, k, v, out, lse[..., 0], segment_ids)
 
 
 def _flash_bwd(causal, sm_scale, block_q, interpret, res, g):
     q, k, v, out, lse, segment_ids = res
-    dq, dk, dv = _bwd_call(q, k, v, out, g, lse, segment_ids, causal,
-                           sm_scale, block_q, interpret)
+    dq, dk, dv = _bwd_call(q, k, v, out, g, lse[..., None],
+                           segment_ids, causal, sm_scale, block_q,
+                           interpret)
     return dq, dk, dv, None
 
 
@@ -297,7 +320,7 @@ def _tile_mask(i, j, block_q, block_k, causal, seg_q, seg_k):
             jnp.int32, (block_q, block_k), 1)
         mask = cols <= rows
     if seg_q is not None:
-        seg = seg_q[:, None] == seg_k[None, :]
+        seg = seg_q == seg_k      # [bq, 1] column vs [1, S|bk] row
         mask = seg if mask is None else mask & seg
     return mask
 
@@ -362,8 +385,7 @@ def _bw_fwd_kernel(*refs, causal: bool, block_q: int, block_k: int,
         l = jnp.max(l_acc[...], axis=-1, keepdims=True)
         o_ref[0, 0] = jnp.where(l > 0, acc[...] / l, 0.0) \
             .astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l[:, 0] > 0,
-                                  m[:, 0] + jnp.log(l[:, 0]), _NEG_INF)
+        lse_ref[0, 0] = jnp.where(l > 0, m + jnp.log(l), _NEG_INF)
 
 
 def _bw_fwd_call(q, k, v, segment_ids, causal, sm_scale, block_q,
@@ -384,12 +406,9 @@ def _bw_fwd_call(q, k, v, segment_ids, causal, sm_scale, block_q,
     ]
     args = [q, k, v]
     if segmented:
-        in_specs += [
-            pl.BlockSpec((1, block_q), lambda b_, h_, i, j: (b_, i)),
-            pl.BlockSpec((1, block_k), lambda b_, h_, i, j: (b_, j)),
-        ]
-        args += [segment_ids.astype(jnp.int32),
-                 segment_ids.astype(jnp.int32)]
+        in_specs += _segment_specs(block_q, block_k,
+                                   lambda i, j: i, lambda i, j: j)
+        args += _segment_planes(segment_ids)
     kernel = functools.partial(_bw_fwd_kernel, causal=causal,
                                block_q=block_q, block_k=block_k,
                                sm_scale=sm_scale, segmented=segmented,
@@ -401,12 +420,12 @@ def _bw_fwd_call(q, k, v, segment_ids, causal, sm_scale, block_q,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, h_, i, j: (b_, h_, i)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
                         pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
@@ -442,7 +461,7 @@ def _bw_dq_kernel(*refs, causal: bool, block_q: int, block_k: int,
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
         o = o_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]                                 # [bq]
+        lse = lse_ref[0, 0]                                 # [bq, 1]
         s = jax.lax.dot_general(q * sm_scale, k,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -451,10 +470,10 @@ def _bw_dq_kernel(*refs, causal: bool, block_q: int, block_k: int,
             s = jnp.where(mask, s, _NEG_INF)
         # exact per-lane softmax weights from the saved log-sum-exp —
         # no rescaling in the backward, each tile's p is final
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
-        p = jnp.where(jnp.isfinite(lse)[:, None], p, 0.0)
+        p = jnp.where(jnp.isfinite(lse), p, 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         delta = jnp.sum(do * o, axis=-1, keepdims=True)     # [bq, 1]
@@ -502,10 +521,10 @@ def _bw_dkv_kernel(*refs, causal: bool, block_q: int, block_k: int,
         mask = _tile_mask(i, j, block_q, block_k, causal, seg_q, seg_k)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
-        p = jnp.where(jnp.isfinite(lse)[:, None], p, 0.0)
+        p = jnp.where(jnp.isfinite(lse), p, 0.0)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -534,18 +553,15 @@ def _bw_bwd_call(q, k, v, o, do, lse, segment_ids, causal, sm_scale,
                           lambda b_, h_, i, j: (b_, h_, i, 0))
     k_tile = pl.BlockSpec((1, 1, block_k, d),
                           lambda b_, h_, i, j: (b_, h_, j, 0))
-    lse_tile = pl.BlockSpec((1, 1, block_q),
-                            lambda b_, h_, i, j: (b_, h_, i))
-    seg = [] if not segmented else [segment_ids.astype(jnp.int32),
-                                    segment_ids.astype(jnp.int32)]
+    lse_tile = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b_, h_, i, j: (b_, h_, i, 0))
+    seg = _segment_planes(segment_ids) if segmented else []
 
     # pass 1 — dq: query tile outer, key tiles stream innermost
     in_specs = [q_tile, k_tile, k_tile, q_tile, q_tile, lse_tile]
     if segmented:
-        in_specs += [
-            pl.BlockSpec((1, block_q), lambda b_, h_, i, j: (b_, i)),
-            pl.BlockSpec((1, block_k), lambda b_, h_, i, j: (b_, j)),
-        ]
+        in_specs += _segment_specs(block_q, block_k,
+                                   lambda i, j: i, lambda i, j: j)
     dq = pl.pallas_call(
         functools.partial(_bw_dq_kernel, causal=causal,
                           block_q=block_q, block_k=block_k,
@@ -567,14 +583,12 @@ def _bw_bwd_call(q, k, v, o, do, lse, segment_ids, causal, sm_scale,
                            lambda b_, h_, j, i: (b_, h_, i, 0))
     k_tile2 = pl.BlockSpec((1, 1, block_k, d),
                            lambda b_, h_, j, i: (b_, h_, j, 0))
-    lse_tile2 = pl.BlockSpec((1, 1, block_q),
-                             lambda b_, h_, j, i: (b_, h_, i))
+    lse_tile2 = pl.BlockSpec((1, 1, block_q, 1),
+                             lambda b_, h_, j, i: (b_, h_, i, 0))
     in_specs = [q_tile2, k_tile2, k_tile2, q_tile2, q_tile2, lse_tile2]
     if segmented:
-        in_specs += [
-            pl.BlockSpec((1, block_q), lambda b_, h_, j, i: (b_, i)),
-            pl.BlockSpec((1, block_k), lambda b_, h_, j, i: (b_, j)),
-        ]
+        in_specs += _segment_specs(block_q, block_k,
+                                   lambda j, i: i, lambda j, i: j)
     dk, dv = pl.pallas_call(
         functools.partial(_bw_dkv_kernel, causal=causal,
                           block_q=block_q, block_k=block_k,
@@ -620,15 +634,15 @@ def _blockwise_fwd(q, k, v, segment_ids, causal, sm_scale, block_q,
                    block_k, interpret):
     out, lse = _bw_fwd_call(q, k, v, segment_ids, causal, sm_scale,
                             block_q, block_k, interpret)
-    return out, (q, k, v, out, lse, segment_ids)
+    return out, (q, k, v, out, lse[..., 0], segment_ids)
 
 
 def _blockwise_bwd(causal, sm_scale, block_q, block_k, interpret, res,
                    g):
     q, k, v, out, lse, segment_ids = res
-    dq, dk, dv = _bw_bwd_call(q, k, v, out, g, lse, segment_ids,
-                              causal, sm_scale, block_q, block_k,
-                              interpret)
+    dq, dk, dv = _bw_bwd_call(q, k, v, out, g, lse[..., None],
+                              segment_ids, causal, sm_scale, block_q,
+                              block_k, interpret)
     return dq, dk, dv, None
 
 
@@ -653,8 +667,11 @@ def blockwise_flash_attention(q, k, v, segment_ids=None, *,
     s, d = q.shape[-2], q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    block_q = fit_block(s, block_q)
-    block_k = fit_block(s, block_k)
+    # the query tile is a row block of q/o/lse, the key tile is both a
+    # row block of k/v and the LANE extent of the score tile and of
+    # the key-side segment row — hence the two alignments
+    block_q = fit_block(s, block_q, align=sublanes(q.dtype))
+    block_k = fit_block(s, block_k, align=128)
     return _blockwise(q, k, v, segment_ids, bool(causal),
                       float(sm_scale), int(block_q), int(block_k),
                       bool(interpret))
@@ -676,6 +693,6 @@ def flash_attention(q, k, v, segment_ids=None, *, causal: bool = False,
     s, d = q.shape[-2], q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    block_q = fit_block(s, block_q)
+    block_q = fit_block(s, block_q, align=sublanes(q.dtype))
     return _flash(q, k, v, segment_ids, bool(causal), float(sm_scale),
                   int(block_q), bool(interpret))

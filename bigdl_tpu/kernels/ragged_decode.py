@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from bigdl_tpu.kernels.common import fit_block
+from bigdl_tpu.kernels.common import fit_block, sublanes
 
 __all__ = ["ragged_decode_attention"]
 
@@ -35,10 +35,10 @@ _NEG_INF = float("-inf")
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *,
-                   block_k: int, sm_scale: float):
+                   block_k: int, k_tiles: int, sm_scale: float):
     slot = pl.program_id(0)
     n = len_ref[slot]                                   # valid KV rows
-    q = q_ref[0, 0].reshape(1, -1).astype(jnp.float32) * sm_scale
+    q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # [1, D]
 
     def body(i, carry):
         m, l, acc = carry
@@ -67,9 +67,15 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *,
     m0 = jnp.full((1, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, 1), jnp.float32)
     acc0 = jnp.zeros((1, d), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, pl.cdiv(n, block_k), body,
-                                  (m0, l0, acc0))
-    o_ref[0, 0] = (acc / l)[0].astype(o_ref.dtype)
+    if k_tiles == 1:
+        # the bucket is one tile (1 <= n <= T always): a static slice —
+        # the compiler cannot prove a dynamic row offset aligned when
+        # T is below a vector tile
+        _, l, acc = body(0, (m0, l0, acc0))
+    else:
+        _, l, acc = jax.lax.fori_loop(0, pl.cdiv(n, block_k), body,
+                                      (m0, l0, acc0))
+    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
 
 
 def ragged_decode_attention(q, k, v, lengths, *, sm_scale: float = None,
@@ -90,20 +96,24 @@ def ragged_decode_attention(q, k, v, lengths, *, sm_scale: float = None,
                          f"[{slots},{h},{t},{d}]")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    block_k = fit_block(t, block_k)
+    # K/V tiles are sliced at a dynamic row offset inside the kernel,
+    # so the tile is a whole number of vector tiles (or all of T)
+    block_k = fit_block(t, block_k, align=sublanes(k.dtype))
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, t)
     kernel = functools.partial(_decode_kernel, block_k=block_k,
+                               k_tiles=t // block_k,
                                sm_scale=float(sm_scale))
-    return pl.pallas_call(
+    # q and the output travel as [slots, H, 1, D]: Mosaic wants the
+    # last two dims of a block to be (8, 128)-aligned or the whole
+    # array's, and a (1, D) tile of a [.., 1, D] array is the latter
+    row = pl.BlockSpec((1, 1, 1, d), lambda s, h_: (s, h_, 0, 0))
+    full = pl.BlockSpec((1, 1, t, d), lambda s, h_: (s, h_, 0, 0))
+    out = pl.pallas_call(
         kernel,
         grid=(slots, h),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, d), lambda s, h_: (s, h_, 0)),
-            pl.BlockSpec((1, 1, t, d), lambda s, h_: (s, h_, 0, 0)),
-            pl.BlockSpec((1, 1, t, d), lambda s, h_: (s, h_, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda s, h_: (s, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((slots, h, d), q.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), row, full, full],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((slots, h, 1, d), q.dtype),
         interpret=interpret,
-    )(lengths, q, k, v)
+    )(lengths, q[:, :, None, :], k, v)
+    return out[:, :, 0, :]

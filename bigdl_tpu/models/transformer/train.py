@@ -278,4 +278,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from bigdl_tpu.utils.engine import enable_compile_cache
+
+    enable_compile_cache()
     main()

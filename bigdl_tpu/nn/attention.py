@@ -19,36 +19,27 @@ from bigdl_tpu.utils.engine import Engine
 
 
 def _flash_attention_tpu(q, k, v, causal: bool):
-    """Pallas flash attention — O(S) memory, no materialized [S,S] score
-    matrix (the pallas-kernel fast path the reference's BigQuant C++
-    played for its hot ops). Returns None when the kernel is absent or
-    rejects the shapes at TRACE time; a Mosaic failure at jit-compile
-    time surfaces to the caller (pass use_flash=False to bypass)."""
-    try:
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            flash_attention)
-    except Exception:
-        return None
-    d = q.shape[-1]
-    try:
-        return flash_attention(q, k, v, causal=causal,
-                               sm_scale=1.0 / math.sqrt(d))
-    except Exception:
-        return None  # shape/platform not supported by the kernel
+    """jax's bundled pallas flash attention — O(S) memory, no
+    materialized [S,S] score matrix. Which shapes it takes is
+    ``_flash_eligible``'s decision, made before the call; whatever the
+    kernel or the TPU compiler then raises reaches the caller."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        flash_attention)
+
+    return flash_attention(q, k, v, causal=causal,
+                           sm_scale=1.0 / math.sqrt(q.shape[-1]))
 
 
-# Route to the pallas flash kernel when the materialized [S,S] score
-# matrix would not comfortably fit HBM. Measured on v5e-1 (bf16, H=8,
-# D=128): XLA's fused einsum BEATS the flash kernel on wall-clock at
-# every length it can compile (S=2048: 13.5 vs 14.1 ms; 4096: 25.5 vs
-# 31.8; 8192: 30.5 vs 41.9; 16384: 60.6 vs 77.4) and dies at S=32768
-# (scores alone 8.6 GB) where flash runs fine (191 ms) — so the kernel
-# is a MEMORY escape hatch, not a speedup, and the router keys on bytes.
+# Route to the bundled flash kernel when the materialized [S,S] score
+# matrix would not comfortably fit HBM: XLA's fused einsum is the
+# default at every length it can hold, so the kernel is a MEMORY escape
+# hatch and the router keys on bytes. (Which of the two is faster at
+# which length on today's code is not measured — ROADMAP A5.)
 _FLASH_SCORE_BYTES = 2 << 30
 
 
 def _flash_eligible(q, mask, dropout_rate, training) -> bool:
-    if q.ndim < 4:  # the kernel needs [B,H,S,D]; lower ranks use einsum
+    if q.ndim != 4:  # the kernel needs [B,H,S,D]; other ranks use einsum
         return False
     b, h, seq, d = q.shape[-4], q.shape[-3], q.shape[-2], q.shape[-1]
     scores_bytes = b * h * seq * seq * q.dtype.itemsize
@@ -81,8 +72,7 @@ def dot_product_attention(q, k, v, *, causal: bool = False, mask=None,
     On TPU with kernels off, sequences whose score matrix would bust
     HBM still route to jax's bundled flash kernel (O(S) memory);
     everything else uses the einsum form, which XLA fuses onto the MXU
-    and — measured on v5e — wins wall-clock at every length it can
-    hold (see _FLASH_SCORE_BYTES).
+    (see _FLASH_SCORE_BYTES).
     """
     d = q.shape[-1]
     if mask is not None and segments is not None:
@@ -105,12 +95,9 @@ def dot_product_attention(q, k, v, *, causal: bool = False, mask=None,
         # site, bitwise the mask the packed model used to build itself
         seg = segments.astype(jnp.int32)
         mask = seg[:, None, :, None] == seg[:, None, None, :]
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if (use_flash and on_tpu
+    if (use_flash and jax.default_backend() == "tpu"
             and _flash_eligible(q, mask, dropout_rate, training)):
-        out = _flash_attention_tpu(q, k, v, causal)
-        if out is not None:
-            return out
+        return _flash_attention_tpu(q, k, v, causal)
     # softmax is a sanctioned f32 island under every precision policy:
     # the QK contraction accumulates f32 on the MXU
     # (preferred_element_type costs nothing) and the exp/normalize run

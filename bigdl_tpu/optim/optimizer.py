@@ -289,8 +289,7 @@ def build_train_step(module: Module, criterion: Criterion,
     ``MultiHeadAttention`` without an explicit ``ring_axis`` runs the
     ring/Ulysses kernel over the config's mesh axis. Like ``zero``,
     the policy no-ops quietly (dense attention, degree gauge reads 1)
-    when it cannot apply — no shard_map in this jax build, no mesh, or
-    the axis missing/size-1. The SP collectives trace INSIDE the step,
+    when it cannot apply — no mesh, or the axis missing/size-1. The SP collectives trace INSIDE the step,
     so under ``set_steps_per_sync(K)`` they land inside the scan body
     and the windowed dispatch boundary stays collective-free; ZeRO
     composes orthogonally (weights shard over the data axis, attention

@@ -8,8 +8,7 @@ from bigdl_tpu.parallel.ring_attention import (
 from bigdl_tpu.parallel.ulysses import (
     ulysses_attention, ulysses_attention_sharded)
 from bigdl_tpu.parallel.sequence import (
-    SeqParallelConfig, active_sequence_parallel,
-    sequence_parallel_available, use_sequence_parallel)
+    SeqParallelConfig, active_sequence_parallel, use_sequence_parallel)
 from bigdl_tpu.parallel.tp import (
     shard_params, shard_opt_state_zero1, spec_for, tree_shardings,
     validate_rules)

@@ -65,11 +65,7 @@ def spmd_pipeline(block_fn: Callable, stage_params, x, *,
 
     buf0 = jnp.zeros(x.shape[1:], x.dtype)
     out0 = jnp.zeros_like(x)
-    if hasattr(jax.lax, "pcast"):
-        buf0, out0 = jax.lax.pcast((buf0, out0), (axis_name,),
-                                   to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        buf0, out0 = jax.lax.pvary((buf0, out0), (axis_name,))
+    buf0, out0 = jax.lax.pcast((buf0, out0), (axis_name,), to="varying")
 
     def step(carry, t):
         buf, out, aux = carry
@@ -94,10 +90,7 @@ def spmd_pipeline(block_fn: Callable, stage_params, x, *,
         return (y, out, aux), None
 
     aux0 = jnp.zeros((), jnp.float32)
-    if hasattr(jax.lax, "pcast"):
-        aux0 = jax.lax.pcast(aux0, (axis_name,), to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        aux0 = jax.lax.pvary(aux0, (axis_name,))
+    aux0 = jax.lax.pcast(aux0, (axis_name,), to="varying")
     (_, out, aux), _ = jax.lax.scan(step, (buf0, out0, aux0),
                                     jnp.arange(m + n_stages - 1))
     # replicate the last stage's outputs to every shard
@@ -159,12 +152,8 @@ def spmd_pipeline_interleaved(block_fn: Callable, stage_params, x, *,
     out0 = jnp.zeros_like(x)
     queue0 = jnp.zeros_like(x)  # stage-0 re-entry waiting room
     aux0 = jnp.zeros((), jnp.float32)
-    if hasattr(jax.lax, "pcast"):
-        buf0, out0, queue0, aux0 = jax.lax.pcast(
-            (buf0, out0, queue0, aux0), (axis_name,), to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        buf0, out0, queue0, aux0 = jax.lax.pvary(
-            (buf0, out0, queue0, aux0), (axis_name,))
+    buf0, out0, queue0, aux0 = jax.lax.pcast(
+        (buf0, out0, queue0, aux0), (axis_name,), to="varying")
 
     def step(carry, t):
         buf, queue, out, aux = carry

@@ -75,10 +75,7 @@ def ring_attention(q, k, v, *, axis_name: str, causal: bool = False,
     # packing is off) become shard-varying inside the scan; mark the
     # (constant) initial values as such for the vma type check
     varying = (m0, l0, o0) if has_seg else (m0, l0, o0, seg0)
-    if hasattr(jax.lax, "pcast"):
-        varying = jax.lax.pcast(varying, (axis_name,), to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        varying = jax.lax.pvary(varying, (axis_name,))
+    varying = jax.lax.pcast(varying, (axis_name,), to="varying")
     if has_seg:
         m0, l0, o0 = varying
     else:

@@ -19,8 +19,7 @@ a *policy* the train step installs:
   ``MultiHeadAttention`` without an explicit ``ring_axis`` adopts it;
 - like ``ZeroConfig``, the policy is a NO-OP when it cannot apply
   (:meth:`SeqParallelConfig.active_on`): no mesh, axis missing or size
-  1, or a jax build without ``jax.shard_map`` — the dense path runs
-  and the exported ``train/seq_parallel/degree`` gauge says 1.
+  1 — the dense path runs and the exported ``train/seq_parallel/degree`` gauge says 1.
 
 Composition story (docs/performance.md "Long context"): the SP
 collectives live INSIDE the traced step, so under
@@ -41,22 +40,14 @@ from typing import Iterator, Optional
 import bigdl_tpu.telemetry as telemetry
 
 __all__ = ["SeqParallelConfig", "use_sequence_parallel",
-           "active_sequence_parallel", "sequence_parallel_available"]
+           "active_sequence_parallel"]
 
 #: the axis sizes the active policy actually achieved — 1 means SP is
-#: off or could not apply (no mesh / missing axis / no shard_map), so
+#: off or could not apply (no mesh / missing axis), so
 #: a dashboard reads the degree it is paying for, not the one asked for
 _G_DEGREE = telemetry.gauge(
     "train/seq_parallel/degree",
     "active sequence-parallel mesh degree (1 = dense attention)")
-
-
-def sequence_parallel_available() -> bool:
-    """Whether this jax build can run the SP kernels at all
-    (``jax.shard_map`` — probed by ``bigdl_tpu.elastic.capability``,
-    the same gate tier-1 skips ring/Ulysses tests on)."""
-    from bigdl_tpu.elastic.capability import shard_map_available
-    return shard_map_available()
 
 
 @dataclass(frozen=True)
@@ -87,18 +78,15 @@ class SeqParallelConfig:
     def degree(self) -> int:
         """The sequence-shard count the policy achieves on the
         resolved mesh (1 = it will not apply)."""
-        mesh = self.resolve_mesh() if sequence_parallel_available() \
-            else None
+        mesh = self.resolve_mesh()
         return int(mesh.shape[self.axis]) if mesh is not None else 1
 
     def active_on(self, mesh=None) -> bool:
-        """Whether the policy applies: shard_map present AND the axis
-        splits >1 ways on the resolved mesh. Mirrors
+        """Whether the policy applies: the axis splits >1 ways on the
+        resolved mesh. Mirrors
         ``ZeroConfig.active_on`` — an inapplicable policy is a quiet
         no-op, not an error, so one training script serves every
         topology."""
-        if not sequence_parallel_available():
-            return False
         if mesh is not None and self.mesh is None:
             from bigdl_tpu.parallel.mesh import resolve_axis_mesh
             return resolve_axis_mesh(mesh, self.axis) is not None

@@ -373,6 +373,7 @@ def _run_worker(args) -> int:
     result line. Exit 0 on completion — or death by injected SIGKILL,
     which the parent observes as rc -9."""
     import jax
+    # the chip is the parent's: chaos children train on the CPU
     jax.config.update("jax_platforms", "cpu")
 
     from bigdl_tpu import faults
@@ -394,6 +395,8 @@ def _spawn_worker(model: str, seed: int, batch_size: int, steps: int,
                   timeout_s: float = 600.0):
     import subprocess
     env = dict(os.environ)
+    # the chip is the parent's (one process per chip): the child that
+    # gets killed and relaunched runs on the CPU
     env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [sys.executable, "-m", "bigdl_tpu.tools.chaos", "--worker",
            "--model", model, "--seed", str(seed),
@@ -420,6 +423,7 @@ def _run_hostkill_worker(args) -> int:
         from bigdl_tpu.utils.engine import Engine
         Engine.init_distributed(initialization_timeout=120)
     else:
+        # the chip is the parent's: a one-process gang trains on the CPU
         jax.config.update("jax_platforms", "cpu")
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

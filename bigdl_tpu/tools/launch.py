@@ -171,6 +171,10 @@ def _launch_gang(args, coord: str, attempt: int) -> List[_Worker]:
             env["BIGDL_FLIGHT_DIR"] = os.path.join(
                 args.ship_telemetry, "flight")
         if args.cpu_devices:
+            # a chip belongs to ONE process: N workers on one host
+            # cannot each open it, so a local gang runs on virtual CPU
+            # devices (on a pod, one worker per host owns that host's
+            # chips and this flag stays off)
             env["JAX_PLATFORMS"] = "cpu"
             env["XLA_FLAGS"] = (
                 env.get("XLA_FLAGS", "")

@@ -52,7 +52,7 @@ __all__ = ["KNOWN_SCHEMA_VERSIONS", "extract_metrics", "classify_key",
            "judge", "main"]
 
 #: bench.py schema versions this sentinel understands; version 1 is
-#: the implicit pre-schema_version format of BENCH_r01–r05
+#: the implicit pre-schema_version format (records without the key)
 KNOWN_SCHEMA_VERSIONS = (1, 2)
 
 _HIGHER_MARKS = ("per_sec", "per_chip", "mfu", "achieved_tfs",

@@ -31,8 +31,8 @@ def measure_sync_compare(build_chunk: Callable, carry,
     import jax.numpy as jnp
 
     def fetch(losses):
-        # a VALUE fetch, not just readiness: on tunneled backends
-        # readiness can signal before execution completes
+        # a VALUE fetch, not just readiness: the value cannot exist
+        # before the execution that computes it has completed
         return float(jnp.sum(jnp.asarray(losses).astype(jnp.float32)))
 
     out: Dict[str, float] = {}

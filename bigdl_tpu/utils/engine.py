@@ -17,6 +17,31 @@ import jax.numpy as jnp
 import numpy as np
 
 
+#: the checkout's root — where the compile cache lives when nobody
+#: placed it from outside
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory. For command-line entry points only — never on
+    ``import bigdl_tpu`` and never under pytest.
+
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside: JAX
+    reads the variable itself, so this touches nothing. Unset, the
+    cache goes to ``<checkout>/.jax_cache``. The directory is part of
+    the cache key's neighbourhood — a path that moved (a temp dir, a
+    pid, a date) never hits — so it is fixed, and no other code sets
+    one."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 class Engine:
     """Process-global runtime config: devices, mesh, dtype policy.
 

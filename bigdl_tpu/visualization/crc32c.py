@@ -42,13 +42,21 @@ def _crc_py(data: bytes) -> int:
     return crc32c(data)
 
 
-_crc_impl = _crc_py
+def _crc_first(data: bytes) -> int:
+    """The first checksum picks the implementation — importing this
+    module opens no library."""
+    _try_native()
+    return _crc_impl(data)
+
+
+_crc_impl = _crc_first
 
 
 def _try_native():
-    """Swap in the C++ crc32c when the .so is ALREADY built (never compile
-    on this path) and verify it actually works before binding it."""
+    """Bind the C++ crc32c when the .so is ALREADY built (never compile
+    on this path) and verified to work; the pure-Python one otherwise."""
     global _crc_impl
+    _crc_impl = _crc_py
     try:
         from bigdl_tpu import native
         if native.load_library(build=False) is None:
@@ -58,6 +66,3 @@ def _try_native():
         _crc_impl = native.native_crc32c
     except Exception:
         pass
-
-
-_try_native()

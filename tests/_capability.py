@@ -1,26 +1,16 @@
 """Environment capability gates for tier-1 (not itself a pytest file).
 
-The two long-standing env exclusions — jax builds without
-``jax.shard_map`` and CPU runtimes that cannot EXECUTE cross-process
-collectives — used to surface as 35 identical crash-shaped failures.
-They are environmental, not bugs, so they now route through the ONE
-probe helper (``bigdl_tpu.elastic.capability``): each excluded test
-skips with the precise, auditable reason, and a runtime that DOES
-support the surface runs the real tests unchanged. The probe result is
-cached per process, so the multiprocess probe's two-process gang runs
-at most once per pytest session.
+CPU runtimes that cannot EXECUTE cross-process collectives used to
+surface as identical crash-shaped failures. That is environmental, not
+a bug, so it routes through the ONE probe helper
+(``bigdl_tpu.elastic.capability``): each excluded test skips with the
+precise, auditable reason, and a runtime that DOES support the surface
+runs the real tests unchanged. The probe result is cached per process,
+so its two-process gang runs at most once per pytest session.
 """
 import pytest
 
-from bigdl_tpu.elastic.capability import (multiprocess_cpu,
-                                          shard_map_available,
-                                          shard_map_reason)
-
-#: decorator for tests that compile through jax.shard_map (ring /
-#: Ulysses sequence parallelism, pipeline parallelism)
-shard_map_skip = pytest.mark.skipif(
-    not shard_map_available(),
-    reason=shard_map_reason() if not shard_map_available() else "")
+from bigdl_tpu.elastic.capability import multiprocess_cpu
 
 
 def require_multiprocess_cpu() -> None:

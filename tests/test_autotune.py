@@ -97,24 +97,21 @@ def test_constraints_reject_with_reasons():
 
 def test_static_prune_rejects_infeasible_with_zero_compiles():
     """The footprint gate is eval_shape-only: the deliberately
-    oversized smoke batch is rejected before ANY XLA compilation."""
-    from jax._src import compiler
+    oversized smoke batch is rejected before ANY XLA compilation (and
+    the counter that says so is shown to see a compile that happens)."""
+    import jax
+    import numpy as np
+    from _compile_counter import count_compiles
+
     valid, _ = enumerate_candidates(smoke_train_space())
-    calls = []
-    orig = compiler.backend_compile
-
-    def counting(*a, **k):
-        calls.append(1)
-        return orig(*a, **k)
-
-    compiler.backend_compile = counting
-    try:
+    with count_compiles() as calls:
         report = static_prune(valid,
                               hbm_budget=SMOKE_HBM_BUDGET_BYTES,
                               contract_checks=False)
-    finally:
-        compiler.backend_compile = orig
     assert calls == [], f"static prune compiled {len(calls)} programs"
+    with count_compiles() as forced:
+        jax.jit(lambda x: x * 5 - 2)(np.ones(7, np.float32))
+    assert len(forced) >= 1
     assert {p.candidate.config["batch_size"] for p in report.pruned} \
         == {INFEASIBLE_BATCH}
     assert {c.config["batch_size"] for c in report.kept} == {16}

@@ -42,16 +42,9 @@ def test_verify_programs_self_gate(suite):
     assert "serving/transformer_lm/verify/16" in names
     # conftest forces 8 virtual devices, so the mesh leg must be there
     assert "train/mlp/zero2/step" in names, notes
-    # the seq-parallel window leg additionally needs jax.shard_map —
-    # on builds without it the skip is announced, never silent
-    from bigdl_tpu.elastic.capability import shard_map_available
-    if shard_map_available():
-        assert "train/transformer_lm/seq_parallel/window@k2" in names, \
-            notes
-        assert notes == []
-    else:
-        assert [n for n in notes
-                if "seq-parallel window leg skipped" not in n] == []
+    # ... and so must the seq-parallel window leg
+    assert "train/transformer_lm/seq_parallel/window@k2" in names, notes
+    assert notes == []
     # every donated program's contract was non-trivial
     donated = [s for s in specs if s.donated > 0]
     assert len(donated) >= 6

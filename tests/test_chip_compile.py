@@ -1,0 +1,358 @@
+"""Ask the TPU compiler, without a TPU.
+
+libtpu is installed in the sandbox and compiles for a chip that is
+*described*, not attached (``jax.experimental.topologies``). These tests
+compile — never run — the kernels of the main path at the shapes
+``chip_smoke.py`` uses, plus the jitted decode step and the LM train
+steps, for a ``v5e:2x2``. What interpret mode cannot see shows here:
+block shapes Mosaic refuses, unaligned dynamic slices, VMEM a kernel may
+not have, collectives the partitioner did not put in.
+
+Rules of this file (on-chip-measurement guide, section 2): the topology
+is described inside a module-scoped fixture that skips when it cannot
+be, never at import; shardings and shapes are built in fixtures or
+tests; every compile runs in the test's own process; the persistent
+compilation cache is off around them; and all of it is ONE file, because
+only one process at a time may hold the TPU library.
+
+Code that asks ``jax.default_backend()`` sees the CPU here, so the tests
+steer it with ``kernels.use(KernelConfig(..., interpret=False))``.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+# the smoke's widths (GPT-2 small) — depth is cut for the whole-step
+# compiles, widths are not
+SLOTS, HEADS, HEAD_DIM, MAX_LEN = 16, 12, 64, 1024
+LM = dict(vocab=50257, hidden=768, layers=2, heads=12, ffn=3072,
+          positions=1024, seq=1024, batch=8)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or it is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without one: keep it off here."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_persistent_cache):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo, no_persistent_cache):
+    return Mesh(np.array(topo.devices).reshape(4), ("data",))
+
+
+def _on(sharding, *shape_dtypes):
+    return [jax.ShapeDtypeStruct(s, np.dtype(d), sharding=sharding)
+            for s, d in shape_dtypes]
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+def _grad_of(attn):
+    def loss(q, k, v, *seg):
+        return attn(q, k, v, *seg).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+# ------------------------------------------------------------ kernels
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t", [1, 2, 4, 8, 16, 32, 64, 128, 256, 512,
+                               1024])
+def test_ragged_decode_compiles_at_ladder_rungs(one_chip, t, dtype):
+    """The default TPU decode path at every rung of the powers-of-two
+    ladder up to ``max_len`` 1024 — below one vector tile, one tile,
+    several tiles."""
+    from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
+
+    q, k, v, lengths = _on(
+        one_chip, ((SLOTS, HEADS, HEAD_DIM), dtype),
+        ((SLOTS, HEADS, t, HEAD_DIM), dtype),
+        ((SLOTS, HEADS, t, HEAD_DIM), dtype), ((SLOTS,), "int32"))
+    assert _has_kernel(_compile(ragged_decode_attention, q, k, v,
+                                lengths))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_ragged_decode_compiles_at_head_dim_128(one_chip, dtype):
+    from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
+
+    q, k, v, lengths = _on(
+        one_chip, ((16, 8, 128), dtype), ((16, 8, 512, 128), dtype),
+        ((16, 8, 512, 128), dtype), ((16,), "int32"))
+    assert _has_kernel(_compile(ragged_decode_attention, q, k, v,
+                                lengths))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("segmented", [False, True])
+def test_flash_attention_compiles_fwd_and_grad(one_chip, segmented,
+                                               dtype):
+    """The full-row flash kernel at the LM train shape of the smoke."""
+    from bigdl_tpu.kernels.flash_attention import flash_attention
+
+    shape = (8, 12, 1024, 64)
+    args = _on(one_chip, (shape, dtype), (shape, dtype), (shape, dtype))
+    if segmented:
+        args += _on(one_chip, ((8, 1024), "int32"))
+
+    def attn(q, k, v, *seg):
+        return flash_attention(q, k, v, *seg, causal=True)
+
+    assert _has_kernel(_compile(attn, *args))
+    assert _has_kernel(_compile(_grad_of(attn), *args))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(8, 12, 1024, 64),
+                                   (1, 8, 8192, 128)])
+def test_blockwise_flash_compiles_fwd_and_grad(one_chip, shape, dtype):
+    from bigdl_tpu.kernels.flash_attention import (
+        blockwise_flash_attention)
+
+    args = _on(one_chip, (shape, dtype), (shape, dtype), (shape, dtype),
+               ((shape[0], shape[2]), "int32"))
+
+    def attn(q, k, v, seg):
+        return blockwise_flash_attention(q, k, v, seg, causal=True)
+
+    assert _has_kernel(_compile(attn, *args))
+    assert _has_kernel(_compile(_grad_of(attn), *args))
+
+
+def test_full_row_flash_is_refused_past_vmem_and_dispatch_knows(one_chip):
+    """At ``[1, 8, 8192, 128]`` the full-row kernel asks for more
+    scoped VMEM than a v5e program may have — the compiler says so —
+    and the dispatch layer's estimate routes that shape to the
+    blockwise kernel instead, which compiles, forward and grad."""
+    from bigdl_tpu import kernels
+    from bigdl_tpu.kernels import KernelConfig
+    from bigdl_tpu.kernels.flash_attention import flash_attention
+
+    shape = (1, 8, 8192, 128)
+    args = _on(one_chip, (shape, "float32"), (shape, "float32"),
+               (shape, "float32"))
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                 *args)
+
+    def attn(q, k, v):
+        out = kernels.attention(q, k, v, causal=True)
+        assert out is not None, "dispatch declined an eligible shape"
+        return out
+
+    with kernels.use(KernelConfig(flash_attention=True,
+                                  interpret=False)):
+        assert _has_kernel(_compile(attn, *args))
+        assert _has_kernel(_compile(_grad_of(attn), *args))
+
+
+def test_flash_vmem_budget_is_inside_what_the_compiler_takes(one_chip):
+    """The largest shapes the 12 MiB estimate still hands to the
+    full-row kernel compile (backward included): the estimate errs on
+    the safe side of the compiler's 16 MiB scoped limit."""
+    from bigdl_tpu.kernels import dispatch
+    from bigdl_tpu.kernels.flash_attention import flash_attention
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    for shape, dtype in (((1, 2, 3072, 64), "float32"),
+                         ((1, 2, 2048, 128), "float32"),
+                         ((1, 2, 2560, 128), "bfloat16")):
+        q = jax.ShapeDtypeStruct(shape, np.dtype(dtype))
+        assert dispatch._flash_vmem_bytes(q, 128) <= 12 << 20
+        args = _on(one_chip, (shape, dtype), (shape, dtype),
+                   (shape, dtype))
+        assert _has_kernel(_compile(_grad_of(attn), *args))
+
+
+def test_int8_gemm_compiles(one_chip):
+    from bigdl_tpu.kernels.int8_gemm import pallas_quantized_matmul
+
+    args = _on(one_chip, ((256, 512), "int8"), ((1024, 512), "int8"),
+               ((256, 1), "float32"), ((1024,), "float32"))
+    assert _has_kernel(_compile(
+        lambda x, w, xs, ws: pallas_quantized_matmul(x, w, xs, ws),
+        *args))
+
+
+def test_bundled_flash_escape_hatch_compiles(one_chip):
+    """``nn.attention`` routes HBM-busting score matrices to jax's
+    bundled flash kernel on a TPU; ``_flash_eligible`` decides the
+    shapes, nothing catches what the kernel raises."""
+    from bigdl_tpu.nn.attention import (_flash_attention_tpu,
+                                        _flash_eligible)
+
+    shape = (1, 8, 16384, 128)
+    q, k, v = _on(one_chip, (shape, "bfloat16"), (shape, "bfloat16"),
+                  (shape, "bfloat16"))
+    assert _flash_eligible(q, None, 0.0, False)
+    assert _has_kernel(_compile(
+        lambda q, k, v: _flash_attention_tpu(q, k, v, True), q, k, v))
+
+
+def test_paged_decode_is_refused(one_chip):
+    """The record for ``paged_decode`` (no caller outside ``kernels/``,
+    ROADMAP C2): the TPU compiler refuses its ``(1, 1, d)`` blocks, in
+    these words. Nothing was spent on repairing it."""
+    from bigdl_tpu.kernels.paged_decode import paged_decode_attention
+
+    args = _on(one_chip, ((16, 12, 64), "float32"),
+               ((128, 12, 128, 64), "float32"),
+               ((128, 12, 128, 64), "float32"), ((16, 8), "int32"),
+               ((16,), "int32"))
+    with pytest.raises(Exception,
+                       match="last two dimensions of your block shape"):
+        _compile(paged_decode_attention, *args)
+
+
+# ------------------------------------------------- whole jitted steps
+
+@pytest.fixture(scope="module")
+def lm():
+    from bigdl_tpu.models import TransformerLM
+    from bigdl_tpu.utils.random import RandomGenerator
+
+    RandomGenerator.set_seed(0)
+    model = TransformerLM(LM["vocab"], hidden_size=LM["hidden"],
+                          num_layers=LM["layers"], num_heads=LM["heads"],
+                          ffn_size=LM["ffn"], max_len=LM["positions"],
+                          tie_embeddings=True)
+    model.ensure_initialized()
+    return model
+
+
+def test_decode_step_holds_the_kernel(one_chip, lm):
+    """The engine's own decode program for the top rung, at the policy a
+    TPU gets by default (decode + int8 on, compiled, no interpreter):
+    its compiled text must contain the Mosaic kernel."""
+    from bigdl_tpu import kernels
+    from bigdl_tpu.analysis.programs import abstract_tree
+    from bigdl_tpu.generation.engine import DecodeEngine
+    from bigdl_tpu.kernels import KernelConfig
+    from bigdl_tpu.serving.compile_cache import (BucketLadder,
+                                                 CompileCache)
+
+    engine = DecodeEngine(CompileCache(), BucketLadder(MAX_LEN), SLOTS, 4)
+    lm.evaluate()
+    programs = engine.abstract_programs(
+        lm, abstract_tree(lm.get_parameters()),
+        abstract_tree(lm.get_state()))
+    (decode,) = [p for p in programs if p[0] == f"decode/{MAX_LEN}"]
+    _, jitted, args = decode
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), args)
+    with kernels.use(KernelConfig(decode_attention=True,
+                                  int8_matmul=True, interpret=False)):
+        before = kernels.dispatch.taken_in_thread()
+        compiled = jitted.lower(*args).compile()
+        assert kernels.dispatch.taken_in_thread() - before == LM["layers"]
+    assert _has_kernel(compiled)
+
+
+def _train_step_args(lm, optim, policy, replicated, batch_sharding,
+                     opt_state_shardings=None):
+    """Abstract arguments of the LM train step: everything on
+    ``replicated`` but the batch and, where given, the optimizer state
+    (``opt_state_shardings`` maps its tree to a tree of shardings)."""
+    from bigdl_tpu.analysis.programs import _key_struct, _train_abstract
+
+    lm.training()
+    params, opt_state, mstate = _train_abstract(lm, optim, policy)
+
+    def place(tree, shardings=None):
+        if shardings is None:
+            shardings = jax.tree.map(lambda _: replicated, tree)
+        return jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sh),
+            tree, shardings)
+
+    tokens = jax.ShapeDtypeStruct((LM["batch"], LM["seq"]), np.int32,
+                                  sharding=batch_sharding)
+    return (place(params),
+            place(opt_state, opt_state_shardings
+                  and opt_state_shardings(opt_state)),
+            place(mstate), place(_key_struct()),
+            jax.ShapeDtypeStruct((), np.float32, sharding=replicated),
+            tokens, tokens)
+
+
+def test_lm_train_step_compiles_and_fits(one_chip, lm):
+    """The smoke's LM train step (bf16 mixed, Adam) for one chip."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.optim import Adam
+    from bigdl_tpu.optim.optimizer import build_train_step
+    from bigdl_tpu.precision import PrecisionPolicy
+
+    policy, optim = PrecisionPolicy.named("bf16_mixed"), Adam(3e-4)
+    args = _train_step_args(lm, optim, policy, one_chip, one_chip)
+    step = build_train_step(lm, nn.SequenceCrossEntropyCriterion(),
+                            optim, precision=policy)
+    mem = step.lower(*args).compile().memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 << 30)
+
+
+def test_zero2_step_on_four_chips_has_its_collectives(four_chips, lm):
+    """``chip_smoke.py --chips 4``'s step, compiled for a four-chip
+    ``data`` mesh: the gradient reduce-scatter (the TPU compiler's fused
+    ``all-reduce-scatter``) and the parameter all-gather are in it."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.analysis.hlo import collective_counts
+    from bigdl_tpu.optim import Adam
+    from bigdl_tpu.optim.optimizer import build_train_step
+    from bigdl_tpu.parallel import ZeroConfig
+    from bigdl_tpu.parallel.zero import tree_zero_specs
+    from bigdl_tpu.precision import PrecisionPolicy
+
+    mesh, cfg = four_chips, ZeroConfig(stage=2)
+    policy, optim = PrecisionPolicy.named("bf16_mixed"), Adam(3e-4)
+
+    def zero2(opt_state):
+        return jax.tree.map(lambda sp: NamedSharding(mesh, sp),
+                            tree_zero_specs(opt_state, mesh, cfg))
+
+    args = _train_step_args(lm, optim, policy, NamedSharding(mesh, P()),
+                            NamedSharding(mesh, P("data")), zero2)
+    step = build_train_step(lm, nn.SequenceCrossEntropyCriterion(),
+                            optim, zero=cfg, mesh=mesh, precision=policy)
+    counts = collective_counts(step.lower(*args).compile())
+    assert counts["reduce-scatter"]["total"] > 0, counts
+    assert counts["all-gather"]["total"] > 0, counts

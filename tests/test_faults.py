@@ -153,6 +153,26 @@ def test_classify_honors_the_bigdl_fatal_marker():
     assert classify(CheckpointCorrupt("bad digest")) == "fatal"
 
 
+@pytest.mark.parametrize("message, verdict", [
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm", "fatal"),
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+     "allocating on stack", "fatal"),
+    ("INTERNAL: Mosaic failed to compile TPU kernel: cannot statically "
+     "prove that index in dimension 2 is a multiple of 2", "fatal"),
+    ("INVALID_ARGUMENT: Executable expected shape f32[8]", "fatal"),
+    ("UNAVAILABLE: connection reset by peer", "transient"),
+    ("INTERNAL: stream did not block host until done", "transient"),
+])
+def test_classify_xla_compile_errors_and_oom_fail_at_once(message,
+                                                          verdict):
+    """A refused compile or a device out-of-memory replays identically:
+    the optimizer's retry loop must raise the first diagnostic, not
+    back off and try again. Environmental XLA errors stay retryable."""
+    import jax
+
+    assert classify(jax.errors.JaxRuntimeError(message)) == verdict
+
+
 def test_backoff_doubles_to_cap_with_equal_jitter():
     import random
     rng = random.Random(0)

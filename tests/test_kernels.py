@@ -101,6 +101,22 @@ class TestKernelConfig:
         assert fit_block(48, 16) == 16
         assert fit_block(19, 16) == 1  # prime: one query per tile
 
+    def test_fit_block_aligned_to_vector_tiles(self):
+        """Tiles the TPU compiler takes: a multiple of the alignment
+        that divides the dim, else the WHOLE dim — never something in
+        between (a 125-row tile of 1000 is a dynamic slice Mosaic
+        cannot prove aligned)."""
+        from bigdl_tpu.kernels.common import sublanes
+        assert fit_block(1024, 128, align=8) == 128
+        assert fit_block(1000, 128, align=8) == 40
+        assert fit_block(1000, 128, align=16) == 1000   # 16 ∤ any divisor
+        assert fit_block(19, 16, align=8) == 19         # prime: one tile
+        assert fit_block(4, 128, align=8) == 4          # below a tile
+        assert fit_block(8192, 128, align=128) == 128
+        assert fit_block(48, 128, align=128) == 48
+        assert (sublanes("float32"), sublanes("bfloat16"),
+                sublanes("int8")) == (8, 16, 32)
+
 
 # ----------------------------------------------------- flash attention
 
@@ -472,6 +488,33 @@ class TestRaggedDecode:
                                         axis=-1), v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=0)
+
+    @pytest.mark.parametrize("t, dtype", [
+        (1, "bfloat16"),      # one row: the static single-tile path
+        (100, "float32"),     # no aligned divisor: one whole-T tile
+        (1000, "float32"),    # tiles of 40 rows under a dynamic bound
+        (96, "bfloat16"),     # bf16 rows pack 16 to a tile: tiles of 96
+    ])
+    def test_tpu_legal_tiles_match_reference(self, t, dtype):
+        """The tiles the TPU compiler takes (whole vector tiles or the
+        whole of T) give the same answers as the masked reference."""
+        slots, h, d = 3, 2, 8
+        r = np.random.default_rng(13)
+        q = jnp.asarray(r.standard_normal((slots, h, d)), dtype)
+        k = jnp.asarray(r.standard_normal((slots, h, t, d)), dtype)
+        v = jnp.asarray(r.standard_normal((slots, h, t, d)), dtype)
+        lengths = jnp.asarray(np.array([1, max(1, t // 2), t], np.int32))
+        out = ragged_decode_attention(q, k, v, lengths, interpret=True)
+        assert out.shape == (slots, h, d) and out.dtype == q.dtype
+        f32 = lambda a: a.astype(jnp.float32)
+        s = jnp.einsum("shd,shtd->sht", f32(q), f32(k)) / math.sqrt(d)
+        mask = jnp.arange(t)[None, None, :] < lengths[:, None, None]
+        ref = jnp.einsum("sht,shtd->shd",
+                         jax.nn.softmax(jnp.where(mask, s, -jnp.inf),
+                                        axis=-1), f32(v))
+        np.testing.assert_allclose(
+            np.asarray(f32(out)), np.asarray(ref), rtol=0,
+            atol=1e-5 if dtype == "float32" else 2e-2)
 
     def test_dispatch_shapes_and_toggle(self):
         r = np.random.default_rng(12)

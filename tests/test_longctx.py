@@ -7,9 +7,9 @@ chunk boundary, because chunking is a dispatch-shape decision, not a
 numeric one. Paged decode must be token-identical to the contiguous
 ragged kernel for any page table naming the same rows. The SP policy
 (``SeqParallelConfig``) must be a quiet no-op wherever it cannot apply
-(this CPU build has no ``jax.shard_map``), leaving the dense program
+(no mesh, or no such axis on it), leaving the dense program
 bit-identical; the sharded equivalence tests live in
-tests/test_parallel.py behind ``shard_map_skip``.
+tests/test_parallel.py.
 """
 import numpy as np
 import pytest
@@ -258,20 +258,14 @@ def test_seq_parallel_config_validation_and_context():
     assert active_sequence_parallel() is None
 
 
-def test_seq_parallel_noop_without_shard_map_or_mesh():
-    """Without ``jax.shard_map`` (this build) or a resolvable mesh the
-    policy reports inactive and degree 1 — ``ZeroConfig.active_on``'s
-    quiet-no-op contract."""
-    import jax
-    from bigdl_tpu.parallel import (SeqParallelConfig,
-                                    sequence_parallel_available)
+def test_seq_parallel_noop_without_mesh():
+    """Without a resolvable mesh the policy reports inactive and
+    degree 1 — ``ZeroConfig.active_on``'s quiet-no-op contract."""
+    from bigdl_tpu.parallel import SeqParallelConfig
 
     cfg = SeqParallelConfig(axis="nonexistent_axis")
     assert not cfg.active_on(None)
     assert cfg.degree() == 1
-    if not hasattr(jax, "shard_map"):
-        assert not sequence_parallel_available()
-        assert not SeqParallelConfig(axis="seq").active_on(None)
 
 
 def test_build_train_step_seq_parallel_noop_is_bitwise_dense():

@@ -3,7 +3,6 @@ Test.scala mains) — each recipe must run end-to-end with --synthetic."""
 import numpy as np
 import pytest
 
-from _capability import shard_map_skip
 
 
 def test_lenet_train_cli(tmp_path):
@@ -212,7 +211,6 @@ def test_transformer_train_cli():
     assert model is not None
 
 
-@shard_map_skip
 def test_transformer_train_cli_pp_tp():
     import jax
     if len(jax.devices()) < 8:
@@ -226,7 +224,6 @@ def test_transformer_train_cli_pp_tp():
     assert model is not None
 
 
-@shard_map_skip
 def test_transformer_train_cli_sp_ring():
     import jax
     if len(jax.devices()) < 8:

@@ -5,7 +5,7 @@ per-request trace propagation (queue-wait + prefill + per-token decode
 spans on a linked track, asserted on exported JSON), the crash flight
 recorder (WorkerDied and fatal-optimizer bundles that
 ``diagnose --postmortem`` ingests; disarmed = one flag check), the
-bench regression sentinel (checked-in BENCH_r01–r05 passes, a
+bench regression sentinel (a steady five-point trajectory passes, a
 synthetic 20% drop fails, unknown schema refused), and the exporter
 edge cases the new series exercise."""
 import glob
@@ -18,8 +18,6 @@ import pytest
 
 from bigdl_tpu import telemetry
 from bigdl_tpu.telemetry import flight, programs
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -478,14 +476,26 @@ class TestFlightRecorder:
 # ------------------------------------------------ regression sentinel
 
 class TestRegressionSentinel:
-    def _trajectory(self):
-        return sorted(glob.glob(os.path.join(_ROOT, "BENCH_r*.json")))
+    def _trajectory(self, tmp_path):
+        """A five-point trajectory in the driver's wrapper format
+        (``{"parsed": <bench line>}``, pre-``schema_version`` like the
+        first records were): made-up values with ~1% jitter."""
+        paths = []
+        for i, value in enumerate((1000.0, 1012.0, 991.0, 1006.0,
+                                   1002.0), 1):
+            path = tmp_path / f"BENCH_r{i:02d}.json"
+            path.write_text(json.dumps({"n": i, "rc": 0, "parsed": {
+                "metric": "resnet50_imagenet_train_imgs_per_sec_per_chip",
+                "value": value, "unit": "images/sec",
+                "vs_baseline": round(value / 50.0, 3)}}))
+            paths.append(str(path))
+        return paths
 
-    def test_checked_in_trajectory_passes(self):
-        """Acceptance: the banked BENCH_r01–r05 trajectory exits 0."""
+    def test_steady_trajectory_passes(self, tmp_path):
+        """Acceptance: a steady five-point trajectory exits 0."""
         from bigdl_tpu.tools.regress import main
 
-        paths = self._trajectory()
+        paths = self._trajectory(tmp_path)
         assert len(paths) >= 5
         assert main(paths) == 0
 
@@ -493,7 +503,7 @@ class TestRegressionSentinel:
         """Acceptance: a 20% throughput drop exits 1."""
         from bigdl_tpu.tools.regress import main
 
-        paths = self._trajectory()
+        paths = self._trajectory(tmp_path)
         with open(paths[-1]) as f:
             parsed = json.load(f)["parsed"]
         bad = dict(parsed, value=parsed["value"] * 0.8,
@@ -544,7 +554,7 @@ class TestRegressionSentinel:
         cand = tmp_path / "cand.json"
         cand.write_text(json.dumps({"schema_version": 99, "value": 1}))
         with pytest.raises(SystemExit) as exc:
-            main(self._trajectory() + ["--candidate", str(cand)])
+            main(self._trajectory(tmp_path) + ["--candidate", str(cand)])
         assert exc.value.code == 2
         assert "schema_version" in capsys.readouterr().err
 

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _capability import shard_map_skip
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import bigdl_tpu.nn as nn
@@ -23,7 +22,6 @@ def devices8():
     return d[:8]
 
 
-@shard_map_skip
 def test_ring_attention_matches_full(devices8):
     rng = np.random.RandomState(0)
     B, H, S, D = 2, 4, 64, 16
@@ -37,7 +35,6 @@ def test_ring_attention_matches_full(devices8):
                                    atol=2e-5)
 
 
-@shard_map_skip
 def test_ring_attention_grad_matches(devices8):
     rng = np.random.RandomState(1)
     B, H, S, D = 1, 2, 32, 8
@@ -141,7 +138,6 @@ def test_dp_tp_train_step(devices8):
     assert params["block_0"]["attn"]["wq"].sharding.spec == P(None, "model")
 
 
-@shard_map_skip
 def test_sp_ring_train_step(devices8):
     """Sequence-parallel training: mesh (data=2, seq=4), ring attention
     inside shard_map, gradients match the unsharded reference."""
@@ -300,7 +296,6 @@ def test_pretrained_child_adopted_in_all_composites():
             np.asarray(td.get_parameters()["layer"]["weight"]), wi)
 
 
-@shard_map_skip
 def test_pipeline_parallel_matches_sequential(devices8):
     """GPipe pipeline over 4 stages == sequential layer application."""
     from bigdl_tpu.parallel import pipeline_forward
@@ -324,7 +319,6 @@ def test_pipeline_parallel_matches_sequential(devices8):
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
 
 
-@shard_map_skip
 def test_pipeline_parallel_grad_flows(devices8):
     from bigdl_tpu.parallel import pipeline_forward
     mesh = make_mesh([4], ["pipe"], devices8[:4])
@@ -406,7 +400,6 @@ def test_flash_routing_is_memory_keyed():
     assert not _flash_eligible(odd, None, 0.0, False)
 
 
-@shard_map_skip
 def test_ulysses_attention_matches_full():
     """All-to-all sequence parallelism: seq-sharded qkv re-shard to
     head-sharded, full attention per head group, shard back — exact
@@ -444,7 +437,6 @@ def test_ulysses_rejects_indivisible_heads():
         np.asarray(ulysses_attention_sharded(q, q, q, mesh))
 
 
-@shard_map_skip
 def test_pipeline_is_differentiable_for_training():
     """PP is training-capable, not a forward-only primitive: gradients
     through the microbatched ppermute pipeline match the dense stack's
@@ -485,7 +477,6 @@ def test_pipeline_is_differentiable_for_training():
     assert float(pp_loss(ws2)) < float(pp_loss(ws))
 
 
-@shard_map_skip
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_segments_match_dense(devices8, causal):
     """Packed segment masks survive the ring rotation: key-side ids
@@ -505,7 +496,6 @@ def test_ring_attention_segments_match_dense(devices8, causal):
                                atol=2e-5)
 
 
-@shard_map_skip
 @pytest.mark.parametrize("causal", [False, True])
 def test_ulysses_attention_segments_match_dense(devices8, causal):
     """Ulysses all-gathers the id row after the head re-shard; the
@@ -526,7 +516,6 @@ def test_ulysses_attention_segments_match_dense(devices8, causal):
                                atol=2e-5)
 
 
-@shard_map_skip
 def test_ring_segments_jit_grad_matches_dense(devices8):
     """jit(grad) through the segment-masked ring — the custom-VJP +
     ppermute composition the train step actually runs."""
@@ -545,7 +534,6 @@ def test_ring_segments_jit_grad_matches_dense(devices8):
                                atol=2e-5)
 
 
-@shard_map_skip
 def test_mha_adopts_seq_parallel_policy(devices8):
     """A plain MHA (no ring_axis) adopts the installed train-step
     policy: under ``use_sequence_parallel`` on a live seq mesh the

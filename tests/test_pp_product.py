@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _capability import shard_map_skip
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import bigdl_tpu.nn as nn
@@ -53,7 +52,6 @@ def test_pipelined_lm_dense_fallback_forward():
     assert np.isfinite(logits).all()
 
 
-@shard_map_skip
 def test_pipelined_lm_pp_matches_dense(devices8):
     """Pipelined forward AND grads must equal the sequential-scan path
     on identical params — PP changes the schedule, never the math."""
@@ -82,7 +80,6 @@ def test_pipelined_lm_pp_matches_dense(devices8):
                                    atol=1e-4)
 
 
-@shard_map_skip
 def test_optimizer_trains_dp_tp_pp_composed(devices8):
     """THE product bar: one Optimizer call trains a pipelined model on a
     (data x pipe x model) mesh with composed DP+TP+PP shardings."""
@@ -111,7 +108,6 @@ def test_optimizer_trains_dp_tp_pp_composed(devices8):
         f"composed training did not move the loss: {init_loss} -> {final}"
 
 
-@shard_map_skip
 def test_sp_ring_reaches_optimizer(devices8):
     """TransformerLM(ring_axis=...) trains through the plain Optimizer on
     a (data x seq) mesh — attention auto-wraps in shard_map over seq."""
@@ -136,7 +132,6 @@ def test_sp_ring_reaches_optimizer(devices8):
         f"SP training did not move the loss: {init_loss} -> {final}"
 
 
-@shard_map_skip
 def test_sp_ulysses_matches_local_forward(devices8):
     """sp_impl='ulysses': the auto-wrapped SP forward equals the local
     (single-device) forward on identical params."""
@@ -155,7 +150,6 @@ def test_sp_ulysses_matches_local_forward(devices8):
                                atol=2e-5)
 
 
-@shard_map_skip
 def test_sp_ring_matches_local_forward(devices8):
     mesh = make_mesh([4], ["seq"], devices8[:4])
     lm = TransformerLM(vocab_size=32, hidden_size=16, num_layers=2,
@@ -172,7 +166,6 @@ def test_sp_ring_matches_local_forward(devices8):
                                atol=2e-5)
 
 
-@shard_map_skip
 def test_mesh_bearing_model_snapshot_roundtrip(tmp_path, devices8):
     """A mesh is runtime placement, not model identity: snapshots of
     mesh-constructed models must save and load on any topology."""
@@ -193,7 +186,6 @@ def test_mesh_bearing_model_snapshot_roundtrip(tmp_path, devices8):
     np.testing.assert_allclose(a, b, atol=2e-5)
 
 
-@shard_map_skip
 def test_interleaved_schedule_matches_dense(devices8):
     """The interleaved (virtual-stage) schedule shrinks the pipeline
     bubble from (S-1)/(M+S-1) to (S-1)/(V*M+S-1); it must remain a pure
@@ -225,7 +217,6 @@ def test_interleaved_schedule_matches_dense(devices8):
                                    atol=1e-4)
 
 
-@shard_map_skip
 def test_interleaved_trains_through_optimizer(devices8):
     """--ppSchedule interleaved is product surface: the stock Optimizer
     trains it on a (data x pipe) mesh."""
@@ -249,7 +240,6 @@ def test_interleaved_trains_through_optimizer(devices8):
     assert opt.driver_state["Loss"] < init_loss - 0.3
 
 
-@shard_map_skip
 def test_interleaved_needs_enough_microbatches(devices8):
     """M < S is schedule-infeasible (a round-v activation would need to
     re-enter stage 0 before it arrives) — fail fast, not silently."""
@@ -303,7 +293,6 @@ def _grads_vs_dense(mesh, model_kw, rules_kw, devices8, atol=2e-4):
                                    atol=atol)
 
 
-@shard_map_skip
 def test_pp_composes_with_ring_sp(devices8):
     """SP inside the pipeline: ring attention runs its manual
     collectives within each stage (seq axis manual alongside pipe) —
@@ -312,14 +301,12 @@ def test_pp_composes_with_ring_sp(devices8):
     _grads_vs_dense(mesh, {"ring_axis": "seq"}, {}, devices8)
 
 
-@shard_map_skip
 def test_pp_composes_with_ulysses_sp(devices8):
     mesh = make_mesh([2, 2, 2], ["data", "pipe", "seq"], devices8)
     _grads_vs_dense(mesh, {"ring_axis": "seq", "sp_impl": "ulysses"},
                     {}, devices8)
 
 
-@shard_map_skip
 def test_pp_composes_with_moe_ep(devices8):
     """MoE inside the pipeline: stacked routed experts GSPMD-sharded
     over the model axis, the load-balance aux threaded through the
@@ -331,7 +318,6 @@ def test_pp_composes_with_moe_ep(devices8):
                     devices8)
 
 
-@shard_map_skip
 def test_full_product_pp_sp_ep(devices8):
     """DP x PP x SP x EP constructible in ONE model on one mesh."""
     mesh = make_mesh([2, 2, 2], ["data", "pipe", "seq"], devices8)
@@ -339,7 +325,6 @@ def test_full_product_pp_sp_ep(devices8):
                     {"expert_axis": "seq"}, devices8)
 
 
-@shard_map_skip
 def test_interleaved_composes_with_moe_ep(devices8):
     """The interleaved schedule's aux threading (valid-mask + psum/m
     over V rounds) must ALSO equal the dense microbatch-looped aux —

@@ -194,13 +194,42 @@ def test_failure_retry_from_checkpoint(tmp_path):
     assert opt.driver_state["neval"] > 30
 
 
+def test_device_oom_fails_at_once_even_with_a_checkpoint(tmp_path):
+    """With a checkpoint configured a RuntimeError is retried — but not
+    the ones XLA raises for a refused compile or a device out of
+    memory: those replay identically, so the first diagnostic is
+    raised at once, with no back-off."""
+    import jax
+
+    X, y = _toy_classification(n=64)
+    ds = DataSet.array([Sample(X[i], y[i]) for i in range(len(X))]) \
+        .transform(SampleToMiniBatch(32))
+    model = nn.Sequential().add(nn.Linear(8, 3)).add(nn.LogSoftMax())
+    from bigdl_tpu.optim import several_iteration
+    opt = LocalOptimizer(model, ds, nn.ClassNLLCriterion(), batch_size=32)
+    opt.set_end_when(max_iteration(4))
+    opt.set_checkpoint(str(tmp_path / "ck"), several_iteration(2))
+    opt.retry_interval_s = 60.0      # a retry would hang the test
+    attempts = []
+
+    def oom():
+        attempts.append(1)
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm")
+
+    opt._optimize_impl = oom
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="RESOURCE_EXHAUSTED"):
+        opt.optimize()
+    assert attempts == [1]
+
+
 def test_convergence_dataset_is_a_learnable_split():
     """tools/convergence's prototype task: the class prototypes are the
     TASK and must be identical across splits (a train/val mismatch here
-    silently turns the 99.9% on-chip result into chance-level — the bug
-    class this guards). The full run is on-chip only (BASELINE.md r3:
-    99.85% held-out top-1 in 20 epochs); it is far too slow for 1-vCPU
-    CI."""
+    silently turns a converging run into chance-level — the bug
+    class this guards). The full run is for the chip; it is far too
+    slow for CI."""
     from bigdl_tpu.tools.convergence import make_dataset
 
     xs_a, ys_a = make_dataset(600, seed=0)

@@ -372,20 +372,11 @@ def test_rotating_feed_windowed_matches_per_step():
 # ------------------------------------------------------- compile counter
 
 def _count_compiles(fn):
-    from jax._src import compiler
-    orig = compiler.backend_compile
-    calls = []
+    from _compile_counter import count_compiles
 
-    def counting(*a, **kw):
-        calls.append(1)
-        return orig(*a, **kw)
-
-    compiler.backend_compile = counting
-    try:
+    with count_compiles() as compiles:
         fn()
-    finally:
-        compiler.backend_compile = orig
-    return len(calls)
+    return len(compiles)
 
 
 def test_windowed_mode_compiles_one_program_per_k_shape():
