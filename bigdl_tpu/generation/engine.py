@@ -35,6 +35,7 @@ from typing import Dict, Sequence, Set, Tuple
 
 import numpy as np
 
+import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu.serving.compile_cache import BucketLadder, CompileCache
 from bigdl_tpu.generation.kv_cache import KVCache
 
@@ -128,15 +129,16 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        def fn(params, state, k, v, tokens, last_in_chunk, slot_ids,
-               offsets):
+        def serving_prefill(params, state, k, v, tokens, last_in_chunk,
+                            slot_ids, offsets):
             on_trace()
             ids = slot_ids.astype(jnp.int32)
             # gather each row's slot window (OOB padding rows clamp to
             # the last slot; their garbage output is never read and
             # their write-back below is dropped)
-            rows_k = k[:, ids, :, :attend_len, :]
-            rows_v = v[:, ids, :, :attend_len, :]
+            with jax.named_scope("attn/kv_write"):
+                rows_k = k[:, ids, :, :attend_len, :]
+                rows_v = v[:, ids, :, :attend_len, :]
             logits, _, rows = model.apply(
                 params, state, tokens, training=False,
                 cache={"k": rows_k, "v": rows_v},
@@ -145,13 +147,14 @@ class DecodeEngine:
             last = jnp.take_along_axis(
                 logits, (last_in_chunk.astype(jnp.int32) - 1)
                 [:, None, None], axis=1)[:, 0, :]
-            k = k.at[:, ids, :, :attend_len, :].set(rows["k"],
-                                                    mode="drop")
-            v = v.at[:, ids, :, :attend_len, :].set(rows["v"],
-                                                    mode="drop")
+            with jax.named_scope("attn/kv_write"):
+                k = k.at[:, ids, :, :attend_len, :].set(rows["k"],
+                                                        mode="drop")
+                v = v.at[:, ids, :, :attend_len, :].set(rows["v"],
+                                                        mode="drop")
             return last, k, v
 
-        return jax.jit(fn, donate_argnums=(2, 3))
+        return jax.jit(serving_prefill, donate_argnums=(2, 3))
 
     @staticmethod
     def _decode_jit(model, attend_len: int, on_trace):
@@ -160,7 +163,7 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        def fn(params, state, k, v, tokens, positions, active):
+        def serving_decode(params, state, k, v, tokens, positions, active):
             on_trace()
             pos = jnp.where(active, positions.astype(jnp.int32), 0)
             logits, _, cache = model.apply(
@@ -169,7 +172,7 @@ class DecodeEngine:
                 attend_len=attend_len)
             return logits[:, 0, :], cache["k"], cache["v"]
 
-        return jax.jit(fn, donate_argnums=(2, 3))
+        return jax.jit(serving_decode, donate_argnums=(2, 3))
 
     @staticmethod
     def _verify_jit(model, attend_len: int, on_trace):
@@ -180,7 +183,7 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        def fn(params, state, k, v, tokens, positions, active):
+        def serving_verify(params, state, k, v, tokens, positions, active):
             on_trace()
             pos = jnp.where(active, positions.astype(jnp.int32), 0)
             logits, _, cache = model.apply(
@@ -189,7 +192,7 @@ class DecodeEngine:
                 attend_len=attend_len)
             return logits, cache["k"], cache["v"]
 
-        return jax.jit(fn, donate_argnums=(2, 3))
+        return jax.jit(serving_verify, donate_argnums=(2, 3))
 
     def prefill_program(self, servable, bucket: int):
         """The compiled prefill for prompt bucket ``bucket``:
@@ -253,15 +256,8 @@ class DecodeEngine:
         ndarray plus the attend bucket. The attend length must cover
         the deepest write (``positions + w``), so the bucket is taken
         from the longest live row plus the verify width."""
-        w = int(tokens.shape[1])
-        longest = int(positions[active].max()) + w if active.any() else w
-        attend_len = self.ladder.bucket_for(longest)
-        prog = self.verify_program(servable, attend_len)
-        logits, kv.k, kv.v = prog(
-            servable.params, servable.state, kv.k, kv.v,
-            tokens.astype(np.int32), positions.astype(np.int32),
-            active.astype(bool))
-        return np.asarray(logits), attend_len
+        return self._step(self.verify_program, servable, kv, tokens,
+                          positions, active, int(tokens.shape[1]))
 
     def abstract_programs(self, model, params, state,
                           kv_dtype=None):
@@ -365,9 +361,10 @@ class DecodeEngine:
             logits, kv.k, kv.v = prog(servable.params, servable.state,
                                       kv.k, kv.v, tokens, last_in,
                                       ids, offsets)
-            for i in range(n):
-                if ids[i] != self.slots and (lens[i] - 1) // sq == c:
-                    out[i] = np.asarray(logits[i])
+            with telemetry.span("serving/prefill/device_wait"):
+                for i in range(n):
+                    if ids[i] != self.slots and (lens[i] - 1) // sq == c:
+                        out[i] = np.asarray(logits[i])
         for i, slot in enumerate(slot_ids):
             kv.lengths[slot] = lens[i]
         return np.stack(out), bucket
@@ -389,14 +386,33 @@ class DecodeEngine:
         an operand the kernel adds no program keys — the ≤ 2-per-
         bucket compile bound holds with kernels on (asserted in
         tests/test_kernels.py)."""
-        longest = int(positions[active].max()) + 1 if active.any() else 1
-        attend_len = self.ladder.bucket_for(longest)
-        prog = self.decode_program(servable, attend_len)
-        logits, kv.k, kv.v = prog(
-            servable.params, servable.state, kv.k, kv.v,
-            tokens.astype(np.int32), positions.astype(np.int32),
-            active.astype(bool))
-        return np.asarray(logits), attend_len
+        return self._step(self.decode_program, servable, kv, tokens,
+                          positions, active, 1)
+
+    def _step(self, program_for, servable, kv: KVCache, tokens, positions,
+              active, width: int):
+        """One decode or verify step in three host spans under the
+        caller's ``serving/decode``: the launch (bucket choice, casts,
+        the program call returning), the wait on the device, and the
+        logits block copied to the host. The wait is explicit whether
+        or not anything is traced — ``np.asarray`` would block for it
+        anyway, so the program is the same program either way."""
+        import jax
+
+        with telemetry.span("serving/decode/dispatch"):
+            longest = (int(positions[active].max()) + width
+                       if active.any() else width)
+            attend_len = self.ladder.bucket_for(longest)
+            prog = program_for(servable, attend_len)
+            logits, kv.k, kv.v = prog(
+                servable.params, servable.state, kv.k, kv.v,
+                tokens.astype(np.int32), positions.astype(np.int32),
+                active.astype(bool))
+        with telemetry.span("serving/decode/device_wait"):
+            jax.block_until_ready(logits)
+        with telemetry.span("serving/decode/logits_d2h"):
+            host = np.asarray(logits)
+        return host, attend_len
 
     # -------------------------------------------------------- warmup
     def warmup(self, servable, kv: KVCache = None, kv_dtype=None) -> int:

@@ -23,6 +23,7 @@ caches exist only during the overlap).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -341,7 +342,8 @@ class DecodeLoop:
                     # memory just because traffic paused ("two caches
                     # exist only during the overlap")
                     self._groups.clear()
-                    self._cond.wait()
+                    with telemetry.span("serving/idle", model=self._name):
+                        self._cond.wait()
                 if self._stopping:
                     if not self._drain:
                         self._abort_locked()
@@ -389,27 +391,41 @@ class DecodeLoop:
         """Admit queued requests into free slots of the CURRENT
         version's cache — runs every step, so admission never waits
         for the batch to drain."""
-        with self._cond:
-            if not self._queue:
-                return
-            servable = self._registry.current(self._name)
-            group = self._groups.get(servable.key)
-            if group is None:
-                group = _Group(servable, self._cache_provider(servable))
-                self._groups[servable.key] = group
-            n = min(group.kv.allocator.free_count,
-                    self._engine.prefill_rows, len(self._queue))
-            if n == 0:
-                return  # full cache queues; eviction frees slots
-            gens = [self._queue.popleft() for _ in range(n)]
-            self._g_depth.set(len(self._queue), **self._labels)
-            # enter the group BEFORE the prefill dispatch: a prefill
-            # that raises must find these gens in group.gens so the
-            # supervisor fails their streams typed instead of
-            # stranding popped-but-unprefilled requests forever
-            for g in gens:
-                g.slot = group.kv.allocator.alloc()
-                group.gens[g.slot] = g
+        # the span opens under the lock, once there is something to
+        # admit, and closes after the last first-token emit, outside it
+        with contextlib.ExitStack() as admission:
+            with self._cond:
+                if not self._queue:
+                    return
+                servable = self._registry.current(self._name)
+                group = self._groups.get(servable.key)
+                if group is None:
+                    group = _Group(servable,
+                                   self._cache_provider(servable))
+                    self._groups[servable.key] = group
+                n = min(group.kv.allocator.free_count,
+                        self._engine.prefill_rows, len(self._queue))
+                if n == 0:
+                    return  # full cache queues; eviction frees slots
+                admission.enter_context(telemetry.span(
+                    "serving/admit", model=self._name, rows=n))
+                gens = [self._queue.popleft() for _ in range(n)]
+                self._g_depth.set(len(self._queue), **self._labels)
+                # enter the group BEFORE the prefill dispatch: a
+                # prefill that raises must find these gens in
+                # group.gens so the supervisor fails their streams
+                # typed instead of stranding popped-but-unprefilled
+                # requests forever
+                for g in gens:
+                    g.slot = group.kv.allocator.alloc()
+                    group.gens[g.slot] = g
+            self._admit_popped(servable, group, gens)
+
+    def _admit_popped(self, servable, group: _Group,
+                      gens: List[_Gen]) -> None:
+        """The unlocked part of :meth:`_admit`: prefix lookups, the
+        prefill batch and the first tokens of ``gens``, already seated
+        in ``group``."""
         # prefix/KV reuse (bigdl_tpu.fleet.prefix): a full-prompt hit
         # seeds its slot's cache rows by device copy and goes straight
         # to decode — only the misses pay a prefill program. Under
@@ -579,28 +595,38 @@ class DecodeLoop:
                         args={"trace_id": g.stream.trace_id,
                               "model": self._name, "token": g.produced,
                               "attend_len": attend_len})
-            for slot in live:
-                g = group.gens[slot]
-                kv.lengths[slot] += 1  # g.last's K/V landed this step
-                with self._cond:
-                    perr = self._preempt_marks.pop(id(g.stream), None)
-                if perr is not None:
-                    # the preemptor's typed error carries the partial
-                    # tokens; the stream keeps them too (.tokens())
-                    perr.tokens = g.stream.tokens()
-                    self._c_preempted.inc(**self._labels)
-                    g.stream._fail(perr)
-                    self._release(group, g)
-                    continue
-                if g.deadline is not None and now > g.deadline:
-                    self._c_timed_out.inc(**self._labels)
-                    g.stream._fail(DeadlineExceeded(
-                        f"{self._name}: generation passed its deadline "
-                        f"after {g.produced} tokens"))
-                    self._release(group, g)
-                    continue
-                self._emit(group, g, g.sampler.sample(logits[slot]))
+            with telemetry.span("serving/sample", model=self._name,
+                                slots=len(live)):
+                self._sample_and_emit(group, live, logits, now)
             self._g_occupancy.set(group.kv.occupancy(), **self._labels)
+
+    def _sample_and_emit(self, group: _Group, live: List[int], logits,
+                         now: float) -> None:
+        """The per-slot sweep after a decode step: preempt and deadline
+        checks, host sampling, delivery (callers' callbacks run in
+        ``_emit``)."""
+        kv = group.kv
+        for slot in live:
+            g = group.gens[slot]
+            kv.lengths[slot] += 1  # g.last's K/V landed this step
+            with self._cond:
+                perr = self._preempt_marks.pop(id(g.stream), None)
+            if perr is not None:
+                # the preemptor's typed error carries the partial
+                # tokens; the stream keeps them too (.tokens())
+                perr.tokens = g.stream.tokens()
+                self._c_preempted.inc(**self._labels)
+                g.stream._fail(perr)
+                self._release(group, g)
+                continue
+            if g.deadline is not None and now > g.deadline:
+                self._c_timed_out.inc(**self._labels)
+                g.stream._fail(DeadlineExceeded(
+                    f"{self._name}: generation passed its deadline "
+                    f"after {g.produced} tokens"))
+                self._release(group, g)
+                continue
+            self._emit(group, g, g.sampler.sample(logits[slot]))
 
     def _emit(self, group: _Group, g: _Gen, token: int) -> None:
         """Deliver one sampled token and apply the eviction rules

@@ -203,6 +203,7 @@ def _fwd_call(q, k, v, segment_ids, causal, sm_scale, block_q,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="bigdl_flash_fwd",
     )(*args)
 
 
@@ -242,6 +243,7 @@ def _bwd_call(q, k, v, o, do, lse, segment_ids, causal, sm_scale,
                         pltpu.VMEM((s, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="bigdl_flash_bwd",
     )(*args)
 
 
@@ -432,6 +434,7 @@ def _bw_fwd_call(q, k, v, segment_ids, causal, sm_scale, block_q,
                         pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_bw_compiler_params(),
         interpret=interpret,
+        name="bigdl_flash_blockwise_fwd",
     )(*args)
 
 
@@ -575,6 +578,7 @@ def _bw_bwd_call(q, k, v, o, do, lse, segment_ids, causal, sm_scale,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_bw_compiler_params(),
         interpret=interpret,
+        name="bigdl_flash_blockwise_dq",
     )(q, k, v, o, do, lse, *seg)
 
     # pass 2 — dk/dv: key tile outer, query tiles stream innermost
@@ -610,6 +614,7 @@ def _bw_bwd_call(q, k, v, o, do, lse, segment_ids, causal, sm_scale,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_bw_compiler_params(),
         interpret=interpret,
+        name="bigdl_flash_blockwise_dkv",
     )(q, k, v, o, do, lse, *seg)
     return dq, dk, dv
 

@@ -104,4 +104,5 @@ def pallas_quantized_matmul(x_q, w_q, x_scale, w_scale, bias=None, *,
         compiler_params=tpu_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="bigdl_int8_gemm",
     )(x_q, w_q, xs, ws, b)

@@ -178,4 +178,5 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         compiler_params=tpu_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="bigdl_paged_decode",
     )(page_table.astype(jnp.int32), lengths, q, k_pages, v_pages)

@@ -115,5 +115,6 @@ def ragged_decode_attention(q, k, v, lengths, *, sm_scale: float = None,
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((slots, h, 1, d), q.dtype),
         interpret=interpret,
+        name="bigdl_ragged_decode",
     )(lengths, q[:, :, None, :], k, v)
     return out[:, :, 0, :]

@@ -86,7 +86,10 @@ class TransformerBlock(Module):
               cache=None, positions=None, attend_len=None, attn_mask=None,
               attn_segments=None):
         r1, r2 = (jax.random.split(rng) if rng is not None else (None, None))
-        h = self.ln1.forward_fn(params["ln1"], input)
+        # named scopes (here and in nn.attention) are the by-role
+        # vocabulary a device trace's op names carry: docs/telemetry.md
+        with jax.named_scope("norm"):
+            h = self.ln1.forward_fn(params["ln1"], input)
         if cache is None:
             h = self.attn.forward_fn(params["attn"], h, training=training,
                                      rng=r1, mask=attn_mask,
@@ -102,9 +105,12 @@ class TransformerBlock(Module):
                 params["attn"], h, training=training, rng=r1,
                 cache=cache, positions=positions, attend_len=attend_len)
         x = input + h
-        h = self.ln2.forward_fn(params["ln2"], x)
-        h, mlp_state = self.mlp.apply(params["mlp"], state.get("mlp", {}), h,
-                                      training=training, rng=r2)
+        with jax.named_scope("norm"):
+            h = self.ln2.forward_fn(params["ln2"], x)
+        with jax.named_scope("mlp"):
+            h, mlp_state = self.mlp.apply(params["mlp"],
+                                          state.get("mlp", {}), h,
+                                          training=training, rng=r2)
         if cache is None:
             return x + h, {"mlp": mlp_state}
         return x + h, {"mlp": mlp_state}, cache
@@ -198,25 +204,27 @@ class TransformerLM(Module):
         else:
             tokens = input.astype(jnp.int32)
         b, s = tokens.shape
-        if cache is None:
-            if packed_pos is None:
-                x = params["embed"][tokens] + params["pos_embed"][:s][None]
+        with jax.named_scope("embed"):
+            if cache is None:
+                if packed_pos is None:
+                    x = (params["embed"][tokens]
+                         + params["pos_embed"][:s][None])
+                else:
+                    # per-document positions (restart at 0 per segment)
+                    # so a packed document sees the same positional
+                    # embeddings it would alone in a row
+                    idx = jnp.clip(packed_pos.astype(jnp.int32), 0,
+                                   self.max_len - 1)
+                    x = params["embed"][tokens] + params["pos_embed"][idx]
             else:
-                # per-document positions (restart at 0 per segment) so a
-                # packed document sees the same positional embeddings it
-                # would alone in a row
-                idx = jnp.clip(packed_pos.astype(jnp.int32), 0,
-                               self.max_len - 1)
+                # incremental decode: row b's S tokens sit at absolute
+                # positions positions[b] .. positions[b]+S-1 (clip
+                # keeps a free-slot row's garbage offset from faulting
+                # the gather; its output is never read)
+                idx = jnp.clip(
+                    positions.astype(jnp.int32)[:, None]
+                    + jnp.arange(s)[None], 0, self.max_len - 1)
                 x = params["embed"][tokens] + params["pos_embed"][idx]
-        else:
-            # incremental decode: row b's S tokens sit at absolute
-            # positions positions[b] .. positions[b]+S-1 (clip keeps a
-            # free-slot row's garbage offset from faulting the gather;
-            # its output is never read)
-            idx = jnp.clip(
-                positions.astype(jnp.int32)[:, None] + jnp.arange(s)[None],
-                0, self.max_len - 1)
-            x = params["embed"][tokens] + params["pos_embed"][idx]
         keys = (jax.random.split(rng, self.num_layers)
                 if rng is not None else [None] * self.num_layers)
         new_state = {}
@@ -236,19 +244,26 @@ class TransformerLM(Module):
                                   training=training, rng=keys[i],
                                   **mask_kw)
             else:
+                # the layer's rows sliced out of the stacked cache and
+                # written back into it count as cache movement, like
+                # the new rows' write inside the attention
+                with jax.named_scope("attn/kv_write"):
+                    layer_in = {"k": cache["k"][i], "v": cache["v"][i]}
                 x, st, layer_cache = blk.apply(
                     params[f"block_{i}"], state.get(f"block_{i}", {}), x,
-                    training=training, rng=keys[i],
-                    cache={"k": cache["k"][i], "v": cache["v"][i]},
+                    training=training, rng=keys[i], cache=layer_in,
                     positions=positions, attend_len=attend_len)
-                cache = {"k": cache["k"].at[i].set(layer_cache["k"]),
-                         "v": cache["v"].at[i].set(layer_cache["v"])}
+                with jax.named_scope("attn/kv_write"):
+                    cache = {"k": cache["k"].at[i].set(layer_cache["k"]),
+                             "v": cache["v"].at[i].set(layer_cache["v"])}
             new_state[f"block_{i}"] = st
-        x = self.ln_f.forward_fn(params["ln_f"], x)
-        if self.tie_embeddings:
-            logits = x @ params["embed"].T
-        else:
-            logits = x @ params["lm_head"]
+        with jax.named_scope("norm"):
+            x = self.ln_f.forward_fn(params["ln_f"], x)
+        with jax.named_scope("lm_head"):
+            if self.tie_embeddings:
+                logits = x @ params["embed"].T
+            else:
+                logits = x @ params["lm_head"]
         if cache is None:
             return logits, new_state
         return logits, new_state, cache

@@ -236,10 +236,20 @@ class MultiHeadAttention(Module):
         def split(t):  # [B,S,E] -> [B,H,S,D]
             return t.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
-        q = split(self._proj(params, x, "q"))
-        k = split(self._proj(params, x, "k"))
-        v = split(self._proj(params, x, "v"))
+        with jax.named_scope("attn/qkv"):
+            q = split(self._proj(params, x, "q"))
+            k = split(self._proj(params, x, "k"))
+            v = split(self._proj(params, x, "v"))
+        with jax.named_scope("attn/core"):
+            out = self._core(q, k, v, mask, segments, training, rng)
+        with jax.named_scope("attn/out"):
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, e)
+            return self._proj(params, out, "o")
 
+    def _core(self, q, k, v, mask, segments, training, rng):
+        """``[B,H,S,D]`` attention by whichever path applies: a
+        sequence-parallel kernel, the pallas flash kernel, or the
+        einsum form."""
         # the module-level knob wins; without one, adopt the train-step
         # policy (build_train_step(seq_parallel=...) installs it for the
         # duration of the trace) — mask/dropout keep the dense path,
@@ -272,9 +282,7 @@ class MultiHeadAttention(Module):
                 q, k, v, causal=self.causal, mask=mask,
                 dropout_rate=self.dropout, rng=rng, training=training,
                 segments=segments)
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, e)
-        return self._proj(params, out, "o")
-
+        return out
 
     def _forward_cached(self, params, x, cache, positions, attend_len):
         """One KV-cached attention step (module ``forward_fn`` doc has
@@ -289,9 +297,10 @@ class MultiHeadAttention(Module):
         def split(t):  # [B,S,E] -> [B,H,S,D]
             return t.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
-        q = split(self._proj(params, x, "q"))
-        k = split(self._proj(params, x, "k"))
-        v = split(self._proj(params, x, "v"))
+        with jax.named_scope("attn/qkv"):
+            q = split(self._proj(params, x, "q"))
+            k = split(self._proj(params, x, "k"))
+            v = split(self._proj(params, x, "v"))
 
         positions = positions.astype(jnp.int32)
 
@@ -303,12 +312,25 @@ class MultiHeadAttention(Module):
         def upd(c, u, p):  # c: [H,T,D], u: [H,S,D], p: scalar offset
             return jax.lax.dynamic_update_slice(c, u, (0, p, 0))
 
-        ck = jax.vmap(upd)(cache["k"], k, positions)
-        cv = jax.vmap(upd)(cache["v"], v, positions)
+        with jax.named_scope("attn/kv_write"):
+            ck = jax.vmap(upd)(cache["k"], k, positions)
+            cv = jax.vmap(upd)(cache["v"], v, positions)
 
         t = ck.shape[2]
         al = t if attend_len is None else int(attend_len)
-        ks, vs = ck[:, :, :al, :], cv[:, :, :al, :]
+        with jax.named_scope("attn/core"):
+            out = self._cached_core(q, ck[:, :, :al, :], cv[:, :, :al, :],
+                                    positions)
+        with jax.named_scope("attn/out"):
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, e)
+            return self._proj(params, out, "o"), {"k": ck, "v": cv}
+
+    @staticmethod
+    def _cached_core(q, ks, vs, positions):
+        """The cached step's attention over the attended cache rows
+        ``ks``/``vs``: the ragged decode kernel for one new token a
+        row, else the length-masked einsum form."""
+        s, al = q.shape[2], ks.shape[2]
         out = None
         if s == 1:
             # the decode step (one new token per row): the ragged
@@ -335,8 +357,7 @@ class MultiHeadAttention(Module):
                 + jnp.arange(s)[None, None, :, None]
             out = dot_product_attention(q, ks, vs, mask=jpos <= qpos,
                                         use_flash=False)
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, e)
-        return self._proj(params, out, "o"), {"k": ck, "v": cv}
+        return out
 
     def _sp_kernel(self, impl: Optional[str] = None):
         if (impl or self.sp_impl) == "ulysses":

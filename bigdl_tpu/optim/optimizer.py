@@ -52,6 +52,10 @@ _RECORD_COUNT = telemetry.counter("train/optimizer/records",
 _RECOVERIES = telemetry.counter(
     "train/optimizer/recoveries",
     "retry-from-checkpoint recoveries performed by optimize()")
+_WINDOW_GAP = telemetry.histogram(
+    "train/optimizer/window_gap_ms",
+    "host ms from one fused window's block_until_ready returning to the "
+    "next window's dispatch returning, within one optimize() call")
 # mixed-precision observability (Optimizer.set_precision): the loss
 # scale and cumulative skipped steps are read off the (already-fetched)
 # scaler state once per host sync; the policy/bytes gauges are set once
@@ -355,7 +359,8 @@ def build_train_step(module: Module, criterion: Criterion,
                 out, new_mstate = module.apply(p_c, model_state, x_c,
                                                training=True, rng=rng)
             out = policy.cast_output(out)
-            loss = criterion.apply(out, targets)
+            with jax.named_scope("loss"):
+                loss = criterion.apply(out, targets)
             reg = module.regularization_loss(p_c)
             aux = _collect_aux_losses(new_mstate)
             total = loss + reg + aux_loss_weight * aux
@@ -409,8 +414,9 @@ def build_train_step(module: Module, criterion: Criterion,
             # exactly the pre-policy program
             from bigdl_tpu.precision import cast_floating
             grads = cast_floating(grads, policy.param_dtype)
-        new_base, new_inner = optim_method.update(grads, inner_opt,
-                                                  update_base, lr)
+        with jax.named_scope("optim_update"):
+            new_base, new_inner = optim_method.update(grads, inner_opt,
+                                                      update_base, lr)
         if master is not None:
             new_master = new_base
             new_params = policy.cast_to_param(new_master)
@@ -1840,6 +1846,7 @@ class Optimizer:
                     self._checkpoint(params, opt_state, model_state)
 
         wall_start = time.time()
+        window_synced = None  # monotonic: the last window's sync returned
         while not end_when(state):
             if self._grace is not None and self._grace.requested():
                 # SIGTERM grace: step boundary, state consistent —
@@ -1933,17 +1940,26 @@ class Optimizer:
                 # against bf16 master params would widen the carry)
                 lrs = jnp.asarray(lr_list, Engine.default_dtype())
                 t1 = time.time()
-                if rotating or device_feed:
-                    params, opt_state, model_state, losses = window_fn(
-                        params, opt_state, model_state, keys, lrs, *wargs)
-                else:
-                    params, opt_state, model_state, losses = \
-                        host_window_fn(params, opt_state, model_state,
-                                       keys, lrs, inp, tgt)
+                # the launch as a live span: what follows it up to the
+                # end of optimizer/compute is the wait on the device
+                with telemetry.span("optimizer/window/dispatch",
+                                    step=state["neval"], steps=k_now):
+                    if rotating or device_feed:
+                        params, opt_state, model_state, losses = \
+                            window_fn(params, opt_state, model_state,
+                                      keys, lrs, *wargs)
+                    else:
+                        params, opt_state, model_state, losses = \
+                            host_window_fn(params, opt_state, model_state,
+                                           keys, lrs, inp, tgt)
+                if window_synced is not None:
+                    _WINDOW_GAP.observe(
+                        (time.monotonic() - window_synced) * 1e3)
                 # THE one sync per window: the losses fetch only gates
                 # the loss path, so close the timing window on the full
                 # outputs first (sanctioned window-boundary sync)
                 jax.block_until_ready((params, opt_state, model_state))  # bigdl: disable=sync-in-loop
+                window_synced = time.monotonic()
                 loss_vals = _losses_list(losses, k_now)
                 t_compute = time.time() - t1
                 if track_scaler and telemetry.enabled():
@@ -1969,11 +1985,14 @@ class Optimizer:
                 telemetry.flight.note_metrics({"step": state["neval"]})
                 telemetry.agg.maybe_ship()
                 rate = sum(sizes) / max(1e-9, t_data + t_compute)
-                for i in range(k_now):
-                    post_step(loss_vals[i], lr_list[i], sizes[i], rate)
+                with telemetry.span("optimizer/window/replay",
+                                    step=state["neval"], steps=k_now):
+                    for i in range(k_now):
+                        post_step(loss_vals[i], lr_list[i], sizes[i], rate)
                 continue
 
             # ---- classic per-step path (k == 1) ---------------------
+            window_synced = None  # a per-step tail is no window gap
             if rotating or device_feed:
                 bsz = self.dataset.batch_size
                 step_args = device_cursor_args()

@@ -23,6 +23,16 @@ Spans are "complete" events (``ph: "X"``): one record per finished span
 with ``ts``/``dur`` in microseconds on one monotonic clock, which is
 what keeps the export loadable by the trace-event schema without
 begin/end pairing fix-ups.
+
+**The profiler's clock.** An enabled live span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so while a profiler
+session is on (``jax.profiler.start_trace`` / ``utils.profiling.trace``)
+the span lies on ``/host:CPU``, on the calling thread's line, on the
+clock the device's ``XLA Ops`` are on — which is what lets a long idle
+gap of the device be put down to what the host was doing in it. With no
+session on, the annotation is one atomic load. The ring keeps
+``time.monotonic()``; pre-measured ``record()`` / ``record_span()``
+intervals (virtual request tracks) stay ring-only.
 """
 from __future__ import annotations
 
@@ -34,6 +44,18 @@ from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional
 
 __all__ = ["SpanRecord", "SpanTracer", "NOOP_SPAN"]
+
+_ANNOTATION = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first enabled
+    span (a disabled process never pays for the import)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 class SpanRecord:
@@ -81,7 +103,7 @@ NOOP_SPAN = _NoopSpan()
 class _Span:
     """A live (enabled-path) span context manager."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  args: Optional[Dict[str, Any]]):
@@ -93,11 +115,14 @@ class _Span:
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        self._ann = _annotation()(self.name)
+        self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         dur = time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
