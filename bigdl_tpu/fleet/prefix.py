@@ -50,21 +50,40 @@ import bigdl_tpu.telemetry as telemetry
 @functools.lru_cache(maxsize=64)
 def _seed_program(cache_shape, dtype_str, rung):
     """The donated seed-copy program for one (cache geometry, rung):
-    splices an entry's K/V blocks into one slot's rows IN PLACE
-    (donated buffers — no full-cache copy per hit). One compile per
-    rung per geometry, bounded by the ladder; cached here rather than
-    per-instance so every PrefixCache sharing a geometry shares the
-    executable."""
+    splices an entry's K/V blocks into one slot's rows of every
+    layer's array IN PLACE (donated buffers — no full-cache copy per
+    hit). One compile per rung per geometry, bounded by the ladder;
+    cached here rather than per-instance so every PrefixCache sharing
+    a geometry shares the executable."""
     import jax
 
+    def splice(layers, entry, slot):
+        return tuple(
+            jax.lax.dynamic_update_slice(a, entry[i][None],
+                                         (slot, 0, 0, 0))
+            for i, a in enumerate(layers))
+
     def fn(k, v, ek, ev, slot):
-        k = jax.lax.dynamic_update_slice(k, ek[:, None],
-                                         (0, slot, 0, 0, 0))
-        v = jax.lax.dynamic_update_slice(v, ev[:, None],
-                                         (0, slot, 0, 0, 0))
-        return k, v
+        return splice(k, ek, slot), splice(v, ev, slot)
 
     return jax.jit(fn, donate_argnums=(0, 1))
+
+
+@functools.lru_cache(maxsize=64)
+def _extract_program(rung):
+    """The extract-copy program for one rung (jit keys the cache
+    geometry itself): one slot's first ``rung`` columns of every
+    layer's array, stacked ``[layers, heads, head_dim, rung]`` — one
+    dispatch per admission, not one per layer."""
+    import jax
+    import jax.numpy as jnp
+
+    def rows(layers, slot):
+        return jnp.stack([
+            jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False)
+            [..., :rung] for a in layers])
+
+    return jax.jit(lambda k, v, slot: (rows(k, slot), rows(v, slot)))
 
 
 def register_prefix_instruments(r) -> Dict[str, object]:
@@ -95,9 +114,10 @@ def register_prefix_instruments(r) -> Dict[str, object]:
 class PrefixEntry:
     """One cached prefix: committed K/V blocks + first-token logits.
 
-    ``k``/``v`` are device arrays ``[layers, heads, rung, head_dim]``
-    (``rung`` = the prompt's ladder bucket — padded so every seeding
-    copy runs at a bucketed shape), ``length`` the real prefix length,
+    ``k``/``v`` are device arrays ``[layers, heads, head_dim, rung]``
+    (time last, as the cache keeps it; ``rung`` = the prompt's ladder
+    bucket — padded so every seeding copy runs at a bucketed shape),
+    ``length`` the real prefix length,
     ``logits`` the host ``[V]`` first-token logits row the prefill
     computed — or ``None`` for a chunk-BOUNDARY entry, whose tokens
     end mid-prompt so no first-token row exists; such entries serve
@@ -232,7 +252,7 @@ class PrefixCache:
         ``max_bytes`` and never frees blocks a live slot still
         reads."""
         key = self.key_for(version_key, tokens)
-        rung = int(k_rows.shape[2])
+        rung = int(k_rows.shape[3])
         entry = PrefixEntry(key, tuple(version_key), len(tokens), rung,
                             k_rows, v_rows, logits)
         evicted, committed = 0, None
@@ -292,10 +312,10 @@ class PrefixCache:
     @staticmethod
     def extract(kv, slot: int, rung: int):
         """Device-copy the committed K/V blocks out of a freshly
-        prefilled slot: ``[layers, heads, rung, head_dim]`` for K and
-        V. Rows past the real prompt length ride along (the rung pads
-        them) but are never attended."""
-        return (kv.k[:, slot, :, :rung, :], kv.v[:, slot, :, :rung, :])
+        prefilled slot: ``[layers, heads, head_dim, rung]`` for K and
+        V. Columns past the real prompt length ride along (the rung
+        pads them) but are never attended."""
+        return _extract_program(int(rung))(kv.k, kv.v, np.int32(slot))
 
     @staticmethod
     def seed(kv, slot: int, entry: PrefixEntry) -> None:
@@ -306,8 +326,8 @@ class PrefixCache:
         copy runs as a donated compiled splice (no full-cache copy),
         so a full-prefix hit's TTFT is one dynamic_update_slice plus
         the first decode step."""
-        fn = _seed_program(kv.k.shape, str(np.dtype(kv.dtype)),
-                           entry.rung)
+        fn = _seed_program((kv.layers,) + kv.k[0].shape,
+                           str(np.dtype(kv.dtype)), entry.rung)
         kv.k, kv.v = fn(kv.k, kv.v, entry.k, entry.v,
                         np.int32(slot))
         kv.lengths[slot] = entry.length

@@ -15,6 +15,12 @@ grows — one compile *per token*. This engine pins every shape instead:
   ``T_b`` cache positions for a length bucket ``T_b``, so short
   sequences never scan the whole preallocated ``max_len``.
 
+The cache every program takes and returns is
+:class:`~bigdl_tpu.generation.kv_cache.KVCache`'s own form: ``k`` and
+``v`` each a tuple of per-layer ``[slots, heads, head_dim, max_len]``
+arrays (time last: ``head_dim`` 64 on the lanes would pad 2x), donated
+as pytrees and written in place — a decode step holds no copy of it.
+
 K ladder rungs ⇒ at most K prefill + K decode = **2K compiled
 programs** per model version, warmed eagerly as pairs by
 :meth:`DecodeEngine.warmup` and counted — not trusted — through the
@@ -133,12 +139,12 @@ class DecodeEngine:
                             slot_ids, offsets):
             on_trace()
             ids = slot_ids.astype(jnp.int32)
-            # gather each row's slot window (OOB padding rows clamp to
-            # the last slot; their garbage output is never read and
-            # their write-back below is dropped)
+            # gather each row's slot window, layer by layer (OOB
+            # padding rows clamp to the last slot; their garbage output
+            # is never read and their write-back below is dropped)
             with jax.named_scope("attn/kv_write"):
-                rows_k = k[:, ids, :, :attend_len, :]
-                rows_v = v[:, ids, :, :attend_len, :]
+                rows_k = tuple(a[ids, :, :, :attend_len] for a in k)
+                rows_v = tuple(a[ids, :, :, :attend_len] for a in v)
             logits, _, rows = model.apply(
                 params, state, tokens, training=False,
                 cache={"k": rows_k, "v": rows_v},
@@ -148,10 +154,10 @@ class DecodeEngine:
                 logits, (last_in_chunk.astype(jnp.int32) - 1)
                 [:, None, None], axis=1)[:, 0, :]
             with jax.named_scope("attn/kv_write"):
-                k = k.at[:, ids, :, :attend_len, :].set(rows["k"],
-                                                        mode="drop")
-                v = v.at[:, ids, :, :attend_len, :].set(rows["v"],
-                                                        mode="drop")
+                k = tuple(a.at[ids, :, :, :attend_len].set(r, mode="drop")
+                          for a, r in zip(k, rows["k"]))
+                v = tuple(a.at[ids, :, :, :attend_len].set(r, mode="drop")
+                          for a, r in zip(v, rows["v"]))
             return last, k, v
 
         return jax.jit(serving_prefill, donate_argnums=(2, 3))
@@ -198,9 +204,10 @@ class DecodeEngine:
         """The compiled prefill for prompt bucket ``bucket``:
         ``(params, state, k, v, tokens[Bp,Sq], last_in_chunk[Bp],
         slot_ids[Bp], offsets[Bp]) -> (logits[Bp,V], k', v')`` with the
-        cache donated. ``Sq`` is the bucket itself, or the engine's
-        ``prefill_chunk`` for larger rungs — ONE token shape per rung
-        either way, so chunking never adds a program. Padding rows
+        cache (``k``, ``v``: per-layer tuples) donated. ``Sq`` is the
+        bucket itself, or the engine's ``prefill_chunk`` for larger
+        rungs — ONE token shape per rung either way, so chunking never
+        adds a program. Padding rows
         carry ``slot_ids == slots`` (out of bounds): their K/V scatter
         is dropped and their logits row is garbage the driver never
         reads."""

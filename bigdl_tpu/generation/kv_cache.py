@@ -1,14 +1,21 @@
 """Preallocated, shape-bucketed KV cache + host-side slot accounting.
 
 The decode engine's whole memory story is ONE allocation per model
-version: ``[layers, slots, heads, max_len, head_dim]`` K and V arrays
-(``max_len`` already padded to the top rung of the service's length
-ladder), an explicit per-slot ``lengths`` vector, and a host-side
-alloc/free bitmap. Requests *occupy slots* — admission is a bitmap
-``alloc()``, eviction a ``free()`` — so continuous batching never
+version: per layer one K and one V array ``[slots, heads, head_dim,
+max_len]`` (``max_len`` already padded to the top rung of the
+service's length ladder), an explicit per-slot ``lengths`` vector, and
+a host-side alloc/free bitmap. Requests *occupy slots* — admission is a
+bitmap ``alloc()``, eviction a ``free()`` — so continuous batching never
 reshapes or reallocates device memory, which is exactly what keeps the
 decode program count bounded (every step runs at the same
 ``[slots, ...]`` shapes; see docs/serving.md "Generation").
+
+Time is the LAST axis because that is the form the chip stores and the
+decode kernel reads: ``head_dim`` 64 on the 128 lanes would pad every
+tile 2x, so the compiler kept a ``[.., max_len, head_dim]`` array
+time-minor anyway and transposed a whole layer on each side of the
+kernel, every step. One array per layer, written in place by the
+donated programs, leaves no copy of the cache in a decode step.
 """
 from __future__ import annotations
 
@@ -69,15 +76,15 @@ class SlotAllocator:
 class KVCache:
     """One model version's preallocated decode cache.
 
-    ``k``/``v`` are device arrays ``[layers, slots, heads, max_len,
-    head_dim]`` threaded (donated) through every prefill/decode program
-    call; ``lengths`` is the explicit host-side int32 vector of
-    per-slot sequence lengths (= the next write position), and
-    ``allocator`` the slot bitmap. A freed slot's rows are NOT zeroed:
-    every position a future occupant can attend is re-written (prompt
-    region by its prefill, each generated position by the decode step
-    that produces it) before the length-masked causal mask ever exposes
-    it."""
+    ``k``/``v`` are tuples of ``layers`` device arrays ``[slots, heads,
+    head_dim, max_len]`` threaded (donated) through every
+    prefill/decode program call; ``lengths`` is the explicit host-side
+    int32 vector of per-slot sequence lengths (= the next write
+    position), and ``allocator`` the slot bitmap. A freed slot's
+    columns are NOT zeroed: every position a future occupant can attend
+    is re-written (prompt region by its prefill, each generated
+    position by the decode step that produces it) before the
+    length-masked causal mask ever exposes it."""
 
     def __init__(self, layers: int, slots: int, heads: int, max_len: int,
                  head_dim: int, dtype=None):
@@ -91,17 +98,20 @@ class KVCache:
         self.max_len = max_len
         self.head_dim = head_dim
         self.dtype = dtype if dtype is not None else Engine.default_dtype()
-        shape = (layers, slots, heads, max_len, head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        shape = (slots, heads, head_dim, max_len)
+        self.k = tuple(jnp.zeros(shape, self.dtype)
+                       for _ in range(layers))
+        self.v = tuple(jnp.zeros(shape, self.dtype)
+                       for _ in range(layers))
         self.lengths = np.zeros((slots,), np.int32)
         self.allocator = SlotAllocator(slots)
 
     @classmethod
     def _model_geometry(cls, model, slots: int, max_len: int) -> tuple:
-        """The ``(layers, slots, heads, max_len, head_dim)`` buffer
-        shape a decoder model's declared geometry (``num_layers``/
-        ``num_heads``/``head_dim`` or ``hidden_size``) implies — ONE
+        """The ``(layers, slots, heads, max_len, head_dim)`` cache
+        geometry (the constructor's arguments) a decoder model's
+        declared geometry (``num_layers``/``num_heads``/``head_dim`` or
+        ``hidden_size``) implies — ONE
         derivation (and positional-table bound) shared by
         :meth:`for_model` and :meth:`spec_for_model`, so the verified
         program shapes can never drift from the allocated ones."""
@@ -125,8 +135,8 @@ class KVCache:
     @classmethod
     def spec_for_model(cls, model, slots: int, max_len: int,
                        dtype=None):
-        """The ``(k, v)`` buffer shapes :meth:`for_model` would
-        allocate (same derivation, same validation), as
+        """The ``(k, v)`` buffers :meth:`for_model` would allocate
+        (same derivation, same validation), as tuples of ``layers``
         ``jax.ShapeDtypeStruct`` — nothing touches a device. The
         static program verifier lowers the engine's prefill/decode
         jits over these instead of a live cache."""
@@ -134,10 +144,12 @@ class KVCache:
 
         from bigdl_tpu.utils.engine import Engine
 
-        shape = cls._model_geometry(model, slots, max_len)
+        layers, slots, heads, max_len, head_dim = cls._model_geometry(
+            model, slots, max_len)
         dt = dtype if dtype is not None else Engine.default_dtype()
-        return (jax.ShapeDtypeStruct(shape, dt),
-                jax.ShapeDtypeStruct(shape, dt))
+        layer = jax.ShapeDtypeStruct((slots, heads, head_dim, max_len),
+                                     dt)
+        return (layer,) * layers, (layer,) * layers
 
     def occupancy(self) -> float:
         """Live-slot fraction (the ``cache_occupancy`` gauge)."""
@@ -150,7 +162,7 @@ class KVCache:
 
     def nbytes(self) -> int:
         """Device bytes held by the K and V buffers."""
-        return int(self.k.nbytes) + int(self.v.nbytes)
+        return sum(int(a.nbytes) for a in self.k + self.v)
 
     def __repr__(self) -> str:
         return (f"KVCache(L={self.layers} slots={self.slots} "

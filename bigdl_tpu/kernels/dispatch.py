@@ -140,19 +140,20 @@ def attention(q, k, v, *, causal: bool = False, segment_ids=None,
                            interpret=interpret)
 
 
-def decode_attention(q, k, v, lengths, *,
+def decode_attention(q, k, v, lengths, *, attend_len: int = None,
                      sm_scale: Optional[float] = None):
     """Ragged-decode dispatch: ``q [slots, H, D]`` (one token per
-    slot), ``k``/``v`` ``[slots, H, T, D]`` cache slices, ``lengths``
-    the host per-slot valid-KV vector. Returns the kernel result
+    slot), ``k``/``v`` one layer's whole ``[slots, H, D, T]`` cache,
+    ``lengths`` the host per-slot valid-KV vector, ``attend_len`` the
+    (static) ladder rung. Returns the kernel result
     (:mod:`bigdl_tpu.kernels.ragged_decode` — reads only
-    ``lengths[i]`` rows per slot) when ``decode`` is enabled and the
+    ``lengths[i]`` columns per slot) when ``decode`` is enabled and the
     shapes qualify, else **None** (the caller's length-masked einsum
     path runs)."""
     if not _config.enabled("decode"):
         _declined("decode", "config")
         return None
-    if (k.ndim != 4 or q.shape != k.shape[:2] + k.shape[3:]
+    if (k.ndim != 4 or v.shape != k.shape or q.shape != k.shape[:3]
             or not _floating(q, k, v)):
         _declined("decode", "shape")
         return None
@@ -160,7 +161,9 @@ def decode_attention(q, k, v, lengths, *,
 
     cfg = _config.get_config()
     _taken("decode")
-    return ragged_decode_attention(q, k, v, lengths, sm_scale=sm_scale,
+    return ragged_decode_attention(q, k, v, lengths,
+                                   attend_len=attend_len,
+                                   sm_scale=sm_scale,
                                    block_k=cfg.block_k,
                                    interpret=cfg.resolve_interpret())
 

@@ -228,6 +228,7 @@ class TransformerLM(Module):
         keys = (jax.random.split(rng, self.num_layers)
                 if rng is not None else [None] * self.num_layers)
         new_state = {}
+        new_k, new_v = [], []
         for i, blk in enumerate(self.blocks):
             if cache is None:
                 # attn_segments only rides along for packed inputs:
@@ -244,18 +245,15 @@ class TransformerLM(Module):
                                   training=training, rng=keys[i],
                                   **mask_kw)
             else:
-                # the layer's rows sliced out of the stacked cache and
-                # written back into it count as cache movement, like
-                # the new rows' write inside the attention
-                with jax.named_scope("attn/kv_write"):
-                    layer_in = {"k": cache["k"][i], "v": cache["v"][i]}
+                # each layer owns its K/V pair: handed in and collected
+                # as it is, so nothing of the cache is sliced or stacked
                 x, st, layer_cache = blk.apply(
                     params[f"block_{i}"], state.get(f"block_{i}", {}), x,
-                    training=training, rng=keys[i], cache=layer_in,
+                    training=training, rng=keys[i],
+                    cache={"k": cache["k"][i], "v": cache["v"][i]},
                     positions=positions, attend_len=attend_len)
-                with jax.named_scope("attn/kv_write"):
-                    cache = {"k": cache["k"].at[i].set(layer_cache["k"]),
-                             "v": cache["v"].at[i].set(layer_cache["v"])}
+                new_k.append(layer_cache["k"])
+                new_v.append(layer_cache["v"])
             new_state[f"block_{i}"] = st
         with jax.named_scope("norm"):
             x = self.ln_f.forward_fn(params["ln_f"], x)
@@ -266,7 +264,7 @@ class TransformerLM(Module):
                 logits = x @ params["lm_head"]
         if cache is None:
             return logits, new_state
-        return logits, new_state, cache
+        return logits, new_state, {"k": tuple(new_k), "v": tuple(new_v)}
 
     def aux_loss(self, state) -> jnp.ndarray:
         """Total MoE load-balance loss across blocks."""

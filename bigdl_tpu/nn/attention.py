@@ -202,11 +202,12 @@ class MultiHeadAttention(Module):
         their K/V block; Ulysses all-gathers the full id row); a custom
         ``mask`` does not, and neither works on the cached path.
 
-        ``cache`` is ``{"k": [B,H,T,D], "v": [B,H,T,D]}`` (T the
-        cache's bucketed max length), ``positions`` an int32 ``[B]`` of
-        per-row write offsets: the S new tokens of row ``b`` land at
-        cache slots ``positions[b] .. positions[b]+S-1`` via
-        ``dynamic_update_slice``, and each query at absolute position
+        ``cache`` is ``{"k": [B,H,D,T], "v": [B,H,D,T]}`` (T the
+        cache's bucketed max length, on the lanes: the form the decode
+        kernel reads, see ``generation/kv_cache.py``), ``positions`` an
+        int32 ``[B]`` of per-row write offsets: the S new tokens of row
+        ``b`` land at cache columns ``positions[b] .. positions[b]+S-1``
+        via ``dynamic_update_slice``, and each query at absolute position
         ``p`` attends the cached keys ``j <= p`` under a length-masked
         causal mask. ``attend_len`` (static) restricts attention to the
         first ``attend_len`` cache slots so short sequences never scan
@@ -304,46 +305,46 @@ class MultiHeadAttention(Module):
 
         positions = positions.astype(jnp.int32)
 
-        # write the S new K/V rows at each row's offset (XLA clamps an
-        # out-of-range start into the buffer; the driver only passes
-        # in-range offsets for live rows, and a clamped write into a
-        # FREE slot is re-written by that slot's next prefill before any
-        # mask ever exposes it)
-        def upd(c, u, p):  # c: [H,T,D], u: [H,S,D], p: scalar offset
-            return jax.lax.dynamic_update_slice(c, u, (0, p, 0))
+        # write the S new K/V columns at each row's offset, in place:
+        # the few new rows are transposed to the cache's [H,D,T] form,
+        # never the cache (XLA clamps an out-of-range start into the
+        # buffer; the driver only passes in-range offsets for live
+        # rows, and a clamped write into a FREE slot is re-written by
+        # that slot's next prefill before any mask ever exposes it)
+        def upd(c, u, p):  # c: [H,D,T], u: [H,S,D], p: scalar offset
+            return jax.lax.dynamic_update_slice(
+                c, jnp.swapaxes(u, 1, 2), (0, 0, p))
 
         with jax.named_scope("attn/kv_write"):
             ck = jax.vmap(upd)(cache["k"], k, positions)
             cv = jax.vmap(upd)(cache["v"], v, positions)
 
-        t = ck.shape[2]
-        al = t if attend_len is None else int(attend_len)
+        al = ck.shape[3] if attend_len is None else int(attend_len)
         with jax.named_scope("attn/core"):
-            out = self._cached_core(q, ck[:, :, :al, :], cv[:, :, :al, :],
-                                    positions)
+            out = self._cached_core(q, ck, cv, positions, al)
         with jax.named_scope("attn/out"):
             out = out.transpose(0, 2, 1, 3).reshape(b, s, e)
             return self._proj(params, out, "o"), {"k": ck, "v": cv}
 
     @staticmethod
-    def _cached_core(q, ks, vs, positions):
-        """The cached step's attention over the attended cache rows
-        ``ks``/``vs``: the ragged decode kernel for one new token a
-        row, else the length-masked einsum form."""
-        s, al = q.shape[2], ks.shape[2]
+    def _cached_core(q, ck, cv, positions, al):
+        """The cached step's attention over the first ``al`` columns of
+        the ``[B,H,D,T]`` cache: the ragged decode kernel for one new
+        token a row (it takes the whole array), else the length-masked
+        einsum form."""
+        s = q.shape[2]
         out = None
         if s == 1:
             # the decode step (one new token per row): the ragged
-            # pallas kernel reads only positions[b]+1 valid cache rows
-            # per slot instead of scanning the whole attend_len slice
-            # — the host lengths vector the engine threads as
+            # pallas kernel reads only positions[b]+1 valid cache
+            # columns per slot instead of scanning the whole attend_len
+            # window — the host lengths vector the engine threads as
             # `positions` is the kernel's ragged bound. Declined
             # dispatch (kernels off / ineligible) falls through to the
             # masked path below, bit-identical to the pre-kernel tree.
             from bigdl_tpu import kernels as _kernels
             out = _kernels.decode_attention(
-                q[:, :, 0, :], ks, vs,
-                positions.astype(jnp.int32) + 1)
+                q[:, :, 0, :], ck, cv, positions + 1, attend_len=al)
             if out is not None:
                 out = out[:, :, None, :]
         if out is None:
@@ -351,12 +352,15 @@ class MultiHeadAttention(Module):
             # absolute position positions[b]+i and may see cache slots
             # j <= that — fed through the ONE attention core above so
             # the cached and full-sequence paths can never drift
-            # numerically
+            # numerically (the swap back to [B,H,al,D] folds into the
+            # products' contraction dimensions)
             jpos = jnp.arange(al)[None, None, None, :]
             qpos = positions[:, None, None, None] \
                 + jnp.arange(s)[None, None, :, None]
-            out = dot_product_attention(q, ks, vs, mask=jpos <= qpos,
-                                        use_flash=False)
+            out = dot_product_attention(
+                q, jnp.swapaxes(ck[..., :al], 2, 3),
+                jnp.swapaxes(cv[..., :al], 2, 3), mask=jpos <= qpos,
+                use_flash=False)
         return out
 
     def _sp_kernel(self, impl: Optional[str] = None):

@@ -95,14 +95,32 @@ def _grad_of(attn):
                                1024])
 def test_ragged_decode_compiles_at_ladder_rungs(one_chip, t, dtype):
     """The default TPU decode path at every rung of the powers-of-two
-    ladder up to ``max_len`` 1024 — below one vector tile, one tile,
-    several tiles."""
+    ladder up to ``max_len`` 1024 — below one lane tile, one tile,
+    several tiles — each reading the whole ``[slots, H, D, max_len]``
+    cache through a block of that rung's width."""
     from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
 
     q, k, v, lengths = _on(
         one_chip, ((SLOTS, HEADS, HEAD_DIM), dtype),
-        ((SLOTS, HEADS, t, HEAD_DIM), dtype),
-        ((SLOTS, HEADS, t, HEAD_DIM), dtype), ((SLOTS,), "int32"))
+        ((SLOTS, HEADS, HEAD_DIM, MAX_LEN), dtype),
+        ((SLOTS, HEADS, HEAD_DIM, MAX_LEN), dtype), ((SLOTS,), "int32"))
+    assert _has_kernel(_compile(
+        lambda q, k, v, n: ragged_decode_attention(q, k, v, n,
+                                                   attend_len=t),
+        q, k, v, lengths))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t", [8, 64, 200])
+def test_ragged_decode_compiles_at_short_caches(one_chip, t, dtype):
+    """A cache shorter than a lane tile, or not a whole number of them
+    (a service with a small ``max_len``): the block is all of ``T``."""
+    from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
+
+    q, k, v, lengths = _on(
+        one_chip, ((SLOTS, HEADS, HEAD_DIM), dtype),
+        ((SLOTS, HEADS, HEAD_DIM, t), dtype),
+        ((SLOTS, HEADS, HEAD_DIM, t), dtype), ((SLOTS,), "int32"))
     assert _has_kernel(_compile(ragged_decode_attention, q, k, v,
                                 lengths))
 
@@ -112,8 +130,8 @@ def test_ragged_decode_compiles_at_head_dim_128(one_chip, dtype):
     from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
 
     q, k, v, lengths = _on(
-        one_chip, ((16, 8, 128), dtype), ((16, 8, 512, 128), dtype),
-        ((16, 8, 512, 128), dtype), ((16,), "int32"))
+        one_chip, ((16, 8, 128), dtype), ((16, 8, 128, 512), dtype),
+        ((16, 8, 128, 512), dtype), ((16,), "int32"))
     assert _has_kernel(_compile(ragged_decode_attention, q, k, v,
                                 lengths))
 
@@ -257,10 +275,11 @@ def lm():
     return model
 
 
-def test_decode_step_holds_the_kernel(one_chip, lm):
-    """The engine's own decode program for the top rung, at the policy a
-    TPU gets by default (decode + int8 on, compiled, no interpreter):
-    its compiled text must contain the Mosaic kernel."""
+def _compile_decode(lm, one_chip, slots):
+    """The engine's own ``decode/1024`` program at ``slots`` slots,
+    compiled at the policy a TPU gets by default (decode + int8 on, no
+    interpreter); returns ``(compiled, donated cache specs, pallas
+    dispatches taken by the trace)``."""
     from bigdl_tpu import kernels
     from bigdl_tpu.analysis.programs import abstract_tree
     from bigdl_tpu.generation.engine import DecodeEngine
@@ -268,7 +287,7 @@ def test_decode_step_holds_the_kernel(one_chip, lm):
     from bigdl_tpu.serving.compile_cache import (BucketLadder,
                                                  CompileCache)
 
-    engine = DecodeEngine(CompileCache(), BucketLadder(MAX_LEN), SLOTS, 4)
+    engine = DecodeEngine(CompileCache(), BucketLadder(MAX_LEN), slots, 4)
     lm.evaluate()
     programs = engine.abstract_programs(
         lm, abstract_tree(lm.get_parameters()),
@@ -282,8 +301,58 @@ def test_decode_step_holds_the_kernel(one_chip, lm):
                                   int8_matmul=True, interpret=False)):
         before = kernels.dispatch.taken_in_thread()
         compiled = jitted.lower(*args).compile()
-        assert kernels.dispatch.taken_in_thread() - before == LM["layers"]
+        taken = kernels.dispatch.taken_in_thread() - before
+    return compiled, jax.tree.leaves(args[2:4]), taken
+
+
+def test_decode_step_holds_the_kernel(one_chip, lm):
+    """The engine's own decode program for the top rung, at the policy a
+    TPU gets by default: its compiled text must contain the Mosaic
+    kernel."""
+    compiled, _, taken = _compile_decode(lm, one_chip, SLOTS)
+    assert taken == LM["layers"]
     assert _has_kernel(compiled)
+
+
+def test_decode_step_holds_no_copy_of_the_cache(one_chip, lm):
+    """The serve cell's decode program (64 slots x 1024, GPT-2-small
+    widths, 2 layers): the cache is stored as the kernel reads it, so
+    the compiled step aliases every donated cache leaf to its output,
+    keeps less than one layer's K in temporaries, and holds no ``copy``
+    and no ``slice`` (alone or as a fusion's root) of a whole layer's
+    K or V. What may remain at that shape are the in-place
+    ``dynamic-update-slice``s of the new columns. (The stacked
+    ``[layers, slots, H, T, D]`` cache read 1.41 GB of temporaries
+    here: two transposing copies, a slice and a stack rewrite per
+    layer and K/V.)"""
+    from bigdl_tpu.analysis.hlo import parse_hlo
+
+    slots = 64
+    compiled, cache_leaves, _ = _compile_decode(lm, one_chip, slots)
+    assert _has_kernel(compiled)
+    layer_elems = slots * HEADS * MAX_LEN * HEAD_DIM
+    layer_bytes = layer_elems * 4
+    assert len(cache_leaves) == 2 * LM["layers"]
+    assert all(int(np.prod(a.shape)) == layer_elems
+               for a in cache_leaves)
+
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= len(cache_leaves) * layer_bytes
+    assert mem.temp_size_in_bytes < layer_bytes, mem.temp_size_in_bytes
+
+    module = parse_hlo(compiled.as_text())
+
+    def root_opcode(op):
+        fused = module.computations.get(op.called.get("calls", ""))
+        if op.opcode != "fusion" or fused is None:
+            return op.opcode
+        return next(o.opcode for o in fused.ops if o.is_root)
+
+    moved = [(op.name, root_opcode(op), op.result_type)
+             for _, op in module.find_ops()
+             if op.result_elements() >= layer_elems
+             and root_opcode(op) in ("copy", "slice", "transpose")]
+    assert not moved, moved
 
 
 def _train_step_args(lm, optim, policy, replicated, batch_sharding,

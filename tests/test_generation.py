@@ -93,8 +93,13 @@ def test_slot_allocator_never_double_assigns():
 def test_kv_cache_geometry_and_occupancy():
     m = _model()
     kv = KVCache.for_model(m, slots=4, max_len=16)
-    assert kv.k.shape == (2, 4, 4, 16, 8)  # [L, slots, H, T, D]
-    assert kv.v.shape == kv.k.shape
+    # one [slots, H, D, T] array per layer, time last
+    assert [a.shape for a in kv.k] == [(4, 4, 8, 16)] * 2
+    assert [a.shape for a in kv.v] == [(4, 4, 8, 16)] * 2
+    assert kv.nbytes() == 2 * 2 * 4 * 4 * 8 * 16 * 4
+    k_spec, v_spec = KVCache.spec_for_model(m, slots=4, max_len=16)
+    assert [(a.shape, a.dtype) for a in k_spec + v_spec] \
+        == [(a.shape, a.dtype) for a in kv.k + kv.v]
     assert kv.lengths.tolist() == [0, 0, 0, 0]
     assert kv.occupancy() == 0.0
     kv.allocator.alloc()

@@ -441,86 +441,103 @@ class TestBlockwiseFlashAttention:
 
 # ------------------------------------------------------- ragged decode
 
+def _decode_operands(slots, h, d, t, seed, dtype="float32"):
+    """q ``[slots, H, D]`` and one layer's cache ``[slots, H, D, T]``
+    (time last — the form ``KVCache`` stores)."""
+    r = np.random.default_rng(seed)
+    return (jnp.asarray(r.standard_normal((slots, h, d)), dtype),
+            jnp.asarray(r.standard_normal((slots, h, d, t)), dtype),
+            jnp.asarray(r.standard_normal((slots, h, d, t)), dtype))
+
+
+def _masked_decode_reference(q, k, v, lengths):
+    """The length-masked einsum over a ``[slots, H, D, T]`` cache."""
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    t = k.shape[3]
+    s = jnp.einsum("shd,shdt->sht", f32(q), f32(k)) \
+        / math.sqrt(q.shape[-1])
+    mask = jnp.arange(t)[None, None, :] < lengths[:, None, None]
+    return jnp.einsum("sht,shdt->shd",
+                      jax.nn.softmax(jnp.where(mask, s, -jnp.inf),
+                                     axis=-1), f32(v))
+
+
 class TestRaggedDecode:
     def test_every_length_in_bucket(self):
         """The ragged kernel vs the length-masked reference at EVERY
         length of a 16-wide bucket — length 1 and bucket-max
         included."""
         slots, h, t, d = 4, 2, 16, 8
-        r = np.random.default_rng(10)
-        q = jnp.asarray(r.standard_normal((slots, h, d))
-                        .astype(np.float32))
-        k = jnp.asarray(r.standard_normal((slots, h, t, d))
-                        .astype(np.float32))
-        v = jnp.asarray(r.standard_normal((slots, h, t, d))
-                        .astype(np.float32))
+        q, k, v = _decode_operands(slots, h, d, t, seed=10)
         for n in range(1, t + 1):
             lengths = jnp.full((slots,), n, jnp.int32)
-            out = ragged_decode_attention(q, k, v, lengths, block_k=8,
+            out = ragged_decode_attention(q, k, v, lengths,
                                           interpret=True)
-            s = jnp.einsum("shd,shtd->sht", q, k,
-                           preferred_element_type=jnp.float32) \
-                / math.sqrt(d)
-            s = jnp.where(jnp.arange(t)[None, None, :] < n, s, -jnp.inf)
-            ref = jnp.einsum("sht,shtd->shd",
-                             jax.nn.softmax(s, axis=-1), v)
+            ref = _masked_decode_reference(q, k, v, lengths)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        atol=1e-5, rtol=0,
                                        err_msg=f"length {n}")
 
     def test_mixed_ragged_lengths(self):
-        slots, h, t, d = 4, 2, 32, 8
-        r = np.random.default_rng(11)
-        q = jnp.asarray(r.standard_normal((slots, h, d))
-                        .astype(np.float32))
-        k = jnp.asarray(r.standard_normal((slots, h, t, d))
-                        .astype(np.float32))
-        v = jnp.asarray(r.standard_normal((slots, h, t, d))
-                        .astype(np.float32))
-        lengths = jnp.asarray(np.array([1, 7, 13, 32], np.int32))
-        out = ragged_decode_attention(q, k, v, lengths, block_k=8,
-                                      interpret=True)
-        s = jnp.einsum("shd,shtd->sht", q, k,
-                       preferred_element_type=jnp.float32) / math.sqrt(d)
-        mask = jnp.arange(t)[None, None, :] < lengths[:, None, None]
-        ref = jnp.einsum("sht,shtd->shd",
-                         jax.nn.softmax(jnp.where(mask, s, -jnp.inf),
-                                        axis=-1), v)
+        """Three 128-column tiles under the dynamic loop bound, with
+        lengths on both sides of every tile edge."""
+        slots, h, t, d = 6, 2, 384, 8
+        q, k, v = _decode_operands(slots, h, d, t, seed=11)
+        lengths = jnp.asarray(np.array([1, 127, 128, 129, 300, 384],
+                                       np.int32))
+        out = ragged_decode_attention(q, k, v, lengths, interpret=True)
+        ref = _masked_decode_reference(q, k, v, lengths)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=0)
 
     @pytest.mark.parametrize("t, dtype", [
-        (1, "bfloat16"),      # one row: the static single-tile path
-        (100, "float32"),     # no aligned divisor: one whole-T tile
-        (1000, "float32"),    # tiles of 40 rows under a dynamic bound
-        (96, "bfloat16"),     # bf16 rows pack 16 to a tile: tiles of 96
+        (1, "bfloat16"),      # one column: the static single-tile path
+        (100, "float32"),     # under one lane tile: one whole-T tile
+        (1000, "float32"),    # no 128-aligned divisor: one whole-T tile
+        (1024, "float32"),    # eight lane tiles under a dynamic bound
+        (256, "bfloat16"),    # bf16 tiles are (16, 128): D = 16 fits
     ])
     def test_tpu_legal_tiles_match_reference(self, t, dtype):
-        """The tiles the TPU compiler takes (whole vector tiles or the
+        """The tiles the TPU compiler takes (whole lane tiles or the
         whole of T) give the same answers as the masked reference."""
-        slots, h, d = 3, 2, 8
-        r = np.random.default_rng(13)
-        q = jnp.asarray(r.standard_normal((slots, h, d)), dtype)
-        k = jnp.asarray(r.standard_normal((slots, h, t, d)), dtype)
-        v = jnp.asarray(r.standard_normal((slots, h, t, d)), dtype)
+        slots, h, d = 3, 2, 16
+        q, k, v = _decode_operands(slots, h, d, t, 13, dtype)
         lengths = jnp.asarray(np.array([1, max(1, t // 2), t], np.int32))
         out = ragged_decode_attention(q, k, v, lengths, interpret=True)
         assert out.shape == (slots, h, d) and out.dtype == q.dtype
-        f32 = lambda a: a.astype(jnp.float32)
-        s = jnp.einsum("shd,shtd->sht", f32(q), f32(k)) / math.sqrt(d)
-        mask = jnp.arange(t)[None, None, :] < lengths[:, None, None]
-        ref = jnp.einsum("sht,shtd->shd",
-                         jax.nn.softmax(jnp.where(mask, s, -jnp.inf),
-                                        axis=-1), f32(v))
+        ref = _masked_decode_reference(q, k, v, lengths)
         np.testing.assert_allclose(
-            np.asarray(f32(out)), np.asarray(ref), rtol=0,
+            np.asarray(out.astype(jnp.float32)), np.asarray(ref), rtol=0,
             atol=1e-5 if dtype == "float32" else 2e-2)
 
+    @pytest.mark.parametrize("attend_len", [256, 32])
+    def test_lower_rung_reads_the_whole_cache_unsliced(self, attend_len):
+        """A lower ladder rung on a full-``T`` cache: the kernel takes
+        the whole array and a block of that rung's width (rounded up
+        to a lane tile below 128), and agrees with the masked einsum
+        over the first ``attend_len`` columns — whatever lies beyond
+        them, and beyond each slot's length, is never read into the
+        result."""
+        slots, h, d, t = 3, 2, 8, 512
+        q, k, v = _decode_operands(slots, h, d, t, seed=14)
+        lengths = jnp.asarray(np.array([1, attend_len // 2, attend_len],
+                                       np.int32))
+        out = ragged_decode_attention(q, k, v, lengths,
+                                      attend_len=attend_len,
+                                      interpret=True)
+        ref = _masked_decode_reference(q, k[..., :attend_len],
+                                       v[..., :attend_len], lengths)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5, rtol=0)
+        # poison everything past the rung: the answer must not move
+        poison = jnp.arange(t) >= attend_len
+        out_p = ragged_decode_attention(
+            q, jnp.where(poison, jnp.nan, k), jnp.where(poison, 1e9, v),
+            lengths, attend_len=attend_len, interpret=True)
+        assert np.array_equal(np.asarray(out_p), np.asarray(out))
+
     def test_dispatch_shapes_and_toggle(self):
-        r = np.random.default_rng(12)
-        q = jnp.asarray(r.standard_normal((2, 2, 8)).astype(np.float32))
-        kv = jnp.asarray(r.standard_normal((2, 2, 16, 8))
-                         .astype(np.float32))
+        q, kv, _ = _decode_operands(2, 2, 8, 16, seed=12)
         lengths = jnp.asarray(np.array([3, 9], np.int32))
         with kernels.use(OFF):
             assert kernels.decode_attention(q, kv, kv, lengths) is None
@@ -529,6 +546,10 @@ class TestRaggedDecode:
             assert out is not None and out.shape == (2, 2, 8)
             # a [B,H,S,D] query is the training shape, not decode's
             assert kernels.decode_attention(kv, kv, kv, lengths) is None
+            # nor is a [slots,H,T,D] cache: time is the last axis
+            assert kernels.decode_attention(
+                q, jnp.swapaxes(kv, 2, 3), jnp.swapaxes(kv, 2, 3),
+                lengths) is None
 
 
 # ----------------------------------------------------------- int8 GEMM
@@ -659,6 +680,33 @@ class TestGenerationWithKernels:
                 out2 = svc.generate("lm", prompt2,
                                     max_new_tokens=5).result(60)
                 assert list(out2) == _greedy_reference(model, prompt2, 5)
+            finally:
+                svc.shutdown()
+
+    def test_slot_reused_after_free_matches_uncached_forwards(self):
+        """Decode -> free -> re-admit on ONE slot, ragged kernel live,
+        two ladder rungs: the second occupant is shorter than the
+        first, so the slot's columns past its length still hold the
+        first stream's K/V (a freed slot is not zeroed) and the lower
+        rung reads a narrower block of the same unsliced array. Each
+        greedy stream equals the one full, uncached forwards give."""
+        from bigdl_tpu.generation import (GenerationConfig,
+                                          GenerationService)
+        model = _gen_model()
+        with kernels.use(ON):
+            svc = GenerationService(config=GenerationConfig(
+                slots=1, max_len=16, length_buckets=(8, 16),
+                prefill_rows=1))
+            svc.load("lm", model)
+            try:
+                first = np.array([3, 7, 1, 4, 9, 12, 5, 8, 2], np.int32)
+                out = svc.generate("lm", first,
+                                   max_new_tokens=6).result(60)
+                assert list(out) == _greedy_reference(model, first, 6)
+                second = np.array([11, 2], np.int32)
+                out2 = svc.generate("lm", second,
+                                    max_new_tokens=10).result(60)
+                assert list(out2) == _greedy_reference(model, second, 10)
             finally:
                 svc.shutdown()
 

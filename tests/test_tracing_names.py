@@ -358,7 +358,7 @@ def _kernel_cases():
                                             interpret=True)
     blockwise = lambda q, k, v: blockwise_flash_attention(
         q, k, v, causal=True, interpret=True)
-    cache = jnp.zeros((2, 2, 16, 8), jnp.float32)
+    cache = jnp.zeros((2, 2, 16, 8), jnp.float32)   # [slots, H, T, D]
     lengths = jnp.ones((2,), jnp.int32)
 
     def paged(q, k, v):
@@ -370,7 +370,9 @@ def _kernel_cases():
         "ragged_decode": (
             lambda q, k, v: ragged_decode_attention(q, k, v, lengths,
                                                     interpret=True),
-            (jnp.zeros((2, 2, 8)), cache, cache),
+            # the contiguous kernel reads [slots, H, D, T]
+            (jnp.zeros((2, 2, 8)), cache.swapaxes(2, 3),
+             cache.swapaxes(2, 3)),
             {"bigdl_ragged_decode"}),
         "paged_decode": (paged, (jnp.zeros((2, 2, 8)), cache, cache),
                          {"bigdl_paged_decode"}),
