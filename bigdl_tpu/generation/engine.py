@@ -21,6 +21,18 @@ The cache every program takes and returns is
 arrays (time last: ``head_dim`` 64 on the lanes would pad 2x), donated
 as pytrees and written in place — a decode step holds no copy of it.
 
+**A prefill's shape follows from the model.** A rung whose one-shot
+``[prefill_rows, heads, rung, rung]`` float32 scores would pass
+``_PREFILL_SCORE_BYTES`` is filled by the engine itself in ``[1,
+chunk]`` pieces through the same per-rung program (``prefill_shape``):
+no option asks for it, and a rung that fits keeps the program it had.
+A model whose layers keep different cache entries
+(``KVCache.layout``: rings for window layers) has each layer's entry
+gathered, attended and written back at its own width
+(``min(rung, columns)``). Every program also returns the model's
+``MOE_STATS_KEY`` state leaves (``[expert layers, 3]``; nothing for a
+model without experts), recorded on the host with the logits.
+
 K ladder rungs ⇒ at most K prefill + K decode = **2K compiled
 programs** per model version, warmed eagerly as pairs by
 :meth:`DecodeEngine.warmup` and counted — not trusted — through the
@@ -44,6 +56,76 @@ import numpy as np
 import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu.serving.compile_cache import BucketLadder, CompileCache
 from bigdl_tpu.generation.kv_cache import KVCache
+
+#: float32 attention scores one prefill call may hold, ``rows x heads x
+#: tokens x rung x 4`` bytes: past it the engine chunks the rung
+_PREFILL_SCORE_BYTES = 512 << 20
+
+_H_TOUCHED = telemetry.histogram(
+    "serving/moe/experts_touched",
+    "experts touched by a decode step, of those held, mean over the "
+    "expert layers")
+_H_PAIRS = telemetry.histogram(
+    "serving/moe/local_pairs",
+    "token-expert pairs of a decode step that fell on held experts, "
+    "summed over the expert layers")
+_H_PAIRS_MAX = telemetry.histogram(
+    "serving/moe/pairs_per_expert_max",
+    "the most pairs one expert took in a decode step, over the expert "
+    "layers")
+
+
+def _moe_stats(state):
+    """The model's ``MOE_STATS_KEY`` leaves stacked ``[expert layers,
+    3]`` in block order, or None: what a program returns beside its
+    logits."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.nn.moe import MOE_STATS_KEY
+
+    found = []
+
+    def walk(node):
+        if isinstance(node, dict):
+            for key in sorted(node, key=lambda k: (len(k), k)):
+                if key == MOE_STATS_KEY:
+                    found.append(node[key])
+                else:
+                    walk(node[key])
+    walk(state)
+    return jnp.stack(found) if found else None
+
+
+def _accepts(model, *names) -> bool:
+    """Whether the model's ``apply`` takes these optional arguments:
+    ``logits_at`` (one position's logits a row instead of every
+    position's) and ``live`` (which rows are real)."""
+    import inspect
+
+    return set(names) <= set(inspect.signature(model.apply).parameters)
+
+
+def _record_moe(stats, kind: str) -> None:
+    """One program call's expert statistics into the always-on
+    histograms (decode steps) and, with the span tracer on, into a ring
+    record that carries the call's totals (``serving/moe/step``: what a
+    traced window sums, decode and prefill alike)."""
+    if stats is None:
+        return
+    stats = np.asarray(stats)
+    touched, pairs, most = (float(stats[:, 0].sum()),
+                            float(stats[:, 1].sum()),
+                            float(stats[:, 2].max()))
+    if kind == "decode":
+        _H_TOUCHED.observe(touched / len(stats))
+        _H_PAIRS.observe(pairs)
+        _H_PAIRS_MAX.observe(most)
+    if telemetry.enabled():
+        telemetry.tracer().record(
+            "serving/moe/step", 0.0,
+            args={"kind": kind, "layers": len(stats),
+                  "experts_touched": touched, "local_pairs": pairs,
+                  "pairs_per_expert_max": most})
 
 
 class DecodeEngine:
@@ -117,7 +199,8 @@ class DecodeEngine:
         return prog
 
     @staticmethod
-    def _prefill_jit(model, attend_len: int, on_trace):
+    def _prefill_jit(model, attend_len: int, on_trace,
+                     fresh: bool = False):
         """The raw prefill jit (donated cache) — shared by the cached
         :meth:`prefill_program` and the :meth:`abstract_programs`
         verification hook, so both see the identical program.
@@ -131,34 +214,48 @@ class DecodeEngine:
         special case: every attended lane is written by the chunk
         itself (the causal mask covers the rest), so the gathered
         stale lanes — exactly like the zero rows the pre-chunking
-        program fed — contribute exact zeros to the softmax."""
+        program fed — contribute exact zeros to the softmax. ``fresh``
+        says so to a model that can use it (``fresh=``: the new tokens
+        attend only each other, through a flash kernel where one
+        fits)."""
         import jax
         import jax.numpy as jnp
+
+        one_row = _accepts(model, "logits_at", "live")
+        extra = {"fresh": True} if fresh and _accepts(model, "fresh") \
+            else {}
 
         def serving_prefill(params, state, k, v, tokens, last_in_chunk,
                             slot_ids, offsets):
             on_trace()
             ids = slot_ids.astype(jnp.int32)
-            # gather each row's slot window, layer by layer (OOB
-            # padding rows clamp to the last slot; their garbage output
-            # is never read and their write-back below is dropped)
+            last_at = last_in_chunk.astype(jnp.int32) - 1
+            # gather each row's slot window, layer by layer, as wide as
+            # the layer's entry reaches into the rung (OOB padding rows
+            # clamp to the last slot; their garbage output is never
+            # read and their write-back below is dropped)
+            width = lambda a: min(attend_len, a.shape[3])
             with jax.named_scope("attn/kv_write"):
-                rows_k = tuple(a[ids, :, :, :attend_len] for a in k)
-                rows_v = tuple(a[ids, :, :, :attend_len] for a in v)
-            logits, _, rows = model.apply(
+                rows_k = tuple(a[ids, :, :, :width(a)] for a in k)
+                rows_v = tuple(a[ids, :, :, :width(a)] for a in v)
+            logits, new_state, rows = model.apply(
                 params, state, tokens, training=False,
                 cache={"k": rows_k, "v": rows_v},
                 positions=offsets.astype(jnp.int32),
-                attend_len=attend_len)
-            last = jnp.take_along_axis(
-                logits, (last_in_chunk.astype(jnp.int32) - 1)
-                [:, None, None], axis=1)[:, 0, :]
+                attend_len=attend_len,
+                **({"logits_at": last_at, "live": ids < k[0].shape[0]}
+                   if one_row else {}), **extra)
+            if one_row:
+                last = logits[:, 0, :]
+            else:
+                last = jnp.take_along_axis(
+                    logits, last_at[:, None, None], axis=1)[:, 0, :]
             with jax.named_scope("attn/kv_write"):
-                k = tuple(a.at[ids, :, :, :attend_len].set(r, mode="drop")
+                k = tuple(a.at[ids, :, :, :width(a)].set(r, mode="drop")
                           for a, r in zip(k, rows["k"]))
-                v = tuple(a.at[ids, :, :, :attend_len].set(r, mode="drop")
+                v = tuple(a.at[ids, :, :, :width(a)].set(r, mode="drop")
                           for a, r in zip(v, rows["v"]))
-            return last, k, v
+            return last, k, v, _moe_stats(new_state)
 
         return jax.jit(serving_prefill, donate_argnums=(2, 3))
 
@@ -169,14 +266,24 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
+        rows_live = _accepts(model, "live")
+
         def serving_decode(params, state, k, v, tokens, positions, active):
             on_trace()
             pos = jnp.where(active, positions.astype(jnp.int32), 0)
-            logits, _, cache = model.apply(
+            logits, new_state, cache = model.apply(
                 params, state, tokens[:, None], training=False,
                 cache={"k": k, "v": v}, positions=pos,
-                attend_len=attend_len)
-            return logits[:, 0, :], cache["k"], cache["v"]
+                attend_len=attend_len,
+                **({"live": active} if rows_live else {}))
+            logits = logits[:, 0, :]
+            # the greedy token of every slot rides along: a step whose
+            # requests are all greedy copies [slots] ids to the host,
+            # not the [slots, V] logits (ties to the lowest id, as
+            # np.argmax on the host breaks them)
+            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (logits, cache["k"], cache["v"],
+                    _moe_stats(new_state), ids)
 
         return jax.jit(serving_decode, donate_argnums=(2, 3))
 
@@ -189,14 +296,17 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
+        rows_live = _accepts(model, "live")
+
         def serving_verify(params, state, k, v, tokens, positions, active):
             on_trace()
             pos = jnp.where(active, positions.astype(jnp.int32), 0)
-            logits, _, cache = model.apply(
+            logits, new_state, cache = model.apply(
                 params, state, tokens, training=False,
                 cache={"k": k, "v": v}, positions=pos,
-                attend_len=attend_len)
-            return logits, cache["k"], cache["v"]
+                attend_len=attend_len,
+                **({"live": active} if rows_live else {}))
+            return logits, cache["k"], cache["v"], _moe_stats(new_state)
 
         return jax.jit(serving_verify, donate_argnums=(2, 3))
 
@@ -212,22 +322,49 @@ class DecodeEngine:
         is dropped and their logits row is garbage the driver never
         reads."""
         model = servable.model
+        fresh = self.prefill_shape(model, bucket)[1] == bucket
         return self._program(
             servable, "prefill", bucket,
-            lambda on_trace: self._prefill_jit(model, bucket,
-                                               on_trace))
+            lambda on_trace: self._prefill_jit(model, bucket, on_trace,
+                                               fresh))
 
     def chunk_for(self, bucket: int) -> int:
-        """The prefill token width for ``bucket``: the bucket itself,
-        or the fixed chunk for rungs past ``prefill_chunk``."""
+        """The prefill token width for ``bucket`` under the configured
+        ``prefill_chunk``: the bucket itself, or the fixed chunk for
+        rungs past it."""
         if self.prefill_chunk is None or bucket <= self.prefill_chunk:
             return bucket
         return self.prefill_chunk
 
+    def prefill_shape(self, model, bucket: int) -> Tuple[int, int]:
+        """``(rows, tokens)`` of one prefill call at rung ``bucket``.
+
+        A configured ``prefill_chunk`` decides as before. Without one
+        the engine looks at what it can see: ``prefill_rows`` whole
+        prompts in one shot while their float32 scores (``rows x heads
+        x rung x rung``) stay within ``_PREFILL_SCORE_BYTES``; past
+        that, ONE row — in one shot where the model says its attention
+        holds no scores at this rung (``scoreless_prefill``), else in
+        the widest pieces that divide the rung and fit. A call reads
+        every weight once, so a padding row would cost whole calls, and
+        one row wastes none."""
+        if self.prefill_chunk is not None:
+            return self.prefill_rows, self.chunk_for(bucket)
+        per_token = int(model.num_heads) * bucket * 4
+        if self.prefill_rows * bucket * per_token <= _PREFILL_SCORE_BYTES:
+            return self.prefill_rows, bucket
+        if getattr(model, "scoreless_prefill", lambda rung: False)(bucket):
+            return 1, bucket
+        fit = max(1, _PREFILL_SCORE_BYTES // per_token)
+        chunk = max(c for c in range(1, min(fit, bucket) + 1)
+                    if bucket % c == 0)
+        return 1, chunk
+
     def decode_program(self, servable, attend_len: int):
         """The compiled decode step for length bucket ``attend_len``:
         ``(params, state, k, v, tokens[slots], positions[slots],
-        active[slots]) -> (logits[slots,V], k', v')``, cache donated.
+        active[slots]) -> (logits[slots,V], k', v', expert counts,
+        argmax ids[slots])``, cache donated.
         Each live slot writes its token's K/V at ``positions[s]`` and
         attends the first ``attend_len`` cache positions under the
         length-masked causal mask; inactive slots write into their own
@@ -290,15 +427,13 @@ class DecodeEngine:
             return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype))
 
         noop = lambda: None  # noqa: E731  on_trace hook, nothing to count
+        rows, sq = self.prefill_shape(model, bucket)
         return [
-            (f"prefill/{bucket}", self._prefill_jit(model, bucket,
-                                                    noop),
+            (f"prefill/{bucket}", self._prefill_jit(model, bucket, noop,
+                                                    sq == bucket),
              (params, state, k_spec, v_spec,
-              sds((self.prefill_rows, self.chunk_for(bucket)),
-                  np.int32),
-              sds((self.prefill_rows,), np.int32),
-              sds((self.prefill_rows,), np.int32),
-              sds((self.prefill_rows,), np.int32))),
+              sds((rows, sq), np.int32), sds((rows,), np.int32),
+              sds((rows,), np.int32), sds((rows,), np.int32))),
             (f"decode/{bucket}", self._decode_jit(model, bucket, noop),
              (params, state, k_spec, v_spec,
               sds((self.slots,), np.int32), sds((self.slots,), np.int32),
@@ -335,7 +470,7 @@ class DecodeEngine:
                              f"(prefill_rows={self.prefill_rows})")
         lens = [len(p) for p in prompts]
         bucket = self.ladder.bucket_for(max(lens))
-        sq = self.chunk_for(bucket)
+        rows, sq = self.prefill_shape(servable.model, bucket)
         starts = [0] * n if start is None else [int(s) for s in start]
         for i, s0 in enumerate(starts):
             if s0 % sq or not 0 <= s0 < lens[i]:
@@ -344,42 +479,67 @@ class DecodeEngine:
                     f"(chunk {sq}) below the prompt length {lens[i]}")
         prog = self.prefill_program(servable, bucket)
         out = [None] * n
-        for c in range(bucket // sq):
-            off = c * sq
-            tokens = np.zeros((self.prefill_rows, sq), np.int32)
-            last_in = np.ones((self.prefill_rows,), np.int32)
-            ids = np.full((self.prefill_rows,), self.slots,
-                          np.int32)  # OOB
-            offsets = np.zeros((self.prefill_rows,), np.int32)
-            live = False
-            for i, p in enumerate(prompts):
-                # a row rides chunk c while it still has tokens there
-                # and its seeded prefix doesn't already cover it
-                if lens[i] <= off or starts[i] > off:
+        # the batch goes through in groups of `rows` prompts (all of it
+        # at once unless the engine chunked the rung by itself)
+        for first in range(0, n, rows):
+            group = range(first, min(first + rows, n))
+            for c in range(bucket // sq):
+                off = c * sq
+                tokens = np.zeros((rows, sq), np.int32)
+                last_in = np.ones((rows,), np.int32)
+                ids = np.full((rows,), self.slots, np.int32)  # OOB
+                offsets = np.zeros((rows,), np.int32)
+                live = False
+                for r, i in enumerate(group):
+                    # a row rides chunk c while it still has tokens
+                    # there and its seeded prefix doesn't already
+                    # cover it
+                    if lens[i] <= off or starts[i] > off:
+                        continue
+                    live = True
+                    ids[r] = slot_ids[i]
+                    offsets[r] = off
+                    piece = np.asarray(prompts[i][off:off + sq], np.int32)
+                    tokens[r, :len(piece)] = piece
+                    last_in[r] = min(lens[i] - off, sq)
+                if not live:
                     continue
-                live = True
-                ids[i] = slot_ids[i]
-                offsets[i] = off
-                piece = np.asarray(p[off:off + sq], np.int32)
-                tokens[i, :len(piece)] = piece
-                last_in[i] = min(lens[i] - off, sq)
-            if not live:
-                continue
-            logits, kv.k, kv.v = prog(servable.params, servable.state,
-                                      kv.k, kv.v, tokens, last_in,
-                                      ids, offsets)
-            with telemetry.span("serving/prefill/device_wait"):
-                for i in range(n):
-                    if ids[i] != self.slots and (lens[i] - 1) // sq == c:
-                        out[i] = np.asarray(logits[i])
+                logits, kv.k, kv.v, stats = prog(
+                    servable.params, servable.state, kv.k, kv.v, tokens,
+                    last_in, ids, offsets)
+                with telemetry.span("serving/prefill/device_wait"):
+                    for r, i in enumerate(group):
+                        if (ids[r] != self.slots
+                                and (lens[i] - 1) // sq == c):
+                            out[i] = np.asarray(logits[r])
+                _record_moe(stats, "prefill")
         for i, slot in enumerate(slot_ids):
             kv.lengths[slot] = lens[i]
         return np.stack(out), bucket
 
+    def prefill_dispatches(self, model, bucket: int,
+                           lens: Sequence[int],
+                           starts: Sequence[int]) -> int:
+        """How many prefill program calls :meth:`prefill` makes for
+        prompts of ``lens`` (seeded up to ``starts``) at rung
+        ``bucket`` — the same walk, for the ``prefill_chunks``
+        counter."""
+        rows, sq = self.prefill_shape(model, bucket)
+        return sum(
+            1 for first in range(0, len(lens), rows)
+            for c in range(bucket // sq)
+            if any(l > c * sq and s0 <= c * sq for l, s0 in
+                   zip(lens[first:first + rows],
+                       starts[first:first + rows])))
+
     def decode(self, servable, kv: KVCache, tokens: np.ndarray,
-               positions: np.ndarray, active: np.ndarray):
+               positions: np.ndarray, active: np.ndarray,
+               ids_only: bool = False):
         """Run one decode step over every slot (one token per live
-        slot); returns the ``[slots, V]`` logits as a host ndarray.
+        slot); returns the ``[slots, V]`` logits as a host ndarray —
+        or, with ``ids_only`` (every live request greedy), the
+        ``[slots]`` argmax ids the program computed, and the logits
+        never leave the device.
         ``attend_len`` is re-bucketed from the longest live row each
         step, so a batch of short sequences runs the small-rung
         program.
@@ -394,10 +554,10 @@ class DecodeEngine:
         bucket compile bound holds with kernels on (asserted in
         tests/test_kernels.py)."""
         return self._step(self.decode_program, servable, kv, tokens,
-                          positions, active, 1)
+                          positions, active, 1, ids_only)
 
     def _step(self, program_for, servable, kv: KVCache, tokens, positions,
-              active, width: int):
+              active, width: int, ids_only: bool = False):
         """One decode or verify step in three host spans under the
         caller's ``serving/decode``: the launch (bucket choice, casts,
         the program call returning), the wait on the device, and the
@@ -411,14 +571,16 @@ class DecodeEngine:
                        if active.any() else width)
             attend_len = self.ladder.bucket_for(longest)
             prog = program_for(servable, attend_len)
-            logits, kv.k, kv.v = prog(
+            logits, kv.k, kv.v, stats, *ids = prog(
                 servable.params, servable.state, kv.k, kv.v,
                 tokens.astype(np.int32), positions.astype(np.int32),
                 active.astype(bool))
+            wanted = ids[0] if ids_only else logits
         with telemetry.span("serving/decode/device_wait"):
-            jax.block_until_ready(logits)
+            jax.block_until_ready(wanted)
         with telemetry.span("serving/decode/logits_d2h"):
-            host = np.asarray(logits)
+            host = np.asarray(wanted)
+            _record_moe(stats, "decode")
         return host, attend_len
 
     # -------------------------------------------------------- warmup
@@ -439,27 +601,28 @@ class DecodeEngine:
             kv = KVCache.for_model(servable.model, self.slots,
                                    self.ladder.max_batch_size, kv_dtype)
         before = self.compile_count(servable)
-        drop_ids = np.full((self.prefill_rows,), self.slots, np.int32)
-        lens1 = np.ones((self.prefill_rows,), np.int32)
-        zero_off = np.zeros((self.prefill_rows,), np.int32)
         dec_tokens = np.zeros((self.slots,), np.int32)
         dec_pos = np.zeros((self.slots,), np.int32)
         inactive = np.zeros((self.slots,), bool)
         for rung in self.ladder:
             pre = self.prefill_program(servable, rung)
-            # the token width serving will actually feed this rung —
-            # the chunk for rungs past prefill_chunk — so a live
-            # chunked admission never re-traces
-            prompts = np.zeros((self.prefill_rows,
-                                self.chunk_for(rung)), np.int32)
+            # the rows and token width serving will actually feed this
+            # rung — the chunk for rungs past prefill_chunk, or the
+            # engine's own pieces of a rung too wide for one shot — so
+            # a live admission never re-traces
+            rows, sq = self.prefill_shape(servable.model, rung)
+            prompts = np.zeros((rows, sq), np.int32)
             # warmup exists to GATE on both programs of every rung
             # before the version takes traffic
-            _, kv.k, kv.v = pre(servable.params, servable.state, kv.k,
-                                kv.v, prompts, lens1, drop_ids,
-                                zero_off)
+            _, kv.k, kv.v, _ = pre(
+                servable.params, servable.state, kv.k, kv.v, prompts,
+                np.ones((rows,), np.int32),
+                np.full((rows,), self.slots, np.int32),
+                np.zeros((rows,), np.int32))
             dec = self.decode_program(servable, rung)
-            out, kv.k, kv.v = dec(servable.params, servable.state, kv.k,
-                                  kv.v, dec_tokens, dec_pos, inactive)
+            out, kv.k, kv.v, *_ = dec(
+                servable.params, servable.state, kv.k, kv.v, dec_tokens,
+                dec_pos, inactive)
             jax.block_until_ready(out)  # bigdl: disable=sync-in-loop
         return self.compile_count(servable) - before
 

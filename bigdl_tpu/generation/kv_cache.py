@@ -2,8 +2,9 @@
 
 The decode engine's whole memory story is ONE allocation per model
 version: per layer one K and one V array ``[slots, heads, head_dim,
-max_len]`` (``max_len`` already padded to the top rung of the
-service's length ladder), an explicit per-slot ``lengths`` vector, and
+columns]`` (``columns`` is ``max_len``, already padded to the top rung
+of the service's length ladder, or a sliding-window layer's ring), an
+explicit per-slot ``lengths`` vector, and
 a host-side alloc/free bitmap. Requests *occupy slots* — admission is a
 bitmap ``alloc()``, eviction a ``free()`` — so continuous batching never
 reshapes or reallocates device memory, which is exactly what keeps the
@@ -76,18 +77,28 @@ class SlotAllocator:
 class KVCache:
     """One model version's preallocated decode cache.
 
-    ``k``/``v`` are tuples of ``layers`` device arrays ``[slots, heads,
-    head_dim, max_len]`` threaded (donated) through every
-    prefill/decode program call; ``lengths`` is the explicit host-side
-    int32 vector of per-slot sequence lengths (= the next write
-    position), and ``allocator`` the slot bitmap. A freed slot's
-    columns are NOT zeroed: every position a future occupant can attend
-    is re-written (prompt region by its prefill, each generated
+    ``k``/``v`` are tuples of ``layers`` device arrays threaded
+    (donated) through every prefill/decode program call; ``lengths`` is
+    the explicit host-side int32 vector of per-slot sequence lengths (=
+    the next write position), and ``allocator`` the slot bitmap. A freed
+    slot's columns are NOT zeroed: every position a future occupant can
+    attend is re-written (prompt region by its prefill, each generated
     position by the decode step that produces it) before the
-    length-masked causal mask ever exposes it."""
+    length-masked causal mask ever exposes it.
+
+    **Entries of a declared kind per layer.** ``layout`` is one ``(kv
+    heads, head dim, columns)`` triple a layer; layer ``i``'s arrays are
+    ``[slots, heads_i, head_dim_i, columns_i]``. A layer that attends
+    its whole prefix keeps ``columns = max_len`` (position ``p`` at
+    column ``p``); a sliding-window layer keeps a RING of ``window``
+    columns (position ``p`` at column ``p mod window``; its attention
+    layer owns that rule, ``nn.attention.GroupedQueryAttention``). The
+    layout comes from the model (``cache_layout(max_len)``); a model
+    that declares none gets the uniform ``[slots, num_heads, head_dim,
+    max_len]`` of a plain multi-head decoder."""
 
     def __init__(self, layers: int, slots: int, heads: int, max_len: int,
-                 head_dim: int, dtype=None):
+                 head_dim: int, dtype=None, layout=None):
         import jax.numpy as jnp
 
         from bigdl_tpu.utils.engine import Engine
@@ -98,23 +109,41 @@ class KVCache:
         self.max_len = max_len
         self.head_dim = head_dim
         self.dtype = dtype if dtype is not None else Engine.default_dtype()
-        shape = (slots, heads, head_dim, max_len)
-        self.k = tuple(jnp.zeros(shape, self.dtype)
-                       for _ in range(layers))
-        self.v = tuple(jnp.zeros(shape, self.dtype)
-                       for _ in range(layers))
+        self.layout = self._layout(layers, heads, max_len, head_dim,
+                                   layout)
+        # of the first layer's entry: K/V heads, which a grouped-query
+        # model has fewer of than the query heads it declares
+        self.heads, self.head_dim = self.layout[0][:2]
+        self.k = tuple(jnp.zeros((slots,) + e, self.dtype)
+                       for e in self.layout)
+        self.v = tuple(jnp.zeros((slots,) + e, self.dtype)
+                       for e in self.layout)
         self.lengths = np.zeros((slots,), np.int32)
         self.allocator = SlotAllocator(slots)
 
+    @staticmethod
+    def _layout(layers, heads, max_len, head_dim, layout) -> tuple:
+        if layout is None:
+            return ((heads, head_dim, max_len),) * layers
+        layout = tuple(tuple(int(n) for n in e) for e in layout)
+        if len(layout) != layers or any(
+                len(e) != 3 or not 1 <= e[2] <= max_len for e in layout):
+            raise ValueError(
+                f"cache layout {layout} does not describe {layers} "
+                f"layers of at most {max_len} columns")
+        return layout
+
     @classmethod
     def _model_geometry(cls, model, slots: int, max_len: int) -> tuple:
-        """The ``(layers, slots, heads, max_len, head_dim)`` cache
-        geometry (the constructor's arguments) a decoder model's
-        declared geometry (``num_layers``/``num_heads``/``head_dim`` or
-        ``hidden_size``) implies — ONE
-        derivation (and positional-table bound) shared by
-        :meth:`for_model` and :meth:`spec_for_model`, so the verified
-        program shapes can never drift from the allocated ones."""
+        """The ``(layers, slots, heads, max_len, head_dim, dtype,
+        layout)`` cache geometry (the constructor's arguments) a decoder
+        model's declared geometry implies: ``cache_layout(max_len)`` /
+        ``cache_dtype()`` where the model has layers of several kinds,
+        else ``num_layers``/``num_heads``/``head_dim`` or
+        ``hidden_size`` — ONE derivation (and positional-table bound)
+        shared by :meth:`for_model` and :meth:`spec_for_model`, so the
+        verified program shapes can never drift from the allocated
+        ones."""
         layers = int(model.num_layers)
         heads = int(model.num_heads)
         head_dim = int(getattr(model, "head_dim",
@@ -123,14 +152,21 @@ class KVCache:
             raise ValueError(
                 f"cache max_len={max_len} exceeds the model's positional "
                 f"table ({model.max_len})")
-        return (layers, slots, heads, max_len, head_dim)
+        declared = getattr(model, "cache_layout", None)
+        layout = declared(max_len) if declared is not None else None
+        dtype = getattr(model, "cache_dtype", lambda: None)()
+        return (layers, slots, heads, max_len, head_dim, dtype, layout)
 
     @classmethod
     def for_model(cls, model, slots: int, max_len: int,
                   dtype=None) -> "KVCache":
         """Size a cache from a decoder model's declared geometry,
-        e.g. a :class:`~bigdl_tpu.models.transformer.TransformerLM`."""
-        return cls(*cls._model_geometry(model, slots, max_len), dtype)
+        e.g. a :class:`~bigdl_tpu.models.transformer.TransformerLM`.
+        ``dtype`` overrides what the model declares."""
+        *geom, declared, layout = cls._model_geometry(model, slots,
+                                                       max_len)
+        return cls(*geom, dtype if dtype is not None else declared,
+                   layout)
 
     @classmethod
     def spec_for_model(cls, model, slots: int, max_len: int,
@@ -144,12 +180,28 @@ class KVCache:
 
         from bigdl_tpu.utils.engine import Engine
 
-        layers, slots, heads, max_len, head_dim = cls._model_geometry(
-            model, slots, max_len)
-        dt = dtype if dtype is not None else Engine.default_dtype()
-        layer = jax.ShapeDtypeStruct((slots, heads, head_dim, max_len),
-                                     dt)
-        return (layer,) * layers, (layer,) * layers
+        layers, slots, heads, max_len, head_dim, declared, layout = \
+            cls._model_geometry(model, slots, max_len)
+        dt = dtype if dtype is not None else (
+            declared if declared is not None else Engine.default_dtype())
+        spec = tuple(jax.ShapeDtypeStruct((slots,) + e, dt) for e in
+                     cls._layout(layers, heads, max_len, head_dim,
+                                 layout))
+        return spec, spec
+
+    @property
+    def uniform(self) -> bool:
+        """Every layer's entry has the same shape (one kind)."""
+        return len(set(self.layout)) == 1
+
+    def kind_bytes(self) -> dict:
+        """Device bytes by kind of entry: ``window`` (rings shorter
+        than ``max_len``) and ``global`` (every position kept)."""
+        out = {"window": 0, "global": 0}
+        for e, a, b in zip(self.layout, self.k, self.v):
+            kind = "window" if e[2] < self.max_len else "global"
+            out[kind] += int(a.nbytes) + int(b.nbytes)
+        return out
 
     def occupancy(self) -> float:
         """Live-slot fraction (the ``cache_occupancy`` gauge)."""

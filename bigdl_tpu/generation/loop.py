@@ -470,7 +470,8 @@ class DecodeLoop:
             self._h_prefill_fill.observe(
                 len(misses) / self._engine.prefill_rows, **self._labels)
             self._c_prefill_chunks.inc(
-                self._chunks_dispatched(bucket, misses, starts),
+                self._chunks_dispatched(servable.model, bucket, misses,
+                                        starts),
                 **self._labels)
             if self._prefix is not None:
                 ladder = self._engine.ladder
@@ -501,18 +502,15 @@ class DecodeLoop:
                                          time.monotonic())
         self._g_occupancy.set(group.kv.occupancy(), **self._labels)
 
-    def _chunks_dispatched(self, bucket: int, misses: List[_Gen],
+    def _chunks_dispatched(self, model, bucket: int, misses: List[_Gen],
                            starts: List[int]) -> int:
         """How many prefill program dispatches the engine just ran for
-        this batch — mirrors :meth:`DecodeEngine.prefill`'s chunk
-        loop (a chunk runs iff some row still has tokens there that
-        its seeded prefix doesn't already cover), feeding the
+        this batch (a chunk runs iff some row still has tokens there
+        that its seeded prefix doesn't already cover), feeding the
         ``prefill_chunks`` counter."""
-        sq = self._engine.chunk_for(bucket)
-        lens = [int(g.prompt.shape[0]) for g in misses]
-        return sum(1 for c in range(bucket // sq)
-                   if any(l > c * sq and s <= c * sq
-                          for l, s in zip(lens, starts)))
+        return self._engine.prefill_dispatches(
+            model, bucket, [int(g.prompt.shape[0]) for g in misses],
+            starts)
 
     def _request_tracks_prefill(self, gens: List[_Gen], t0: float,
                                 t1: float, t2: float) -> None:
@@ -573,10 +571,17 @@ class DecodeLoop:
             faults.point("serving/decode", model=self._name,
                          slots=len(live))
             t0 = time.monotonic()
+            # a step whose requests are all greedy takes the program's
+            # own argmax: [slots] ids cross to the host, not the
+            # [slots, V] logits (sampling parameters stay operands of
+            # nothing: a sampled request simply asks for the logits)
+            greedy = all(group.gens[s].sampler.params.temperature <= 0.0
+                         for s in live)
             with telemetry.span("serving/decode", model=self._name,
                                 slots=len(live)):
                 logits, attend_len = self._engine.decode(
-                    group.servable, kv, tokens, positions, active)
+                    group.servable, kv, tokens, positions, active,
+                    ids_only=greedy)
             now = time.monotonic()
             per_token_ms = (now - t0) * 1000.0 / len(live)
             self._h_token.observe(per_token_ms, **self._labels)
@@ -597,14 +602,15 @@ class DecodeLoop:
                               "attend_len": attend_len})
             with telemetry.span("serving/sample", model=self._name,
                                 slots=len(live)):
-                self._sample_and_emit(group, live, logits, now)
+                self._sample_and_emit(group, live, logits, now, greedy)
             self._g_occupancy.set(group.kv.occupancy(), **self._labels)
 
     def _sample_and_emit(self, group: _Group, live: List[int], logits,
-                         now: float) -> None:
+                         now: float, ids: bool = False) -> None:
         """The per-slot sweep after a decode step: preempt and deadline
         checks, host sampling, delivery (callers' callbacks run in
-        ``_emit``)."""
+        ``_emit``). ``ids``: ``logits`` holds the step's ``[slots]``
+        greedy token ids, not logits rows."""
         kv = group.kv
         for slot in live:
             g = group.gens[slot]
@@ -626,7 +632,8 @@ class DecodeLoop:
                     f"after {g.produced} tokens"))
                 self._release(group, g)
                 continue
-            self._emit(group, g, g.sampler.sample(logits[slot]))
+            self._emit(group, g, int(logits[slot]) if ids
+                       else g.sampler.sample(logits[slot]))
 
     def _emit(self, group: _Group, g: _Gen, token: int) -> None:
         """Deliver one sampled token and apply the eviction rules

@@ -3,7 +3,11 @@
 Sampling runs on the host over the ``[V]`` logits row each program
 returns — per-request temperature/top-k/seed therefore never become
 program shapes (one request asking for ``top_k=7`` must not compile a
-new decode program), and determinism is trivial: each request owns a
+new decode program). One case needs no row: the decode program also
+returns every slot's argmax, and a step whose live requests are all
+greedy takes those ``[slots]`` ids and leaves the ``[slots, V]``
+logits on the device (``DecodeEngine.decode(ids_only=True)``; ties to
+the lowest id on both sides). Determinism is trivial: each request owns a
 ``numpy`` PCG64 generator seeded at submit, so the same (weights,
 prompt, sampling params, seed) always yields the same token stream, on
 any platform.

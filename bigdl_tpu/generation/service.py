@@ -40,6 +40,14 @@ from bigdl_tpu.serving.compile_cache import BucketLadder, CompileCache
 from bigdl_tpu.serving.registry import ModelRegistry, Servable
 
 
+_G_WINDOW_BYTES = telemetry.gauge(
+    "serving/cache/window_bytes",
+    "device bytes of the newest cache's sliding-window (ring) entries")
+_G_GLOBAL_BYTES = telemetry.gauge(
+    "serving/cache/global_bytes",
+    "device bytes of the newest cache's whole-context entries")
+
+
 @dataclass
 class GenerationConfig:
     """Tuning surface (docs/serving.md "Generation" has the math).
@@ -181,8 +189,7 @@ class GenerationService:
             # warm into the cache the decode loop will ADOPT at this
             # version's first admission — one full-size K/V allocation
             # per version, not one for warmup plus one for serving
-            kv = KVCache.for_model(servable.model, self.config.slots,
-                                   self.config.max_len)
+            kv = self._new_cache(servable)
             self.engine.warmup(servable, kv=kv)
             with self._lock:
                 # at most ONE stashed cache per name: a previously
@@ -277,8 +284,22 @@ class GenerationService:
             kv = self._warm_caches.pop(servable.key, None)
         if kv is not None:
             return kv
-        return KVCache.for_model(servable.model, self.config.slots,
-                                 self.config.max_len)
+        return self._new_cache(servable)
+
+    def _new_cache(self, servable) -> KVCache:
+        """One version's cache, its entries of the kinds the model
+        declares; the bytes by kind go to the always-on gauges."""
+        kv = KVCache.for_model(servable.model, self.config.slots,
+                               self.config.max_len)
+        if self.prefix is not None and not kv.uniform:
+            raise ValueError(
+                f"{servable.name!r} keeps cache entries of several kinds "
+                f"({sorted(set(kv.layout))}); the prefix cache stores one "
+                "kind of block (prefix_cache_bytes=0 serves it)")
+        by_kind = kv.kind_bytes()
+        _G_WINDOW_BYTES.set(by_kind["window"], model=servable.name)
+        _G_GLOBAL_BYTES.set(by_kind["global"], model=servable.name)
+        return kv
 
     def generate(self, name: str, prompt, *,
                  max_new_tokens: Optional[int] = None,

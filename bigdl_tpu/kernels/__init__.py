@@ -13,11 +13,15 @@ ONE gate in front of them:
   KV per slot instead of the bucket max;
 - :mod:`~bigdl_tpu.kernels.int8_gemm` — fused dequant-int8-GEMM
   completing the BigQuant serving story over the calibrated scales;
+- :mod:`~bigdl_tpu.kernels.moe_gmm` — the routed expert layer's
+  grouped product over sorted, tile-aligned token-expert pairs: an
+  expert no pair fell on is never read;
 - :mod:`~bigdl_tpu.kernels.dispatch` — :func:`attention` /
-  :func:`decode_attention` / :func:`int8_matmul`: config + shape
+  :func:`decode_attention` / :func:`int8_matmul` /
+  :func:`grouped_matmul`: config + shape
   eligibility in, kernel result or None (= run your jnp path) out;
 - :mod:`~bigdl_tpu.kernels.config` — :class:`KernelConfig` and the
-  ``BIGDL_KERNELS`` env toggle; decode + int8 default ON on real TPU
+  ``BIGDL_KERNELS`` env toggle; decode + int8 + gmm default ON on real TPU
   (flash stays opt-in until the bench KERNELS trajectory justifies
   it), everything OFF on CPU, and kernels run under the pallas
   *interpreter* everywhere but real TPU so tier-1 on CPU executes the
@@ -34,8 +38,8 @@ from bigdl_tpu.kernels.config import (KernelConfig, active_label,
                                       configure, enabled, get_config,
                                       interpret_mode, use)
 from bigdl_tpu.kernels.dispatch import (attention, decode_attention,
-                                        int8_matmul)
+                                        grouped_matmul, int8_matmul)
 
 __all__ = ["KernelConfig", "configure", "get_config", "use", "enabled",
            "interpret_mode", "active_label", "attention",
-           "decode_attention", "int8_matmul"]
+           "decode_attention", "int8_matmul", "grouped_matmul"]

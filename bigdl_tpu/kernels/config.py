@@ -5,7 +5,7 @@ SURVEY.md §1); ours is a *runtime policy*: a :class:`KernelConfig`
 names which pallas kernels the dispatch layer
 (:mod:`bigdl_tpu.kernels.dispatch`) may select, everything else runs
 the pure-jnp reference path. The default is resolved lazily from the
-backend — **decode + int8 on on real TPU** (they skip work the
+backend — **decode + int8 + gmm on on real TPU** (they skip work the
 reference cannot skip), **flash opt-in even there** (XLA's fused einsum
 stays the default at the lengths it can hold until a measurement on
 today's code says otherwise — ROADMAP A5), **everything off on CPU** —
@@ -14,7 +14,7 @@ and the ``BIGDL_KERNELS`` env var overrides it without touching code:
 - ``BIGDL_KERNELS=1`` / ``on`` / ``all`` — every kernel on;
 - ``BIGDL_KERNELS=0`` / ``off`` — every kernel off;
 - ``BIGDL_KERNELS=flash,decode`` — a comma subset of
-  ``flash`` / ``decode`` / ``int8``.
+  ``flash`` / ``decode`` / ``int8`` / ``gmm``.
 
 ``interpret`` (``None`` = auto) runs the kernels through the pallas
 interpreter instead of Mosaic — auto means *interpret everywhere but
@@ -45,7 +45,7 @@ __all__ = ["KernelConfig", "configure", "get_config", "use", "enabled",
 logger = logging.getLogger("bigdl_tpu")
 
 #: the ops a config can enable, in the order the env parser accepts
-_OPS = ("flash", "decode", "int8")
+_OPS = ("flash", "decode", "int8", "gmm")
 
 
 @dataclass(frozen=True)
@@ -55,7 +55,8 @@ class KernelConfig:
     ``flash_attention`` — the tiled flash-attention training kernel;
     ``decode_attention`` — the ragged decode kernel (reads only
     ``lengths[i]`` valid KV per slot); ``int8_matmul`` — the fused
-    dequant-int8-GEMM serving kernel. ``interpret=None`` auto-selects
+    dequant-int8-GEMM serving kernel; ``grouped_matmul`` — the routed
+    expert layer's grouped product. ``interpret=None`` auto-selects
     the pallas interpreter off-TPU; ``block_q``/``block_k`` are
     preferred tile sizes (shrunk to the largest divisor of the actual
     dimension, so ragged test shapes stay eligible)."""
@@ -63,6 +64,7 @@ class KernelConfig:
     flash_attention: bool = False
     decode_attention: bool = False
     int8_matmul: bool = False
+    grouped_matmul: bool = False
     interpret: Optional[bool] = None
     block_q: int = 128
     block_k: int = 128
@@ -80,11 +82,11 @@ class KernelConfig:
     @classmethod
     def all_on(cls, **kw) -> "KernelConfig":
         """Every kernel enabled — ``BIGDL_KERNELS=1`` and the test/
-        bench on-legs. (The real-TPU *default* is decode + int8 only;
+        bench on-legs. (The real-TPU *default* is decode + int8 + gmm;
         flash stays opt-in there until a measurement justifies the
         flip — see the module docstring.)"""
         return cls(flash_attention=True, decode_attention=True,
-                   int8_matmul=True, **kw)
+                   int8_matmul=True, grouped_matmul=True, **kw)
 
     @classmethod
     def off(cls) -> "KernelConfig":
@@ -111,13 +113,14 @@ class KernelConfig:
                 "or 1/on/all, 0/off)")
         return cls(flash_attention="flash" in ops,
                    decode_attention="decode" in ops,
-                   int8_matmul="int8" in ops)
+                   int8_matmul="int8" in ops,
+                   grouped_matmul="gmm" in ops)
 
     @property
     def any_enabled(self) -> bool:
         """Whether any kernel is selected at all."""
         return (self.flash_attention or self.decode_attention
-                or self.int8_matmul)
+                or self.int8_matmul or self.grouped_matmul)
 
     def resolve_interpret(self) -> bool:
         """The effective interpret flag: auto (``None``) means
@@ -164,13 +167,15 @@ def _default() -> KernelConfig:
         # flash stays OPT-IN on TPU (XLA's fused einsum is the default
         # at every length it can hold) — promote it via
         # BIGDL_KERNELS=1/flash once a measurement justifies the flip
-        cfg = KernelConfig(decode_attention=True, int8_matmul=True)
+        cfg = KernelConfig(decode_attention=True, int8_matmul=True,
+                           grouped_matmul=True)
     else:
         cfg = KernelConfig.off()
     if cfg.any_enabled:
         on = [op for op, flag in zip(_OPS, (cfg.flash_attention,
                                             cfg.decode_attention,
-                                            cfg.int8_matmul)) if flag]
+                                            cfg.int8_matmul,
+                                            cfg.grouped_matmul)) if flag]
         logger.info(
             "kernel policy: %s on backend %r, %s", "+".join(on), backend,
             "in the pallas INTERPRETER (no TPU backend)"
@@ -212,13 +217,14 @@ def use(config: KernelConfig) -> Iterator[KernelConfig]:
 
 
 def enabled(op: str) -> bool:
-    """Whether kernel ``op`` (``flash`` | ``decode`` | ``int8``) is
+    """Whether kernel ``op`` (``flash`` | ``decode`` | ``int8`` | ``gmm``) is
     enabled under the active config."""
     cfg = get_config()
     try:
         return {"flash": cfg.flash_attention,
                 "decode": cfg.decode_attention,
-                "int8": cfg.int8_matmul}[op]
+                "int8": cfg.int8_matmul,
+                "gmm": cfg.grouped_matmul}[op]
     except KeyError:
         raise ValueError(f"unknown kernel op {op!r} "
                          f"(choose from {list(_OPS)})") from None

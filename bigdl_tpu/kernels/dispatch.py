@@ -14,7 +14,7 @@ configuration is byte-identical to the pre-kernel tree.
 Dispatch decisions happen at TRACE time (config and shapes are
 static), so the per-trace counters below count compiled-program
 routing, not per-step calls: ``kernels/dispatch/pallas`` (label
-``op=flash|decode|int8``) vs ``kernels/dispatch/reference`` (labels
+``op=flash|decode|int8|gmm``) vs ``kernels/dispatch/reference`` (labels
 ``op=...`` plus ``reason=config|shape|vmem`` so a `diagnose` dump
 attributes every decline).
 """
@@ -30,7 +30,7 @@ from bigdl_tpu.kernels import config as _config
 from bigdl_tpu.kernels.common import fit_block, sublanes
 
 __all__ = ["attention", "decode_attention", "paged_decode_attention",
-           "int8_matmul", "taken_in_thread"]
+           "int8_matmul", "grouped_matmul", "taken_in_thread"]
 
 # module-level registration so `tools.check --telemetry-audit` sees the
 # REAL instruments on import, not a hand-maintained name list
@@ -143,7 +143,8 @@ def attention(q, k, v, *, causal: bool = False, segment_ids=None,
 def decode_attention(q, k, v, lengths, *, attend_len: int = None,
                      sm_scale: Optional[float] = None):
     """Ragged-decode dispatch: ``q [slots, H, D]`` (one token per
-    slot), ``k``/``v`` one layer's whole ``[slots, H, D, T]`` cache,
+    slot), ``k``/``v`` one layer's whole ``[slots, Hkv, D, T]`` cache
+    (``H`` a multiple of ``Hkv``: grouped-query attention),
     ``lengths`` the host per-slot valid-KV vector, ``attend_len`` the
     (static) ladder rung. Returns the kernel result
     (:mod:`bigdl_tpu.kernels.ragged_decode` — reads only
@@ -153,8 +154,9 @@ def decode_attention(q, k, v, lengths, *, attend_len: int = None,
     if not _config.enabled("decode"):
         _declined("decode", "config")
         return None
-    if (k.ndim != 4 or v.shape != k.shape or q.shape != k.shape[:3]
-            or not _floating(q, k, v)):
+    if (k.ndim != 4 or v.shape != k.shape or q.ndim != 3
+            or q.shape[0] != k.shape[0] or q.shape[2] != k.shape[2]
+            or q.shape[1] % k.shape[1] or not _floating(q, k, v)):
         _declined("decode", "shape")
         return None
     from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
@@ -236,3 +238,65 @@ def int8_matmul(x_q, w_q, x_scale, w_scale, bias=None):
         # costs a one-ulp FMA drift vs the reference; int8_gemm.py)
         out = out + bias.reshape(1, -1).astype(jnp.float32)
     return out
+
+
+def _grouped_reference(x, w, tile_expert, tile_m: int):
+    """The grouped product in plain jnp: every row tile times its
+    expert's matrix (gathered a tile at a time). Rows of tiles that hold
+    no pair are computed like any other; the layer never reads them."""
+    tiles = x.shape[0] // tile_m
+    out = jnp.einsum("tmk,tkn->tmn", x.reshape(tiles, tile_m, x.shape[1]),
+                     w[tile_expert])
+    return out.reshape(x.shape[0], w.shape[2]).astype(x.dtype)
+
+
+def grouped_matmul(x, w, tile_expert, num_tiles, *, tile_m: int):
+    """The routed expert layer's grouped product: ``x [M, K]`` (sorted
+    token-expert pairs, every expert's run on whole ``tile_m``-row
+    tiles), ``w [E, K, N]``, ``tile_expert [M / tile_m]``, ``num_tiles
+    [1]`` the live leading tiles. Always returns ``[M, N]`` (rows of
+    dead tiles: zeros from the kernel, a product like any other from
+    the plain form; nothing reads them): the pallas kernel
+    (:mod:`bigdl_tpu.kernels.moe_gmm`) when ``gmm`` is enabled and the
+    shapes tile, else the jnp form above. Unlike the other dispatchers
+    it owns its fallback, because the kernel's backward pass IS the
+    fallback's (a forward kernel, differentiated through the plain
+    form), so the two must stay one definition."""
+    import jax
+
+    from bigdl_tpu.kernels.common import sublanes
+
+    if not _config.enabled("gmm"):
+        _declined("gmm", "config")
+        return _grouped_reference(x, w, tile_expert, tile_m)
+    interpret = _config.get_config().resolve_interpret()
+    k, n = w.shape[1], w.shape[2]
+    if (x.ndim != 2 or w.ndim != 3 or not _floating(x, w)
+            or x.dtype != w.dtype
+            or (not interpret and (tile_m % sublanes(x.dtype)
+                                   or k % 128 or n % 128))):
+        _declined("gmm", "shape")
+        return _grouped_reference(x, w, tile_expert, tile_m)
+    from bigdl_tpu.kernels.moe_gmm import grouped_matmul_pallas
+
+    _taken("gmm")
+
+    @jax.custom_vjp
+    def gmm(x, w, te, nt):
+        return grouped_matmul_pallas(x, w, te, nt, tile_m=tile_m,
+                                     interpret=interpret)
+
+    def fwd(x, w, te, nt):
+        return gmm(x, w, te, nt), (x, w, te)
+
+    def bwd(saved, g):
+        # rows of dead tiles carry no gradient: the layer gathers only
+        # live rows, so their cotangent is zero already
+        x, w, te = saved
+        _, pull = jax.vjp(
+            lambda a, b: _grouped_reference(a, b, te, tile_m), x, w)
+        return pull(g) + (None, None)
+
+    gmm.defvjp(fwd, bwd)
+    return gmm(x, w, tile_expert.astype(jnp.int32),
+               num_tiles.astype(jnp.int32))

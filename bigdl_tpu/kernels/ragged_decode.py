@@ -22,7 +22,12 @@ sliced, transposed or copied between the cache and the kernel. Scores
 are ``q[1, D] @ k[D, block_k]``; the value product contracts the lane
 axis of ``p[1, block_k]`` with ``v[D, block_k]``.
 
-One token per slot (decode's shape), grid ``(slots, heads)``; used
+K/V heads may be fewer than query heads (grouped-query attention):
+the grid runs over the K/V heads and a program's query block is the
+``[G, D]`` group that shares its K/V head, so every cached column is
+read once for its ``G`` queries. ``G`` = 1 is multi-head attention.
+
+One token per slot (decode's shape), grid ``(slots, kv heads)``; used
 through :func:`bigdl_tpu.kernels.decode_attention`, which owns
 eligibility and the jnp fallback.
 """
@@ -47,7 +52,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *,
                    block_k: int, k_tiles: int, sm_scale: float):
     slot = pl.program_id(0)
     n = len_ref[slot]                                   # valid KV columns
-    q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # [1, D]
+    q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # [G, D]
 
     def body(i, carry):
         m, l, acc = carry
@@ -61,7 +66,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *,
                                 preferred_element_type=jnp.float32)
         col = start + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        s = jnp.where(col < n, s, _NEG_INF)
+        s = jnp.where(col < n, s, _NEG_INF)             # [G, block_k]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # first tile: m = -inf, m_new finite (col 0 < n always) so
         # alpha underflows to an exact 0 and the zero-initialized
@@ -77,10 +82,10 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *,
             preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    d = q.shape[-1]
-    m0 = jnp.full((1, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((1, 1), jnp.float32)
-    acc0 = jnp.zeros((1, d), jnp.float32)
+    g, d = q.shape
+    m0 = jnp.full((g, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((g, 1), jnp.float32)
+    acc0 = jnp.zeros((g, d), jnp.float32)
     if k_tiles == 1:
         # the block is one tile (1 <= n <= attend_len always): a static
         # slice — a block narrower than a vector tile's 128 lanes has
@@ -97,7 +102,9 @@ def ragged_decode_attention(q, k, v, lengths, *, attend_len: int = None,
                             interpret: bool = False):
     """One decode step of attention over ragged KV: ``q`` is
     ``[slots, H, D]`` (the step's single token per slot), ``k``/``v``
-    are one layer's WHOLE cache ``[slots, H, D, T]`` — time on the
+    are one layer's WHOLE cache ``[slots, Hkv, D, T]`` (``H`` a multiple
+    of ``Hkv``; query heads ``j G .. (j + 1) G - 1`` share K/V head
+    ``j``) — time on the
     lanes, the form :class:`~bigdl_tpu.generation.kv_cache.KVCache`
     stores, so nothing is sliced or transposed on the way in —
     ``lengths`` the host int32 ``[slots]`` of valid columns per slot
@@ -109,9 +116,11 @@ def ragged_decode_attention(q, k, v, lengths, *, attend_len: int = None,
     from jax.experimental.pallas import tpu as pltpu
 
     slots, h, d, t = k.shape
-    if q.shape != (slots, h, d) or v.shape != k.shape:
+    if (q.ndim != 3 or q.shape[0] != slots or q.shape[2] != d
+            or q.shape[1] % h or v.shape != k.shape):
         raise ValueError(f"q {q.shape} / v {v.shape} do not match "
                          f"cache [{slots},{h},{d},{t}]")
+    g = q.shape[1] // h
     al = t if attend_len is None else int(attend_len)
     if not 1 <= al <= t:
         raise ValueError(f"attend_len={al} outside [1, {t}]")
@@ -128,10 +137,10 @@ def ragged_decode_attention(q, k, v, lengths, *, attend_len: int = None,
     kernel = functools.partial(_decode_kernel, block_k=block_k,
                                k_tiles=a // block_k,
                                sm_scale=float(sm_scale))
-    # q and the output travel as [slots, H, 1, D]: Mosaic wants the
+    # q and the output travel as [slots, Hkv, G, D]: Mosaic wants the
     # last two dims of a block to be (8, 128)-aligned or the whole
-    # array's, and a (1, D) tile of a [.., 1, D] array is the latter
-    row = pl.BlockSpec((1, 1, 1, d), lambda s, h_: (s, h_, 0, 0))
+    # array's, and a (G, D) tile of a [.., G, D] array is the latter
+    row = pl.BlockSpec((1, 1, g, d), lambda s, h_: (s, h_, 0, 0))
     cache = pl.BlockSpec((1, 1, d, a), lambda s, h_: (s, h_, 0, 0))
     out = pl.pallas_call(
         kernel,
@@ -139,8 +148,8 @@ def ragged_decode_attention(q, k, v, lengths, *, attend_len: int = None,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), row, cache,
                   cache],
         out_specs=row,
-        out_shape=jax.ShapeDtypeStruct((slots, h, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, h, g, d), q.dtype),
         interpret=interpret,
         name="bigdl_ragged_decode",
-    )(lengths, q[:, :, None, :], k, v)
-    return out[:, :, 0, :]
+    )(lengths, q.reshape(slots, h, g, d), k, v)
+    return out.reshape(slots, h * g, d)

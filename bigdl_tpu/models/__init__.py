@@ -11,3 +11,4 @@ from bigdl_tpu.models.autoencoder import Autoencoder
 from bigdl_tpu.models.transformer import (TransformerBlock, TransformerLM,
                                           FeedForward)
 from bigdl_tpu.models.transformer.pipelined import PipelinedTransformerLM
+from bigdl_tpu.models.transformer.decoder import PatternDecoderLM

@@ -18,16 +18,21 @@ from bigdl_tpu.nn.module import Module
 from bigdl_tpu.utils.engine import Engine
 
 
-def _flash_attention_tpu(q, k, v, causal: bool):
+def _flash_attention_tpu(q, k, v, causal: bool, block: int = None):
     """jax's bundled pallas flash attention — O(S) memory, no
     materialized [S,S] score matrix. Which shapes it takes is
     ``_flash_eligible``'s decision, made before the call; whatever the
-    kernel or the TPU compiler then raises reaches the caller."""
+    kernel or the TPU compiler then raises reaches the caller.
+    ``block`` (forward only) sets its query and key tiles; its own
+    default is 128 x 128."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention)
+        BlockSizes, flash_attention)
 
+    sizes = None if block is None else BlockSizes(
+        block_q=block, block_k_major=block, block_k=block, block_b=1)
     return flash_attention(q, k, v, causal=causal,
-                           sm_scale=1.0 / math.sqrt(q.shape[-1]))
+                           sm_scale=1.0 / math.sqrt(q.shape[-1]),
+                           block_sizes=sizes)
 
 
 # Route to the bundled flash kernel when the materialized [S,S] score
@@ -383,3 +388,311 @@ def _inside_axis(axis_name: str) -> bool:
         return True
     except NameError:
         return False
+
+
+def rotary(x, positions, theta: float):
+    """Rotary position embedding, half-split form (``x cos +
+    rotate_half(x) sin``): ``x [B, heads, S, D]``, ``positions [B, S]``
+    absolute. Angles in float32, the result in ``x``'s dtype."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[:, None, :, None] * inv
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    return (x * cos + rot * sin).astype(x.dtype)
+
+
+def _head_norm(x, weight, eps):
+    """RMSNorm over the head dim, mean square in float32."""
+    x32 = x.astype(jnp.float32)  # bigdl: disable=implicit-upcast-in-trace
+    ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(ms + eps)).astype(x.dtype) * weight
+
+
+def _grouped_scores(q, k_t):
+    """``q [B, H, Sq, D]`` against keys ``k_t [B, Hkv, D, Sk]`` (the
+    cache's form: time last): float32 scores ``[B, Hkv, G, Sq, Sk]``,
+    query heads ``j G .. (j + 1) G - 1`` on K/V head ``j``."""
+    b, h, sq, d = q.shape
+    hkv = k_t.shape[1]
+    qg = q.reshape(b, hkv, h // hkv, sq, d)
+    return jnp.einsum("bngqd,bndk->bngqk", qg, k_t,
+                      preferred_element_type=jnp.float32) / math.sqrt(d)
+
+
+def _grouped_values(weights, v_t):
+    """``weights [B, Hkv, G, Sq, Sk]`` over values ``v_t [B, Hkv, D,
+    Sk]`` -> ``[B, H, Sq, D]``."""
+    out = jnp.einsum("bngqk,bndk->bngqd", weights.astype(v_t.dtype), v_t)
+    b, n, g, sq, d = out.shape
+    return out.reshape(b, n * g, sq, d)
+
+
+class GroupedQueryAttention(Module):
+    """Causal attention whose K/V heads are fewer than its query heads,
+    over [B, S, E] input, with the pieces today's decoders put around
+    it — each one an argument, so a layer pattern can mix kinds:
+
+    - ``num_kv_heads`` K/V heads of ``head_dim``, each shared by
+      ``num_heads / num_kv_heads`` query heads;
+    - ``qk_norm``: q and k through an RMSNorm over the head;
+    - ``rope_theta``: rotary positions on q and k (``None``: the layer
+      carries no positions);
+    - ``window``: a query sees the last ``window`` positions, its own
+      included (``None``: the whole prefix);
+    - ``gate``: the output is multiplied by ``sigmoid(x Wg)`` before
+      ``Wo``. No biases.
+
+    ``fresh`` (static, with ``cache=``): the rows' entries hold nothing
+    yet and every offset is 0 — a prompt prefilled in one shot. The new
+    tokens then attend only each other, and where the shapes allow it
+    (a TPU, lane-aligned length and head, no window bound inside the
+    length) through jax's bundled flash kernel: no ``[S, S]`` scores.
+
+    **Cached.** ``cache`` is ``{"k", "v"}`` ``[B, Hkv, D, C]`` as
+    :class:`~bigdl_tpu.generation.kv_cache.KVCache` keeps this layer. A
+    global layer's cache holds position ``p`` at column ``p``, as
+    ``MultiHeadAttention``'s does. A window layer's is a RING of
+    ``window`` columns: position ``p`` lives at column ``p mod window``.
+    Keys are rotated before they are stored, so a column needs no
+    position of its own and a decode step attends ``min(p + 1,
+    window)`` columns in whatever order they lie. ``valid`` (int
+    ``[B]``, cached prefill only) is how many of the S new tokens of
+    each row are real: padding past it is never written into a ring,
+    where it would overwrite the oldest positions still in the window.
+    """
+
+    def __init__(self, hidden_size: int, num_heads: int,
+                 num_kv_heads: int, head_dim: int, *,
+                 window: Optional[int] = None,
+                 rope_theta: Optional[float] = None, qk_norm: bool = True,
+                 gate: bool = True, norm_eps: float = 1e-5):
+        super().__init__()
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads over "
+                             f"{num_kv_heads} K/V heads")
+        self.hidden_size = hidden_size
+        self.num_heads = num_heads
+        self.num_kv_heads = num_kv_heads
+        self.head_dim = head_dim
+        self.window = window
+        self.rope_theta = rope_theta
+        self.qk_norm = qk_norm
+        self.gate = gate
+        self.norm_eps = norm_eps
+
+    def init(self, rng):
+        dtype = Engine.default_dtype()
+        h, d = self.hidden_size, self.head_dim
+        nq, nkv = self.num_heads * d, self.num_kv_heads * d
+        ks = jax.random.split(rng, 5)
+        s_in, s_out = 1.0 / math.sqrt(h), 1.0 / math.sqrt(nq)
+        p = {"wq": jax.random.uniform(ks[0], (h, nq), dtype, -s_in, s_in),
+             "wk": jax.random.uniform(ks[1], (h, nkv), dtype, -s_in, s_in),
+             "wv": jax.random.uniform(ks[2], (h, nkv), dtype, -s_in, s_in),
+             "wo": jax.random.uniform(ks[3], (nq, h), dtype,
+                                      -s_out, s_out)}
+        if self.gate:
+            p["wg"] = jax.random.uniform(ks[4], (h, nq), dtype,
+                                         -s_in, s_in)
+        if self.qk_norm:
+            p["q_norm"] = jnp.ones((d,), dtype)
+            p["k_norm"] = jnp.ones((d,), dtype)
+        return p
+
+    def cache_columns(self, max_len: int) -> int:
+        """Columns this layer's cache entry keeps for ``max_len``
+        positions: the ring, or every position."""
+        return max_len if self.window is None else min(self.window,
+                                                       max_len)
+
+    def scoreless(self, length: int) -> bool:
+        """Whether ``length`` fresh tokens can attend each other without
+        materialised scores: the bundled flash kernel takes them (it
+        knows the causal mask, not a window) and nothing interprets."""
+        from bigdl_tpu import kernels as _kernels
+
+        return (not _kernels.interpret_mode()
+                and length % 128 == 0 and self.head_dim % 128 == 0
+                and (self.window is None or length <= self.window))
+
+    def forward_fn(self, params, input, *, training=False, rng=None,
+                   cache=None, positions=None, attend_len=None,
+                   valid=None, fresh=False):
+        x = input
+        b, s, _ = x.shape
+        d = self.head_dim
+
+        def heads(t, n):  # [B,S,n*D] -> [B,n,S,D]
+            return t.reshape(b, s, n, d).transpose(0, 2, 1, 3)
+
+        with jax.named_scope("attn/qkv"):
+            q = heads(x @ params["wq"], self.num_heads)
+            k = heads(x @ params["wk"], self.num_kv_heads)
+            v = heads(x @ params["wv"], self.num_kv_heads)
+            if self.qk_norm:
+                q = _head_norm(q, params["q_norm"], self.norm_eps)
+                k = _head_norm(k, params["k_norm"], self.norm_eps)
+        if cache is None:
+            offsets = jnp.zeros((b,), jnp.int32)
+        elif positions is None:
+            raise ValueError("cache= needs positions= (per-row int32 "
+                             "write offsets into the KV cache)")
+        else:
+            offsets = positions.astype(jnp.int32)
+        qpos = offsets[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+        if self.rope_theta is not None:
+            with jax.named_scope("attn/rope"):
+                q = rotary(q, qpos, self.rope_theta)
+                k = rotary(k, qpos, self.rope_theta)
+        k_t, v_t = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
+        if cache is None:
+            with jax.named_scope("attn/core"):
+                out = self._attend(q, [(k_t, v_t, self._own_mask(s))])
+            new_cache = None
+        elif fresh:
+            with jax.named_scope("attn/core"):
+                if self.scoreless(s):
+                    g = self.num_heads // self.num_kv_heads
+                    # tiles of 512 where the length allows: at the
+                    # kernel's own 128 x 128 a layer of 48 x 4096 x 4096
+                    # read 11 ms on a v5e, a tenth of its operations
+                    out = _flash_attention_tpu(
+                        q, jnp.repeat(k, g, axis=1),
+                        jnp.repeat(v, g, axis=1), True,
+                        block=512 if s % 512 == 0 else 128)
+                else:
+                    out = self._attend(q, [(k_t, v_t, self._own_mask(s))])
+            with jax.named_scope("attn/kv_write"):
+                new_cache = self._write(cache, k_t, v_t, offsets, qpos,
+                                        valid)
+        elif self.window is None:
+            out, new_cache = self._global_step(q, k_t, v_t, cache,
+                                               offsets, qpos, attend_len)
+        else:
+            out, new_cache = self._ring_step(q, k_t, v_t, cache, offsets,
+                                             qpos, attend_len, valid)
+        out = out.transpose(0, 2, 1, 3).reshape(b, s,
+                                                self.num_heads * d)
+        if self.gate:
+            with jax.named_scope("attn/gate"):
+                out = out * jax.nn.sigmoid(x @ params["wg"])
+        with jax.named_scope("attn/out"):
+            out = out @ params["wo"]
+        return out if cache is None else (out, new_cache)
+
+    # -------------------------------------------------------- pieces
+    def _own_mask(self, s: int):
+        """``[1, 1, 1, S, S]``: new token i sees new token j."""
+        i = jnp.arange(s)[:, None]
+        j = jnp.arange(s)[None, :]
+        ok = j <= i
+        if self.window is not None:
+            ok = ok & (i - j < self.window)
+        return ok[None, None, None]
+
+    @staticmethod
+    def _attend(q, parts):
+        """Soft-max attention of ``q`` over the concatenation of
+        ``parts`` = ``[(k_t, v_t, mask), ...]``, each ``[B, Hkv, D,
+        Sk]`` with a mask broadcastable to ``[B, Hkv, G, Sq, Sk]``."""
+        scores = [jnp.where(m, _grouped_scores(q, kt),
+                            jnp.finfo(jnp.float32).min)
+                  for kt, _, m in parts]
+        w = jax.nn.softmax(jnp.concatenate(scores, axis=-1), axis=-1)
+        out, at = None, 0
+        for kt, vt, _ in parts:
+            n = kt.shape[-1]
+            o = _grouped_values(w[..., at:at + n], vt)
+            out, at = (o if out is None else out + o), at + n
+        return out
+
+    def _global_step(self, q, k_t, v_t, cache, offsets, qpos,
+                     attend_len):
+        """Position p at column p: write the new columns at each row's
+        offset, then attend the first ``attend_len`` columns."""
+        with jax.named_scope("attn/kv_write"):
+            new = self._write(cache, k_t, v_t, offsets, qpos, None)
+            ck, cv = new["k"], new["v"]
+        al = ck.shape[3] if attend_len is None else min(int(attend_len),
+                                                        ck.shape[3])
+        with jax.named_scope("attn/core"):
+            out = None
+            if q.shape[2] == 1:
+                from bigdl_tpu import kernels as _kernels
+                out = _kernels.decode_attention(
+                    q[:, :, 0, :], ck, cv, offsets + 1, attend_len=al)
+                if out is not None:
+                    out = out[:, :, None, :]
+            if out is None:
+                mask = (jnp.arange(al)[None, None, :]
+                        <= qpos[:, :, None])[:, None, None]
+                out = self._attend(q, [(ck[..., :al], cv[..., :al],
+                                        mask)])
+        return out, new
+
+    def _ring_step(self, q, k_t, v_t, cache, offsets, qpos, attend_len,
+                   valid):
+        """Position p at column ``p mod window``. One new token a row
+        with the decode kernel on: write it, then the kernel reads the
+        ``min(p + 1, window)`` live columns. Otherwise (a prefill chunk,
+        a verify step, kernels off): attend what the ring held BEFORE
+        this call, told apart by the position each column must hold,
+        together with the new tokens themselves, then write the new
+        tokens that stay inside the window."""
+        w, s = self.window, q.shape[2]
+        cols = cache["k"].shape[3]
+        c = cols if attend_len is None else min(int(attend_len), cols)
+        with jax.named_scope("attn/kv_write"):
+            new = self._write(cache, k_t, v_t, offsets, qpos, valid)
+        if s == 1:
+            from bigdl_tpu import kernels as _kernels
+            with jax.named_scope("attn/core"):
+                out = _kernels.decode_attention(
+                    q[:, :, 0, :], new["k"], new["v"],
+                    jnp.minimum(offsets + 1, w), attend_len=c)
+            if out is not None:
+                return out[:, :, None, :], new
+        with jax.named_scope("attn/core"):
+            # the position column j held before this call: the largest
+            # p <= offset - 1 with p mod window == j (negative: none)
+            j = jnp.arange(c, dtype=jnp.int32)[None, :]
+            last = offsets[:, None] - 1
+            held = last - (last - j) % w                      # [B, C]
+            old = ((held[:, None, :] >= 0)
+                   & (held[:, None, :] > qpos[:, :, None] - w))
+            out = self._attend(
+                q, [(cache["k"][..., :c], cache["v"][..., :c],
+                     old[:, None, None]),
+                    (k_t, v_t, self._own_mask(s))])
+        return out, new
+
+    def _write(self, cache, k_t, v_t, offsets, qpos, valid):
+        """The new columns ``k_t`` / ``v_t`` ``[B, Hkv, D, S]`` into the
+        layer's entry: at each row's offset in a global layer; in a
+        ring at ``p mod window``, one column in place for a decode step,
+        else only the real tokens (``valid``) that stay inside the
+        window once the call is over."""
+        s = k_t.shape[3]
+
+        def upd(cc, u, p):  # cc: [Hkv,D,T], u: [Hkv,D,S]
+            return jax.lax.dynamic_update_slice(cc, u, (0, 0, p))
+
+        if self.window is None or s == 1:
+            at = offsets if self.window is None else offsets % self.window
+            return {"k": jax.vmap(upd)(cache["k"], k_t, at),
+                    "v": jax.vmap(upd)(cache["v"], v_t, at)}
+        w, cols = self.window, cache["k"].shape[3]
+        n = (jnp.full_like(offsets, s) if valid is None
+             else valid.astype(jnp.int32))
+        i = jnp.arange(s, dtype=jnp.int32)[None, :]
+        keep = (i < n[:, None]) & (i >= n[:, None] - w)
+        at = jnp.where(keep, qpos % w, cols)             # cols: dropped
+
+        def put(cc, u, a):   # [Hkv,D,cols], [Hkv,D,S], [S]
+            return cc.at[:, :, a].set(u, mode="drop")
+
+        return {"k": jax.vmap(put)(cache["k"], k_t, at),
+                "v": jax.vmap(put)(cache["v"], v_t, at)}

@@ -425,3 +425,156 @@ def test_zero2_step_on_four_chips_has_its_collectives(four_chips, lm):
     counts = collective_counts(step.lower(*args).compile())
     assert counts["reduce-scatter"]["total"] > 0, counts
     assert counts["all-gather"]["total"] > 0, counts
+
+
+# ------------------------- a decoder of unlike layers (ISSUE 28's cell)
+
+# trinity-large-ep8's published widths; depth cut to one window layer
+# and one global one, both with the expert FFN
+PD = dict(vocab=25024, hidden=3072, heads=48, kv_heads=8, head_dim=128,
+          ffn=12288, expert=3072, router=256, held=32, top_k=4,
+          window=4096, slots=48, max_len=6144, rungs=(4096, 6144))
+
+
+@pytest.mark.parametrize("rows,tile_m", [(704, 16), (8192, 128)])
+def test_grouped_product_compiles(one_chip, rows, tile_m):
+    """The expert layer's kernel at the cell's two shapes: a decode
+    step's 48 x 4 pairs on tiles of 16 rows, a prefill chunk's on tiles
+    of 128; 32 experts of 3072 x 3072 in bfloat16."""
+    from bigdl_tpu.kernels.moe_gmm import grouped_matmul_pallas
+
+    e, k = PD["held"], PD["hidden"]
+    x, w, te, nt = _on(one_chip, ((rows, k), "bfloat16"),
+                       ((e, k, PD["expert"]), "bfloat16"),
+                       ((rows // tile_m,), "int32"), ((1,), "int32"))
+    compiled = _compile(
+        lambda x, w, te, nt: grouped_matmul_pallas(x, w, te, nt,
+                                                   tile_m=tile_m),
+        x, w, te, nt)
+    assert "bigdl_moe_gmm" in compiled.as_text()
+
+
+@pytest.mark.parametrize("t", PD["rungs"])
+def test_ragged_decode_compiles_with_grouped_heads(one_chip, t):
+    """48 query heads over 8 K/V heads of 128: the query block is the
+    ``[6, 128]`` group, the cache block ``[128, rung]``."""
+    from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
+
+    s, d = PD["slots"], PD["head_dim"]
+    cache = ((s, PD["kv_heads"], d, PD["max_len"]), "bfloat16")
+    q, k, v, n = _on(one_chip, ((s, PD["heads"], d), "bfloat16"), cache,
+                     cache, ((s,), "int32"))
+    assert _has_kernel(_compile(
+        lambda q, k, v, n: ragged_decode_attention(q, k, v, n,
+                                                   attend_len=t),
+        q, k, v, n))
+
+
+@pytest.fixture(scope="module")
+def pattern_programs(one_chip):
+    """The engine's own prefill (rung 4096) and decode (rung 6144)
+    programs for a window layer and a global one at the published
+    widths, bfloat16, compiled at the policy a TPU gets by default."""
+    from bigdl_tpu import kernels
+    from bigdl_tpu.generation.engine import DecodeEngine
+    from bigdl_tpu.generation.kv_cache import KVCache
+    from bigdl_tpu.kernels import KernelConfig
+    from bigdl_tpu.models import PatternDecoderLM
+    from bigdl_tpu.serving.compile_cache import (BucketLadder,
+                                                 CompileCache)
+
+    model = PatternDecoderLM(
+        PD["vocab"], PD["hidden"],
+        [("window", "experts"), ("global", "experts")], PD["heads"],
+        PD["kv_heads"], PD["head_dim"], PD["ffn"], window=PD["window"],
+        max_len=PD["max_len"], expert_size=PD["expert"],
+        shared_size=PD["expert"], router_experts=PD["router"],
+        local_experts=(0, PD["held"]), top_k=PD["top_k"],
+        route_scale=2.448, embed_scale=PD["hidden"] ** 0.5).evaluate()
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    model.set_parameters(params)         # the cache's dtype follows it
+    state = jax.eval_shape(model.initial_state)
+    engine = DecodeEngine(CompileCache(),
+                          BucketLadder(PD["max_len"], PD["rungs"]),
+                          PD["slots"], 4)
+    k_spec, v_spec = KVCache.spec_for_model(model, PD["slots"],
+                                            PD["max_len"])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape,
+                                                    np.dtype(dtype))
+    out = {"pieces": engine.prefill_shape(model, 4096),   # interpreting
+           "cache": k_spec + v_spec}
+    with kernels.use(KernelConfig(decode_attention=True, int8_matmul=True,
+                                  grouped_matmul=True, interpret=False)):
+        rows, chunk = out["shape"] = engine.prefill_shape(model, 4096)
+        calls = {
+            "prefill": (engine._prefill_jit(model, 4096, lambda: None,
+                                            chunk == 4096),
+                        (params, state, k_spec, v_spec,
+                         sds((rows, chunk), "int32"),
+                         sds((rows,), "int32"), sds((rows,), "int32"),
+                         sds((rows,), "int32"))),
+            "decode": (engine._decode_jit(model, 6144, lambda: None),
+                       (params, state, k_spec, v_spec,
+                        sds((PD["slots"],), "int32"),
+                        sds((PD["slots"],), "int32"),
+                        sds((PD["slots"],), "bool")))}
+        for name, (jitted, args) in calls.items():
+            args = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            out[name] = jitted.lower(*args).compile()
+    return out
+
+
+def test_pattern_decode_step_aliases_both_kinds_of_cache(pattern_programs):
+    """A ring of 4096 columns and a whole context of 6144 side by side,
+    8 K/V heads each: the compiled decode step aliases every leaf of
+    both kinds to its output, keeps temporaries under a hundredth of
+    the cache, and holds the decode kernel (2) and the grouped product
+    (2 layers x gate, up, down)."""
+    compiled, leaves = (pattern_programs["decode"],
+                        pattern_programs["cache"])
+    assert [a.shape[3] for a in leaves] == [4096, 6144, 4096, 6144]
+    assert all(a.dtype == jnp.bfloat16 and a.shape[1] == PD["kv_heads"]
+               for a in leaves)
+    cache_bytes = sum(int(np.prod(a.shape)) * 2 for a in leaves)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 100
+    text = compiled.as_text()
+    assert text.count("bigdl_ragged_decode") >= 2
+    assert text.count("bigdl_moe_gmm") >= 6
+
+
+def test_pattern_prefill_holds_no_scores_and_one_row_of_logits(
+        pattern_programs):
+    """Rung 4096 at 48 heads: the einsum form in one shot would hold
+    ``[4, 48, 4096, 4096]`` float32 scores (12.9 GB) and ``[4, 4096,
+    25024]`` logits. The engine decides by itself: where the model's
+    attention is scoreless at the rung (compiled kernels, 4096 <= the
+    window) ONE row in one shot through the bundled flash kernel, else
+    (interpreting, as the CPU does) ``[1, 512]`` pieces. The compiled
+    one-shot program holds no result as large as one row's ``heads x
+    4096 x 4096`` scores, no logits but one position a row, the flash
+    kernel twice and the grouped product six times."""
+    from bigdl_tpu.analysis.hlo import parse_hlo
+
+    assert pattern_programs["pieces"] == (1, 512)
+    assert pattern_programs["shape"] == (1, 4096)
+    compiled = pattern_programs["prefill"]
+    assert compiled.as_text().count("tpu_custom_call") >= 8
+    module = parse_hlo(compiled.as_text())
+    one_shot = PD["heads"] * 4096 * 4096
+    ops = [op for _, op in module.find_ops()
+           if op.opcode not in ("tuple", "parameter")]
+    big = [(op.name, op.result_type) for op in ops
+           if op.result_elements() >= one_shot]
+    assert not big, big
+    wide = [(op.name, op.result_type) for op in ops
+            if f",{PD['vocab']}]" in op.result_type.split("{")[0]
+            and op.result_elements() > PD["hidden"] * PD["vocab"]]
+    assert not wide, wide
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 << 30

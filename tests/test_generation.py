@@ -183,6 +183,45 @@ def test_prefill_logits_bitwise_and_decode_logits_tight():
     assert int(np.argmax(logits[0])) == int(np.argmax(exact))
 
 
+def test_a_greedy_step_takes_ids_and_a_sampled_request_gets_logits():
+    """The decode program returns every slot's argmax beside its logits:
+    ``ids_only`` hands back those ``[slots]`` ids (equal to ``np.argmax``
+    of the logits the same step returns, ties and all), the loop asks
+    for them only while every live request is greedy, and a request
+    with a temperature still samples from full logits rows."""
+    from bigdl_tpu.generation.engine import DecodeEngine
+    from bigdl_tpu.serving.compile_cache import BucketLadder, CompileCache
+    from bigdl_tpu.serving.registry import ModelRegistry
+
+    model = _model()
+    sv = ModelRegistry().load("m", model)
+    eng = DecodeEngine(CompileCache(), BucketLadder(16, (16,)),
+                       slots=4, prefill_rows=2)
+    kv = KVCache.for_model(model, 4, 16)
+    eng.prefill(sv, kv, [np.array([3, 7, 1], np.int32),
+                         np.array([5, 2], np.int32)], [0, 2])
+    tokens = np.array([9, 0, 4, 0], np.int32)
+    active = np.array([True, False, True, False])
+    positions = kv.lengths.copy()
+    logits, _ = eng.decode(sv, kv, tokens, positions, active)
+    ids, _ = eng.decode(sv, kv, tokens, positions, active, ids_only=True)
+    assert ids.shape == (4,) and ids.dtype == np.int32
+    assert ids.tolist() == np.argmax(logits, axis=-1).tolist()
+
+    svc = _service(model)
+    try:
+        prompt = np.array([3, 7, 1, 4, 9], np.int32)
+        greedy = svc.generate("lm", prompt, max_new_tokens=6).result(60)
+        assert list(greedy) == _greedy_reference(model, prompt, 6)
+        a = svc.generate("lm", prompt, max_new_tokens=6, temperature=0.9,
+                         seed=11).result(60)
+        b = svc.generate("lm", prompt, max_new_tokens=6, temperature=0.9,
+                         seed=11).result(60)
+        assert list(a) == list(b) and len(a) == 6
+    finally:
+        svc.shutdown()
+
+
 # ------------------------------------------------- the compile bound
 
 def test_k_buckets_compile_at_most_2k_under_generation_burst():
