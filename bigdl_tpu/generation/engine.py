@@ -49,6 +49,7 @@ via the compile counter in tests/test_fleet.py.
 from __future__ import annotations
 
 import threading
+from collections import Counter
 from typing import Dict, Sequence, Set, Tuple
 
 import numpy as np
@@ -56,6 +57,7 @@ import numpy as np
 import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu.serving.compile_cache import BucketLadder, CompileCache
 from bigdl_tpu.generation.kv_cache import KVCache
+from bigdl_tpu.kernels.ragged_decode import block_columns, kv_tile
 
 #: float32 attention scores one prefill call may hold, ``rows x heads x
 #: tokens x rung x 4`` bytes: past it the engine chunks the rung
@@ -126,6 +128,30 @@ def _record_moe(stats, kind: str) -> None:
             args={"kind": kind, "layers": len(stats),
                   "experts_touched": touched, "local_pairs": pairs,
                   "pairs_per_expert_max": most})
+
+
+def _record_kv(model, kv: KVCache, positions, active,
+               attend_len: int) -> None:
+    """With the span tracer on, one decode step's cache columns into a
+    ring record (``serving/decode/kv``): ``valid_columns``, the columns
+    the live slots attend summed over the layers (``c``, or ``min(c,
+    window)`` in a ring), and ``fetched_columns``, the same rounded up
+    to the whole tiles the ragged decode kernel fetches, by its own
+    tile function. Host arithmetic on the lengths vector only."""
+    if not telemetry.enabled():
+        return
+    c = positions[active].astype(np.int64) + 1
+    itemsize = kv.k[0].dtype.itemsize
+    valid = fetched = 0
+    for (heads, d, columns), layers in Counter(kv.layout).items():
+        n = np.minimum(c, columns)
+        tile = kv_tile(block_columns(columns, min(attend_len, columns)),
+                       d, int(model.num_heads) // heads, itemsize)
+        valid += layers * int(n.sum())
+        fetched += layers * int((-(-n // tile) * tile).sum())
+    telemetry.tracer().record(
+        "serving/decode/kv", 0.0,
+        args={"valid_columns": valid, "fetched_columns": fetched})
 
 
 class DecodeEngine:
@@ -577,6 +603,9 @@ class DecodeEngine:
                 active.astype(bool))
             wanted = ids[0] if ids_only else logits
         with telemetry.span("serving/decode/device_wait"):
+            if width == 1:      # host work behind the device's
+                _record_kv(servable.model, kv, positions, active,
+                           attend_len)
             jax.block_until_ready(wanted)
         with telemetry.span("serving/decode/logits_d2h"):
             host = np.asarray(wanted)

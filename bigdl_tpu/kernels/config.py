@@ -57,9 +57,12 @@ class KernelConfig:
     ``lengths[i]`` valid KV per slot); ``int8_matmul`` — the fused
     dequant-int8-GEMM serving kernel; ``grouped_matmul`` — the routed
     expert layer's grouped product. ``interpret=None`` auto-selects
-    the pallas interpreter off-TPU; ``block_q``/``block_k`` are
-    preferred tile sizes (shrunk to the largest divisor of the actual
-    dimension, so ragged test shapes stay eligible)."""
+    the pallas interpreter off-TPU; ``block_q``/``block_k`` are the
+    FLASH kernels' preferred tile sizes (shrunk to the largest divisor
+    of the actual dimension, so ragged test shapes stay eligible) and
+    nothing else reads them: the ragged decode kernel sizes its K/V
+    tile itself from the shapes it is handed
+    (:func:`bigdl_tpu.kernels.ragged_decode.kv_tile`)."""
 
     flash_attention: bool = False
     decode_attention: bool = False

@@ -161,13 +161,10 @@ def decode_attention(q, k, v, lengths, *, attend_len: int = None,
         return None
     from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
 
-    cfg = _config.get_config()
     _taken("decode")
-    return ragged_decode_attention(q, k, v, lengths,
-                                   attend_len=attend_len,
-                                   sm_scale=sm_scale,
-                                   block_k=cfg.block_k,
-                                   interpret=cfg.resolve_interpret())
+    return ragged_decode_attention(
+        q, k, v, lengths, attend_len=attend_len, sm_scale=sm_scale,
+        interpret=_config.get_config().resolve_interpret())
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,

@@ -470,6 +470,36 @@ def test_ragged_decode_compiles_with_grouped_heads(one_chip, t):
         q, k, v, n))
 
 
+@pytest.mark.parametrize("slots,hq,hkv,d,t,rung,dtype", [
+    (64, 12, 12, 64, 1024, 1024, "float32"),     # gpt2s_serve_closed64
+    (48, 48, 8, 128, 4096, 4096, "bfloat16"),    # trinity: a ring
+    (48, 48, 8, 128, 6144, 4096, "bfloat16"),    # its global entry,
+    (48, 48, 8, 128, 6144, 6144, "bfloat16"),    # both rungs
+    (48, 48, 8, 128, 64, 64, "bfloat16"),        # a short cache
+])
+def test_ragged_decode_compiles_at_the_serve_cells(one_chip, slots, hq,
+                                                   hkv, d, t, rung,
+                                                   dtype):
+    """Both serve cells' decode kernels at their real shapes, the K/V
+    tile on the grid at the width the kernel sizes for the shape (one
+    tile a GPT-2 row, 2048 columns of Trinity's), running max, sum and
+    accumulator in VMEM scratch across a slot-head's tiles."""
+    from bigdl_tpu.kernels.ragged_decode import (block_columns, kv_tile,
+                                                 ragged_decode_attention)
+
+    tile = kv_tile(block_columns(t, rung), d, hq // hkv,
+                   np.dtype(dtype).itemsize)
+    assert tile == {1024: 1024, 64: 64}.get(t, 2048)
+    cache = ((slots, hkv, d, t), dtype)
+    q, k, v, n = _on(one_chip, ((slots, hq, d), dtype), cache, cache,
+                     ((slots,), "int32"))
+    compiled = _compile(
+        lambda q, k, v, n: ragged_decode_attention(q, k, v, n,
+                                                   attend_len=rung),
+        q, k, v, n)
+    assert "bigdl_ragged_decode" in compiled.as_text()
+
+
 @pytest.fixture(scope="module")
 def pattern_programs(one_chip):
     """The engine's own prefill (rung 4096) and decode (rung 6144)

@@ -452,11 +452,53 @@ def test_generation_telemetry_spans_and_gauges():
         svc.shutdown()
         names = {rec.name for rec in telemetry.tracer().spans()}
         assert "serving/prefill" in names and "serving/decode" in names
+        assert "serving/decode/kv" in names
         m = svc.metrics("lm")
         assert m["tokens"] == 3
         assert 0.0 < m["padding_efficiency"] <= 1.0
         assert "ttft_ms_p50" in m and "token_ms_p99" in m
         assert telemetry.audit_names(svc.metrics_registry) == []
+    finally:
+        telemetry.disable()
+        telemetry.tracer().clear()
+
+
+@pytest.mark.parametrize("attend_len, positions, want", [
+    # a 4-column ring and a global entry of 512 columns, 2 layers each;
+    # one tile a block at these sizes, whatever the rung
+    (256, [2, 299, 0, 40], (2 * (3 + 4) + 2 * (3 + 41),
+                            2 * (4 + 4) + 2 * (256 + 256))),
+    (512, [2, 299, 0, 40], (2 * (3 + 4) + 2 * (3 + 41),
+                            2 * (4 + 4) + 2 * (512 + 512))),
+])
+def test_decode_step_records_its_cache_columns(attend_len, positions,
+                                               want):
+    """``serving/decode/kv``: the columns the live slots attend (``c``,
+    ``min(c, window)`` in a ring) and the same in whole tiles of the
+    kernel's own tile function, per layer; nothing with the tracer
+    off."""
+    from types import SimpleNamespace
+
+    from bigdl_tpu.generation.engine import _record_kv
+    from bigdl_tpu.generation.kv_cache import KVCache
+    from bigdl_tpu.kernels.ragged_decode import block_columns, kv_tile
+
+    layout = [(2, 16, 4)] * 2 + [(2, 16, 512)] * 2
+    kv = KVCache(4, 4, 2, 512, 16, dtype="float32", layout=layout)
+    model = SimpleNamespace(num_heads=4)
+    positions = np.asarray(positions, np.int32)
+    active = np.array([True, False, False, True])
+    assert kv_tile(block_columns(512, attend_len), 16, 2, 4) == attend_len
+    telemetry.tracer().clear()
+    _record_kv(model, kv, positions, active, attend_len)
+    assert not telemetry.tracer().spans()
+    telemetry.enable()
+    try:
+        _record_kv(model, kv, positions, active, attend_len)
+        (rec,) = [r for r in telemetry.tracer().spans()
+                  if r.name == "serving/decode/kv"]
+        assert (rec.args["valid_columns"],
+                rec.args["fetched_columns"]) == want
     finally:
         telemetry.disable()
         telemetry.tracer().clear()

@@ -25,7 +25,8 @@ from bigdl_tpu.kernels.flash_attention import (blockwise_flash_attention,
                                                fit_block,
                                                flash_attention)
 from bigdl_tpu.kernels.int8_gemm import pallas_quantized_matmul
-from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
+from bigdl_tpu.kernels.ragged_decode import (block_columns, kv_tile,
+                                             ragged_decode_attention)
 from bigdl_tpu.models.transformer import TransformerLM
 from bigdl_tpu.utils.random import RandomGenerator
 
@@ -535,6 +536,72 @@ class TestRaggedDecode:
             q, jnp.where(poison, jnp.nan, k), jnp.where(poison, 1e9, v),
             lengths, attend_len=attend_len, interpret=True)
         assert np.array_equal(np.asarray(out_p), np.asarray(out))
+
+    @pytest.mark.parametrize("a", [64, 128, 1024, 4096, 6144])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("g", [1, 6])
+    @pytest.mark.parametrize("d", [64, 128])
+    def test_tile_edges_and_poisoned_tails(self, d, g, dtype, a):
+        """Both serve cells' query groups, head sizes and dtypes over
+        short, one-tile and many-tile blocks: lengths at 1, one under /
+        at / one over every edge of the tile the kernel sizes for the
+        shape, and full, against the masked einsum — with every column
+        past a slot's length poisoned with NaN in K and in V, which a
+        skipped tile never fetches and a walked tile masks out of both
+        products."""
+        tile = kv_tile(a, d, g, np.dtype(dtype).itemsize)
+        edges = range(tile, a, tile)
+        lens = sorted({1, a} | {e + o for e in edges for o in (-1, 0, 1)})
+        r = np.random.default_rng(d + g + a)
+        q = jnp.asarray(r.standard_normal((len(lens), g, d)), dtype)
+        k, v = (r.standard_normal((len(lens), 1, d, a)).astype("float32")
+                for _ in range(2))
+        lengths = np.asarray(lens, np.int32)
+        past = np.arange(a)[None, None, None, :] \
+            >= lengths[:, None, None, None]
+        out = ragged_decode_attention(
+            q, jnp.asarray(np.where(past, np.nan, k), dtype),
+            jnp.asarray(np.where(past, np.nan, v), dtype),
+            jnp.asarray(lengths), interpret=True)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        # the masked einsum over the same rounded operands, tails zeroed
+        f32 = lambda x: jnp.asarray(np.where(past, 0.0, x),  # noqa: E731
+                                    dtype).astype(jnp.float32)[:, 0]
+        sc = jnp.einsum("sgd,sdt->sgt", q.astype(jnp.float32), f32(k)) \
+            / math.sqrt(d)
+        p = jax.nn.softmax(jnp.where(~past[:, 0], sc, -jnp.inf), axis=-1)
+        ref = jnp.einsum("sgt,sdt->sgd", p, f32(v))
+        np.testing.assert_allclose(
+            np.asarray(out.astype(jnp.float32)), np.asarray(ref), rtol=0,
+            atol=1e-5 if dtype == "float32" else 2e-2)
+
+    @pytest.mark.parametrize("itemsize", [2, 4])
+    @pytest.mark.parametrize("d, g", [(64, 1), (128, 6), (128, 1)])
+    def test_tile_is_a_lane_aligned_divisor_at_every_rung(self, d, g,
+                                                          itemsize):
+        """At every rung of the default ladders up to both serve cells'
+        lengths (and caches no lane tile divides) the tile divides the
+        block and is whole lane tiles, or is the whole block; it comes
+        from the shapes alone — ``KernelConfig.block_k`` is flash's."""
+        import inspect
+
+        from bigdl_tpu.serving.compile_cache import BucketLadder
+
+        for max_len in (1024, 6144, 200, 1000):
+            for rung in BucketLadder(max_len):
+                a = block_columns(max_len, rung)
+                assert a >= min(rung, max_len)
+                tile = kv_tile(a, d, g, itemsize)
+                assert a % tile == 0
+                assert tile % 128 == 0 or tile == a
+                with kernels.use(kernels.KernelConfig.all_on(block_k=256)):
+                    assert kv_tile(a, d, g, itemsize) == tile
+        assert "block_k" not in inspect.signature(
+            ragged_decode_attention).parameters
+        # the two serve cells: one tile a GPT-2 row, 2048 columns of a
+        # ring or of the global entry
+        assert kv_tile(1024, 64, 1, 4) == 1024
+        assert kv_tile(4096, 128, 6, 2) == kv_tile(6144, 128, 6, 2) == 2048
 
     def test_dispatch_shapes_and_toggle(self):
         q, kv, _ = _decode_operands(2, 2, 8, 16, seed=12)
