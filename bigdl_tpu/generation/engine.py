@@ -39,6 +39,29 @@ programs** per model version, warmed eagerly as pairs by
 shared :class:`~bigdl_tpu.serving.compile_cache.CompileCache` compile
 counter the serving tests already assert against.
 
+**What a decoder must provide to be served** (the whole contract;
+``TransformerLM`` and ``PatternDecoderLM`` meet it, and the programs
+below ask a model nothing else - no signature is inspected, no
+attribute has a default):
+
+- ``apply(params, state, tokens, *, training, cache, positions,
+  attend_len, logits_at=None, live=None, fresh=False) -> (logits,
+  state, cache)``: one KV-cached step over ``tokens [B, S]``. ``cache``
+  is ``{"k", "v"}``, each a tuple of one array a layer in
+  ``cache_layout``'s shapes with ``B`` rows; ``positions`` (int32
+  ``[B]``) each row's write offset; ``attend_len`` (static) the rung.
+  ``logits_at`` (int32 ``[B]``): return that one new position's logits
+  a row, ``[B, 1, V]``, the tokens past it being padding; without it
+  ``[B, S, V]``. ``live`` (bool ``[B]``): False for rows that are
+  padding or free decode slots. ``fresh`` (static): a one-shot prefill,
+  every offset 0 and nothing of the rows cached yet.
+- ``cache_layout(max_len) -> [(kv heads, head dim, columns), ...]``,
+  one entry a layer; ``cache_dtype()`` (None: the default type);
+  ``scoreless_prefill(rung) -> bool``: whether ``rung`` fresh tokens
+  attend each other without ``[rung, rung]`` scores.
+- the attributes ``num_layers``, ``num_heads``, ``max_len``,
+  ``vocab_size``.
+
 Speculative decoding (``bigdl_tpu.fleet.speculative``) adds one
 **verify** program per rung — ``[slots, w]`` draft tokens through the
 same cached incremental forward, adjudicated host-side — growing the
@@ -96,15 +119,6 @@ def _moe_stats(state):
                     walk(node[key])
     walk(state)
     return jnp.stack(found) if found else None
-
-
-def _accepts(model, *names) -> bool:
-    """Whether the model's ``apply`` takes these optional arguments:
-    ``logits_at`` (one position's logits a row instead of every
-    position's) and ``live`` (which rows are real)."""
-    import inspect
-
-    return set(names) <= set(inspect.signature(model.apply).parameters)
 
 
 def _record_moe(stats, kind: str) -> None:
@@ -241,15 +255,10 @@ class DecodeEngine:
         itself (the causal mask covers the rest), so the gathered
         stale lanes — exactly like the zero rows the pre-chunking
         program fed — contribute exact zeros to the softmax. ``fresh``
-        says so to a model that can use it (``fresh=``: the new tokens
-        attend only each other, through a flash kernel where one
-        fits)."""
+        says so to the model: the new tokens attend only each other,
+        through a flash kernel where one fits."""
         import jax
         import jax.numpy as jnp
-
-        one_row = _accepts(model, "logits_at", "live")
-        extra = {"fresh": True} if fresh and _accepts(model, "fresh") \
-            else {}
 
         def serving_prefill(params, state, k, v, tokens, last_in_chunk,
                             slot_ids, offsets):
@@ -268,20 +277,14 @@ class DecodeEngine:
                 params, state, tokens, training=False,
                 cache={"k": rows_k, "v": rows_v},
                 positions=offsets.astype(jnp.int32),
-                attend_len=attend_len,
-                **({"logits_at": last_at, "live": ids < k[0].shape[0]}
-                   if one_row else {}), **extra)
-            if one_row:
-                last = logits[:, 0, :]
-            else:
-                last = jnp.take_along_axis(
-                    logits, last_at[:, None, None], axis=1)[:, 0, :]
+                attend_len=attend_len, logits_at=last_at,
+                live=ids < k[0].shape[0], fresh=fresh)
             with jax.named_scope("attn/kv_write"):
                 k = tuple(a.at[ids, :, :, :width(a)].set(r, mode="drop")
                           for a, r in zip(k, rows["k"]))
                 v = tuple(a.at[ids, :, :, :width(a)].set(r, mode="drop")
                           for a, r in zip(v, rows["v"]))
-            return last, k, v, _moe_stats(new_state)
+            return logits[:, 0, :], k, v, _moe_stats(new_state)
 
         return jax.jit(serving_prefill, donate_argnums=(2, 3))
 
@@ -292,16 +295,13 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        rows_live = _accepts(model, "live")
-
         def serving_decode(params, state, k, v, tokens, positions, active):
             on_trace()
             pos = jnp.where(active, positions.astype(jnp.int32), 0)
             logits, new_state, cache = model.apply(
                 params, state, tokens[:, None], training=False,
                 cache={"k": k, "v": v}, positions=pos,
-                attend_len=attend_len,
-                **({"live": active} if rows_live else {}))
+                attend_len=attend_len, live=active)
             logits = logits[:, 0, :]
             # the greedy token of every slot rides along: a step whose
             # requests are all greedy copies [slots] ids to the host,
@@ -322,16 +322,13 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        rows_live = _accepts(model, "live")
-
         def serving_verify(params, state, k, v, tokens, positions, active):
             on_trace()
             pos = jnp.where(active, positions.astype(jnp.int32), 0)
             logits, new_state, cache = model.apply(
                 params, state, tokens, training=False,
                 cache={"k": k, "v": v}, positions=pos,
-                attend_len=attend_len,
-                **({"live": active} if rows_live else {}))
+                attend_len=attend_len, live=active)
             return logits, cache["k"], cache["v"], _moe_stats(new_state)
 
         return jax.jit(serving_verify, donate_argnums=(2, 3))
@@ -379,7 +376,7 @@ class DecodeEngine:
         per_token = int(model.num_heads) * bucket * 4
         if self.prefill_rows * bucket * per_token <= _PREFILL_SCORE_BYTES:
             return self.prefill_rows, bucket
-        if getattr(model, "scoreless_prefill", lambda rung: False)(bucket):
+        if model.scoreless_prefill(bucket):
             return 1, bucket
         fit = max(1, _PREFILL_SCORE_BYTES // per_token)
         chunk = max(c for c in range(1, min(fit, bucket) + 1)

@@ -91,26 +91,21 @@ class KVCache:
     ``[slots, heads_i, head_dim_i, columns_i]``. A layer that attends
     its whole prefix keeps ``columns = max_len`` (position ``p`` at
     column ``p``); a sliding-window layer keeps a RING of ``window``
-    columns (position ``p`` at column ``p mod window``; its attention
-    layer owns that rule, ``nn.attention.GroupedQueryAttention``). The
-    layout comes from the model (``cache_layout(max_len)``); a model
-    that declares none gets the uniform ``[slots, num_heads, head_dim,
-    max_len]`` of a plain multi-head decoder."""
+    columns (position ``p`` at column ``p mod window``;
+    ``nn.attention.cached_attention`` owns that rule). The layout comes
+    from the model: ``cache_layout(max_len)``, the cache's one entry
+    point in the engine's contract (``generation/engine.py``)."""
 
-    def __init__(self, layers: int, slots: int, heads: int, max_len: int,
-                 head_dim: int, dtype=None, layout=None):
+    def __init__(self, slots: int, max_len: int, layout, dtype=None):
         import jax.numpy as jnp
 
         from bigdl_tpu.utils.engine import Engine
 
-        self.layers = layers
         self.slots = slots
-        self.heads = heads
         self.max_len = max_len
-        self.head_dim = head_dim
         self.dtype = dtype if dtype is not None else Engine.default_dtype()
-        self.layout = self._layout(layers, heads, max_len, head_dim,
-                                   layout)
+        self.layout = self._layout(layout, max_len)
+        self.layers = len(self.layout)
         # of the first layer's entry: K/V heads, which a grouped-query
         # model has fewer of than the query heads it declares
         self.heads, self.head_dim = self.layout[0][:2]
@@ -122,51 +117,42 @@ class KVCache:
         self.allocator = SlotAllocator(slots)
 
     @staticmethod
-    def _layout(layers, heads, max_len, head_dim, layout) -> tuple:
-        if layout is None:
-            return ((heads, head_dim, max_len),) * layers
+    def _layout(layout, max_len: int) -> tuple:
         layout = tuple(tuple(int(n) for n in e) for e in layout)
-        if len(layout) != layers or any(
+        if not layout or any(
                 len(e) != 3 or not 1 <= e[2] <= max_len for e in layout):
             raise ValueError(
-                f"cache layout {layout} does not describe {layers} "
-                f"layers of at most {max_len} columns")
+                f"cache layout {layout} does not describe layers of at "
+                f"most {max_len} columns")
         return layout
 
     @classmethod
-    def _model_geometry(cls, model, slots: int, max_len: int) -> tuple:
-        """The ``(layers, slots, heads, max_len, head_dim, dtype,
-        layout)`` cache geometry (the constructor's arguments) a decoder
-        model's declared geometry implies: ``cache_layout(max_len)`` /
-        ``cache_dtype()`` where the model has layers of several kinds,
-        else ``num_layers``/``num_heads``/``head_dim`` or
-        ``hidden_size`` — ONE derivation (and positional-table bound)
-        shared by :meth:`for_model` and :meth:`spec_for_model`, so the
-        verified program shapes can never drift from the allocated
-        ones."""
-        layers = int(model.num_layers)
-        heads = int(model.num_heads)
-        head_dim = int(getattr(model, "head_dim",
-                               model.hidden_size // heads))
-        if max_len > int(getattr(model, "max_len", max_len)):
+    def _model_geometry(cls, model, max_len: int) -> tuple:
+        """``(layout, dtype)`` as the model declares them
+        (``cache_layout(max_len)``, ``cache_dtype()``), checked against
+        its positional bound — ONE derivation shared by
+        :meth:`for_model` and :meth:`spec_for_model`, so the verified
+        program shapes can never drift from the allocated ones."""
+        if max_len > int(model.max_len):
             raise ValueError(
                 f"cache max_len={max_len} exceeds the model's positional "
                 f"table ({model.max_len})")
-        declared = getattr(model, "cache_layout", None)
-        layout = declared(max_len) if declared is not None else None
-        dtype = getattr(model, "cache_dtype", lambda: None)()
-        return (layers, slots, heads, max_len, head_dim, dtype, layout)
+        layout = cls._layout(model.cache_layout(max_len), max_len)
+        if len(layout) != int(model.num_layers):
+            raise ValueError(
+                f"cache layout {layout} does not describe "
+                f"{model.num_layers} layers")
+        return layout, model.cache_dtype()
 
     @classmethod
     def for_model(cls, model, slots: int, max_len: int,
                   dtype=None) -> "KVCache":
-        """Size a cache from a decoder model's declared geometry,
-        e.g. a :class:`~bigdl_tpu.models.transformer.TransformerLM`.
+        """Size a cache from what a decoder model declares, e.g. a
+        :class:`~bigdl_tpu.models.transformer.TransformerLM`.
         ``dtype`` overrides what the model declares."""
-        *geom, declared, layout = cls._model_geometry(model, slots,
-                                                       max_len)
-        return cls(*geom, dtype if dtype is not None else declared,
-                   layout)
+        layout, declared = cls._model_geometry(model, max_len)
+        return cls(slots, max_len, layout,
+                   dtype if dtype is not None else declared)
 
     @classmethod
     def spec_for_model(cls, model, slots: int, max_len: int,
@@ -180,13 +166,11 @@ class KVCache:
 
         from bigdl_tpu.utils.engine import Engine
 
-        layers, slots, heads, max_len, head_dim, declared, layout = \
-            cls._model_geometry(model, slots, max_len)
+        layout, declared = cls._model_geometry(model, max_len)
         dt = dtype if dtype is not None else (
             declared if declared is not None else Engine.default_dtype())
-        spec = tuple(jax.ShapeDtypeStruct((slots,) + e, dt) for e in
-                     cls._layout(layers, heads, max_len, head_dim,
-                                 layout))
+        spec = tuple(jax.ShapeDtypeStruct((slots,) + e, dt)
+                     for e in layout)
         return spec, spec
 
     @property

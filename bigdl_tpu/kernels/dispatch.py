@@ -29,8 +29,8 @@ import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu.kernels import config as _config
 from bigdl_tpu.kernels.common import fit_block, sublanes
 
-__all__ = ["attention", "decode_attention", "paged_decode_attention",
-           "int8_matmul", "grouped_matmul", "taken_in_thread"]
+__all__ = ["attention", "decode_attention", "int8_matmul",
+           "grouped_matmul", "taken_in_thread"]
 
 # module-level registration so `tools.check --telemetry-audit` sees the
 # REAL instruments on import, not a hand-maintained name list
@@ -165,36 +165,6 @@ def decode_attention(q, k, v, lengths, *, attend_len: int = None,
     return ragged_decode_attention(
         q, k, v, lengths, attend_len=attend_len, sm_scale=sm_scale,
         interpret=_config.get_config().resolve_interpret())
-
-
-def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
-                           sm_scale: Optional[float] = None):
-    """Paged ragged-decode dispatch: ``q [slots, H, D]`` one token per
-    slot, ``k_pages``/``v_pages`` ``[num_pages, H, page_size, D]``
-    pools, ``page_table [slots, pages_per_slot]`` physical page ids,
-    ``lengths`` the host ragged bound. Returns the kernel result
-    (:mod:`bigdl_tpu.kernels.paged_decode` — table-indirect page reads,
-    token-identical to contiguous decode) when ``decode`` is enabled
-    and the shapes qualify, else **None** (the caller gathers its
-    contiguous view and runs the reference path)."""
-    if not _config.enabled("decode"):
-        _declined("decode", "config")
-        return None
-    if (k_pages.ndim != 4 or v_pages.shape != k_pages.shape
-            or q.ndim != 3
-            or q.shape[1:] != (k_pages.shape[1], k_pages.shape[3])
-            or page_table.ndim != 2
-            or page_table.shape[0] != q.shape[0]
-            or not _floating(q, k_pages, v_pages)):
-        _declined("decode", "shape")
-        return None
-    from bigdl_tpu.kernels.paged_decode import (
-        paged_decode_attention as _paged)
-
-    cfg = _config.get_config()
-    _taken("decode")
-    return _paged(q, k_pages, v_pages, page_table, lengths,
-                  sm_scale=sm_scale, interpret=cfg.resolve_interpret())
 
 
 #: compiled (non-interpret) int8 tiles must fill the MXU: the same

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 import bigdl_tpu.nn as nn
-from bigdl_tpu.nn.attention import MultiHeadAttention
+from bigdl_tpu.nn.attention import MultiHeadAttention, scoreless
 from bigdl_tpu.nn.moe import MoE
 from bigdl_tpu.nn.module import (AUX_LOSS_KEY, Module, adopt_or_init,
                                   adopt_state)
@@ -84,7 +84,7 @@ class TransformerBlock(Module):
 
     def apply(self, params, state, input, *, training=False, rng=None,
               cache=None, positions=None, attend_len=None, attn_mask=None,
-              attn_segments=None):
+              attn_segments=None, fresh=False):
         r1, r2 = (jax.random.split(rng) if rng is not None else (None, None))
         # named scopes (here and in nn.attention) are the by-role
         # vocabulary a device trace's op names carry: docs/telemetry.md
@@ -103,7 +103,8 @@ class TransformerBlock(Module):
             # rows at `positions` and returns the updated cache
             h, cache = self.attn.forward_fn(
                 params["attn"], h, training=training, rng=r1,
-                cache=cache, positions=positions, attend_len=attend_len)
+                cache=cache, positions=positions, attend_len=attend_len,
+                fresh=fresh)
         x = input + h
         with jax.named_scope("norm"):
             h = self.ln2.forward_fn(params["ln2"], x)
@@ -127,7 +128,18 @@ class TransformerLM(Module):
     at the per-document ``positions`` (restarting at 0), so the packed
     forward is per-token exact against running each document alone.
     Segment id 0 marks padding; its logits are garbage by design (mask
-    their targets with the criterion's ``ignore_index``)."""
+    their targets with the criterion's ``ignore_index``).
+
+    **Served** by the generation engine through the contract of
+    ``generation/engine.py``: ``apply(..., cache=, positions=,
+    attend_len=)`` is one KV-cached step returning ``(logits, state,
+    cache)``; ``logits_at=`` (int ``[B]``): the logits of that one new
+    position a row come back, ``[B, 1, V]`` - a prefill multiplies one
+    row by the head, not every position's; ``live=`` (bool ``[B]``):
+    rows that are padding or free decode slots - accepted and unused,
+    no layer here lets one row's tokens touch another's; ``fresh=``
+    (static): a one-shot prefill, every offset 0 - the new tokens
+    attend only each other (``nn.attention.cached_attention``)."""
 
     def __init__(self, vocab_size: int, hidden_size: int = 512,
                  num_layers: int = 6, num_heads: int = 8,
@@ -157,6 +169,22 @@ class TransformerLM(Module):
             for i in range(num_layers)]
         self.ln_f = LayerNorm(hidden_size)
 
+    # ---- what the generation engine asks of a decoder it serves
+    def cache_layout(self, max_len: int):
+        """``[(kv heads, head dim, columns), ...]``, one per layer:
+        every layer keeps every position of all its heads."""
+        return [(self.num_heads, self.hidden_size // self.num_heads,
+                 max_len)] * self.num_layers
+
+    def cache_dtype(self):
+        """None: the cache takes the engine's default type."""
+        return None
+
+    def scoreless_prefill(self, rung: int) -> bool:
+        """Whether ``rung`` fresh tokens prefill in one shot without
+        materialised attention scores (never at GPT-2's head of 64)."""
+        return scoreless(rung, self.hidden_size // self.num_heads)
+
     def init(self, rng):
         dtype = Engine.default_dtype()
         keys = jax.random.split(rng, self.num_layers + 4)
@@ -178,7 +206,8 @@ class TransformerLM(Module):
                 for i, blk in enumerate(self.blocks)}
 
     def apply(self, params, state, input, *, training=False, rng=None,
-              cache=None, positions=None, attend_len=None):
+              cache=None, positions=None, attend_len=None,
+              logits_at=None, live=None, fresh=False):
         from bigdl_tpu.utils.table import Table
         seg = None
         packed_pos = None
@@ -251,10 +280,14 @@ class TransformerLM(Module):
                     params[f"block_{i}"], state.get(f"block_{i}", {}), x,
                     training=training, rng=keys[i],
                     cache={"k": cache["k"][i], "v": cache["v"][i]},
-                    positions=positions, attend_len=attend_len)
+                    positions=positions, attend_len=attend_len,
+                    fresh=fresh)
                 new_k.append(layer_cache["k"])
                 new_v.append(layer_cache["v"])
             new_state[f"block_{i}"] = st
+        if logits_at is not None:
+            x = jnp.take_along_axis(
+                x, logits_at.astype(jnp.int32)[:, None, None], axis=1)
         with jax.named_scope("norm"):
             x = self.ln_f.forward_fn(params["ln_f"], x)
         with jax.named_scope("lm_head"):

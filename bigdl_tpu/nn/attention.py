@@ -8,6 +8,7 @@ einsums that XLA tiles onto the MXU; bf16-friendly.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Optional
 
@@ -18,20 +19,26 @@ from bigdl_tpu.nn.module import Module
 from bigdl_tpu.utils.engine import Engine
 
 
-def _flash_attention_tpu(q, k, v, causal: bool, block: int = None):
+def _flash_attention_tpu(q, k, v, causal: bool):
     """jax's bundled pallas flash attention — O(S) memory, no
     materialized [S,S] score matrix. Which shapes it takes is
     ``_flash_eligible``'s decision, made before the call; whatever the
     kernel or the TPU compiler then raises reaches the caller.
-    ``block`` (forward only) sets its query and key tiles; its own
-    default is 128 x 128."""
+
+    The forward's query and key tiles are 512 where the length allows,
+    else the kernel's own 128: at 128 x 128 a layer of 48 x 4096 x 4096
+    read 11 ms on a v5e, a tenth of its operations, against 1.9. The
+    backward keeps the kernel's defaults."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes, flash_attention)
 
-    sizes = None if block is None else BlockSizes(
-        block_q=block, block_k_major=block, block_k=block, block_b=1)
+    b, h, s, d = q.shape
+    tile = 512 if s % 512 == 0 else 128
+    sizes = dataclasses.replace(
+        BlockSizes.get_default(b, h, s, k.shape[2], d),
+        block_q=tile, block_k_major=tile, block_k=tile)
     return flash_attention(q, k, v, causal=causal,
-                           sm_scale=1.0 / math.sqrt(q.shape[-1]),
+                           sm_scale=1.0 / math.sqrt(d),
                            block_sizes=sizes)
 
 
@@ -190,7 +197,7 @@ class MultiHeadAttention(Module):
 
     def forward_fn(self, params, input, *, training=False, rng=None,
                    cache=None, positions=None, attend_len=None,
-                   mask=None, segments=None):
+                   mask=None, segments=None, fresh=False):
         """Full-sequence attention, or — with ``cache=`` — one
         incremental (KV-cached) step.
 
@@ -210,25 +217,22 @@ class MultiHeadAttention(Module):
         ``cache`` is ``{"k": [B,H,D,T], "v": [B,H,D,T]}`` (T the
         cache's bucketed max length, on the lanes: the form the decode
         kernel reads, see ``generation/kv_cache.py``), ``positions`` an
-        int32 ``[B]`` of per-row write offsets: the S new tokens of row
-        ``b`` land at cache columns ``positions[b] .. positions[b]+S-1``
-        via ``dynamic_update_slice``, and each query at absolute position
-        ``p`` attends the cached keys ``j <= p`` under a length-masked
-        causal mask. ``attend_len`` (static) restricts attention to the
-        first ``attend_len`` cache slots so short sequences never scan
-        the whole preallocated cache — the per-bucket decode programs
-        close over one rung each. Returns ``(out, new_cache)``.
+        int32 ``[B]`` of per-row write offsets, ``attend_len`` and
+        ``fresh`` static: the step itself is :func:`cached_attention`,
+        shared with :class:`GroupedQueryAttention`, here with as many
+        K/V heads as query heads and no window. Returns ``(out,
+        new_cache)``.
 
-        Without ``cache`` the path below is byte-identical to the
-        pre-cache implementation (weights are shared; generation adds
-        no parameters)."""
+        Without ``cache`` the weights are the same (generation adds no
+        parameters)."""
         if cache is not None:
             if mask is not None or segments is not None:
                 raise ValueError(
                     "segment masks are not supported on the KV-cached "
                     "decode path (pack training slabs, not decode steps)")
-            return self._forward_cached(params, input, cache, positions,
-                                        attend_len)
+            if positions is None:
+                raise ValueError("cache= needs positions= (per-row int32 "
+                                 "write offsets into the KV cache)")
         if mask is not None and self.ring_axis is not None:
             raise ValueError(
                 "custom masks are not supported on the sequence-parallel "
@@ -246,11 +250,20 @@ class MultiHeadAttention(Module):
             q = split(self._proj(params, x, "q"))
             k = split(self._proj(params, x, "k"))
             v = split(self._proj(params, x, "v"))
-        with jax.named_scope("attn/core"):
-            out = self._core(q, k, v, mask, segments, training, rng)
+        if cache is None:
+            with jax.named_scope("attn/core"):
+                out = self._core(q, k, v, mask, segments, training, rng)
+        else:
+            # the S new rows go to the cache's [H,D,T] form, never the
+            # cache
+            out, cache = cached_attention(
+                q, jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3), cache,
+                positions.astype(jnp.int32), attend_len=attend_len,
+                fresh=fresh)
         with jax.named_scope("attn/out"):
             out = out.transpose(0, 2, 1, 3).reshape(b, s, e)
-            return self._proj(params, out, "o")
+            out = self._proj(params, out, "o")
+        return out if cache is None else (out, cache)
 
     def _core(self, q, k, v, mask, segments, training, rng):
         """``[B,H,S,D]`` attention by whichever path applies: a
@@ -288,84 +301,6 @@ class MultiHeadAttention(Module):
                 q, k, v, causal=self.causal, mask=mask,
                 dropout_rate=self.dropout, rng=rng, training=training,
                 segments=segments)
-        return out
-
-    def _forward_cached(self, params, x, cache, positions, attend_len):
-        """One KV-cached attention step (module ``forward_fn`` doc has
-        the contract). Pure: returns the updated cache, mutates
-        nothing."""
-        b, s, e = x.shape
-        h, d = self.num_heads, self.head_dim
-        if positions is None:
-            raise ValueError("cache= needs positions= (per-row int32 "
-                             "write offsets into the KV cache)")
-
-        def split(t):  # [B,S,E] -> [B,H,S,D]
-            return t.reshape(b, s, h, d).transpose(0, 2, 1, 3)
-
-        with jax.named_scope("attn/qkv"):
-            q = split(self._proj(params, x, "q"))
-            k = split(self._proj(params, x, "k"))
-            v = split(self._proj(params, x, "v"))
-
-        positions = positions.astype(jnp.int32)
-
-        # write the S new K/V columns at each row's offset, in place:
-        # the few new rows are transposed to the cache's [H,D,T] form,
-        # never the cache (XLA clamps an out-of-range start into the
-        # buffer; the driver only passes in-range offsets for live
-        # rows, and a clamped write into a FREE slot is re-written by
-        # that slot's next prefill before any mask ever exposes it)
-        def upd(c, u, p):  # c: [H,D,T], u: [H,S,D], p: scalar offset
-            return jax.lax.dynamic_update_slice(
-                c, jnp.swapaxes(u, 1, 2), (0, 0, p))
-
-        with jax.named_scope("attn/kv_write"):
-            ck = jax.vmap(upd)(cache["k"], k, positions)
-            cv = jax.vmap(upd)(cache["v"], v, positions)
-
-        al = ck.shape[3] if attend_len is None else int(attend_len)
-        with jax.named_scope("attn/core"):
-            out = self._cached_core(q, ck, cv, positions, al)
-        with jax.named_scope("attn/out"):
-            out = out.transpose(0, 2, 1, 3).reshape(b, s, e)
-            return self._proj(params, out, "o"), {"k": ck, "v": cv}
-
-    @staticmethod
-    def _cached_core(q, ck, cv, positions, al):
-        """The cached step's attention over the first ``al`` columns of
-        the ``[B,H,D,T]`` cache: the ragged decode kernel for one new
-        token a row (it takes the whole array), else the length-masked
-        einsum form."""
-        s = q.shape[2]
-        out = None
-        if s == 1:
-            # the decode step (one new token per row): the ragged
-            # pallas kernel reads only positions[b]+1 valid cache
-            # columns per slot instead of scanning the whole attend_len
-            # window — the host lengths vector the engine threads as
-            # `positions` is the kernel's ragged bound. Declined
-            # dispatch (kernels off / ineligible) falls through to the
-            # masked path below, bit-identical to the pre-kernel tree.
-            from bigdl_tpu import kernels as _kernels
-            out = _kernels.decode_attention(
-                q[:, :, 0, :], ck, cv, positions + 1, attend_len=al)
-            if out is not None:
-                out = out[:, :, None, :]
-        if out is None:
-            # length-masked causal mask: query i of row b sits at
-            # absolute position positions[b]+i and may see cache slots
-            # j <= that — fed through the ONE attention core above so
-            # the cached and full-sequence paths can never drift
-            # numerically (the swap back to [B,H,al,D] folds into the
-            # products' contraction dimensions)
-            jpos = jnp.arange(al)[None, None, None, :]
-            qpos = positions[:, None, None, None] \
-                + jnp.arange(s)[None, None, :, None]
-            out = dot_product_attention(
-                q, jnp.swapaxes(ck[..., :al], 2, 3),
-                jnp.swapaxes(cv[..., :al], 2, 3), mask=jpos <= qpos,
-                use_flash=False)
         return out
 
     def _sp_kernel(self, impl: Optional[str] = None):
@@ -430,6 +365,154 @@ def _grouped_values(weights, v_t):
     return out.reshape(b, n * g, sq, d)
 
 
+def _own_mask(s: int, window: Optional[int]):
+    """``[1, 1, 1, S, S]``: new token i sees new token j."""
+    i = jnp.arange(s)[:, None]
+    j = jnp.arange(s)[None, :]
+    ok = j <= i
+    if window is not None:
+        ok = ok & (i - j < window)
+    return ok[None, None, None]
+
+
+def _attend(q, parts):
+    """Soft-max attention of ``q`` over the concatenation of ``parts``
+    = ``[(k_t, v_t, mask), ...]``, each ``[B, Hkv, D, Sk]`` with a mask
+    broadcastable to ``[B, Hkv, G, Sq, Sk]``: the one length-masked
+    form every cached step falls back to (operands, float32 scores and
+    soft-max as ``dot_product_attention``'s, in the grouped spelling)."""
+    scores = [jnp.where(m, _grouped_scores(q, kt),
+                        jnp.finfo(jnp.float32).min)
+              for kt, _, m in parts]
+    w = jax.nn.softmax(jnp.concatenate(scores, axis=-1), axis=-1)
+    out, at = None, 0
+    for kt, vt, _ in parts:
+        n = kt.shape[-1]
+        o = _grouped_values(w[..., at:at + n], vt)
+        out, at = (o if out is None else out + o), at + n
+    return out
+
+
+def scoreless(length: int, head_dim: int,
+              window: Optional[int] = None) -> bool:
+    """Whether ``length`` fresh tokens can attend each other without
+    materialised scores: the bundled flash kernel takes them (it knows
+    the causal mask, not a window) and nothing interprets."""
+    from bigdl_tpu import kernels as _kernels
+
+    return (not _kernels.interpret_mode()
+            and length % 128 == 0 and head_dim % 128 == 0
+            and (window is None or length <= window))
+
+
+def _write_columns(cache, k_t, v_t, offsets, qpos, valid, window):
+    """The new columns ``k_t`` / ``v_t`` ``[B, Hkv, D, S]`` into the
+    layer's entry: at each row's offset in a global layer; in a ring at
+    ``p mod window``, one column in place for a decode step, else only
+    the real tokens (``valid``) that stay inside the window once the
+    call is over. (XLA clamps an out-of-range start into the buffer;
+    the engine passes in-range offsets for live rows, and a clamped
+    write into a FREE slot is re-written by that slot's next prefill
+    before any mask exposes it.)"""
+    s = k_t.shape[3]
+    if window is None or s == 1:
+        at = offsets if window is None else offsets % window
+
+        def put(cc, u, p):   # [Hkv,D,T], [Hkv,D,S], scalar offset
+            return jax.lax.dynamic_update_slice(cc, u, (0, 0, p))
+    else:
+        cols = cache["k"].shape[3]
+        n = (jnp.full_like(offsets, s) if valid is None
+             else valid.astype(jnp.int32))
+        i = jnp.arange(s, dtype=jnp.int32)[None, :]
+        keep = (i < n[:, None]) & (i >= n[:, None] - window)
+        at = jnp.where(keep, qpos % window, cols)        # cols: dropped
+
+        def put(cc, u, a):   # [Hkv,D,cols], [Hkv,D,S], [S]
+            return cc.at[:, :, a].set(u, mode="drop")
+
+    return {"k": jax.vmap(put)(cache["k"], k_t, at),
+            "v": jax.vmap(put)(cache["v"], v_t, at)}
+
+
+def cached_attention(q, k_t, v_t, cache, offsets, *,
+                     window: Optional[int] = None, attend_len=None,
+                     valid=None, fresh: bool = False):
+    """One KV-cached attention step, the only one: ``q [B, H, S, D]``
+    and the S new keys and values ``k_t`` / ``v_t`` ``[B, Hkv, D, S]``
+    (projected, normed and rotated by the caller) against the layer's
+    entry ``cache = {"k", "v"}`` ``[B, Hkv, D, C]``, as
+    :class:`~bigdl_tpu.generation.kv_cache.KVCache` keeps it. Returns
+    ``(out [B, H, S, D], new_cache)``; pure.
+
+    ``offsets`` (int32 ``[B]``): row ``b``'s new tokens sit at
+    positions ``offsets[b] .. offsets[b] + S - 1``. Without a
+    ``window`` position ``p`` lives at column ``p`` and a query attends
+    the columns ``j <= p`` of the first ``attend_len`` (static: the
+    engine's rung, so a short sequence never scans the whole entry).
+    With one the entry is a RING of ``window`` columns, position ``p``
+    at column ``p mod window``; a column needs no position of its own
+    (keys are rotated before they are stored), so a decode step attends
+    ``min(p + 1, window)`` columns in whatever order they lie.
+    ``valid`` (int ``[B]``, prefill only) is how many of the S new
+    tokens of each row are real: padding past it is never written into
+    a ring, where it would overwrite positions still in the window.
+    ``fresh`` (static): the rows' entries hold nothing yet and every
+    offset is 0 - a prompt prefilled in one shot. The new tokens then
+    attend only each other, through the bundled flash kernel where
+    :func:`scoreless` says it fits.
+
+    One new token a row goes through the ragged decode kernel
+    (``kernels.decode_attention``, which reads ``lengths`` columns of
+    the whole entry) where the dispatch takes it; everything else, and
+    a declined dispatch, through the length-masked :func:`_attend`. A
+    ring's prefill chunk or verify step attends what the ring held
+    BEFORE the call, told apart by the position each column must hold,
+    together with the new tokens themselves."""
+    s, d = q.shape[2], q.shape[3]
+    qpos = offsets[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+    cols = cache["k"].shape[3]
+    c = cols if attend_len is None else min(int(attend_len), cols)
+    with jax.named_scope("attn/kv_write"):
+        new = _write_columns(cache, k_t, v_t, offsets, qpos, valid,
+                             window)
+    with jax.named_scope("attn/core"):
+        out = None
+        if fresh and scoreless(s, d, window):
+            g = q.shape[1] // k_t.shape[1]
+            out = _flash_attention_tpu(
+                q, jnp.repeat(jnp.swapaxes(k_t, 2, 3), g, axis=1),
+                jnp.repeat(jnp.swapaxes(v_t, 2, 3), g, axis=1), True)
+        elif fresh:
+            out = _attend(q, [(k_t, v_t, _own_mask(s, window))])
+        elif s == 1:
+            from bigdl_tpu import kernels as _kernels
+            lengths = offsets + 1 if window is None \
+                else jnp.minimum(offsets + 1, window)
+            out = _kernels.decode_attention(
+                q[:, :, 0, :], new["k"], new["v"], lengths, attend_len=c)
+            if out is not None:
+                out = out[:, :, None, :]
+        if out is None and window is None:
+            mask = (jnp.arange(c)[None, None, :]
+                    <= qpos[:, :, None])[:, None, None]
+            out = _attend(q, [(new["k"][..., :c], new["v"][..., :c],
+                               mask)])
+        elif out is None:
+            # the position column j held before this call: the largest
+            # p <= offset - 1 with p mod window == j (negative: none)
+            j = jnp.arange(c, dtype=jnp.int32)[None, :]
+            last = offsets[:, None] - 1
+            held = last - (last - j) % window                 # [B, C]
+            old = ((held[:, None, :] >= 0)
+                   & (held[:, None, :] > qpos[:, :, None] - window))
+            out = _attend(
+                q, [(cache["k"][..., :c], cache["v"][..., :c],
+                     old[:, None, None]),
+                    (k_t, v_t, _own_mask(s, window))])
+    return out, new
+
+
 class GroupedQueryAttention(Module):
     """Causal attention whose K/V heads are fewer than its query heads,
     over [B, S, E] input, with the pieces today's decoders put around
@@ -445,23 +528,10 @@ class GroupedQueryAttention(Module):
     - ``gate``: the output is multiplied by ``sigmoid(x Wg)`` before
       ``Wo``. No biases.
 
-    ``fresh`` (static, with ``cache=``): the rows' entries hold nothing
-    yet and every offset is 0 — a prompt prefilled in one shot. The new
-    tokens then attend only each other, and where the shapes allow it
-    (a TPU, lane-aligned length and head, no window bound inside the
-    length) through jax's bundled flash kernel: no ``[S, S]`` scores.
-
-    **Cached.** ``cache`` is ``{"k", "v"}`` ``[B, Hkv, D, C]`` as
-    :class:`~bigdl_tpu.generation.kv_cache.KVCache` keeps this layer. A
-    global layer's cache holds position ``p`` at column ``p``, as
-    ``MultiHeadAttention``'s does. A window layer's is a RING of
-    ``window`` columns: position ``p`` lives at column ``p mod window``.
-    Keys are rotated before they are stored, so a column needs no
-    position of its own and a decode step attends ``min(p + 1,
-    window)`` columns in whatever order they lie. ``valid`` (int
-    ``[B]``, cached prefill only) is how many of the S new tokens of
-    each row are real: padding past it is never written into a ring,
-    where it would overwrite the oldest positions still in the window.
+    **Cached.** ``cache`` is ``{"k", "v"}`` ``[B, Hkv, D, C]``, a ring
+    of ``window`` columns for a window layer; the step, with
+    ``positions``, ``attend_len``, ``valid`` and ``fresh``, is
+    :func:`cached_attention`'s, shared with ``MultiHeadAttention``.
     """
 
     def __init__(self, hidden_size: int, num_heads: int,
@@ -509,14 +579,8 @@ class GroupedQueryAttention(Module):
                                                        max_len)
 
     def scoreless(self, length: int) -> bool:
-        """Whether ``length`` fresh tokens can attend each other without
-        materialised scores: the bundled flash kernel takes them (it
-        knows the causal mask, not a window) and nothing interprets."""
-        from bigdl_tpu import kernels as _kernels
-
-        return (not _kernels.interpret_mode()
-                and length % 128 == 0 and self.head_dim % 128 == 0
-                and (self.window is None or length <= self.window))
+        """:func:`scoreless` for this layer's head and window."""
+        return scoreless(length, self.head_dim, self.window)
 
     def forward_fn(self, params, input, *, training=False, rng=None,
                    cache=None, positions=None, attend_len=None,
@@ -550,30 +614,11 @@ class GroupedQueryAttention(Module):
         k_t, v_t = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
         if cache is None:
             with jax.named_scope("attn/core"):
-                out = self._attend(q, [(k_t, v_t, self._own_mask(s))])
-            new_cache = None
-        elif fresh:
-            with jax.named_scope("attn/core"):
-                if self.scoreless(s):
-                    g = self.num_heads // self.num_kv_heads
-                    # tiles of 512 where the length allows: at the
-                    # kernel's own 128 x 128 a layer of 48 x 4096 x 4096
-                    # read 11 ms on a v5e, a tenth of its operations
-                    out = _flash_attention_tpu(
-                        q, jnp.repeat(k, g, axis=1),
-                        jnp.repeat(v, g, axis=1), True,
-                        block=512 if s % 512 == 0 else 128)
-                else:
-                    out = self._attend(q, [(k_t, v_t, self._own_mask(s))])
-            with jax.named_scope("attn/kv_write"):
-                new_cache = self._write(cache, k_t, v_t, offsets, qpos,
-                                        valid)
-        elif self.window is None:
-            out, new_cache = self._global_step(q, k_t, v_t, cache,
-                                               offsets, qpos, attend_len)
+                out = _attend(q, [(k_t, v_t, _own_mask(s, self.window))])
         else:
-            out, new_cache = self._ring_step(q, k_t, v_t, cache, offsets,
-                                             qpos, attend_len, valid)
+            out, cache = cached_attention(
+                q, k_t, v_t, cache, offsets, window=self.window,
+                attend_len=attend_len, valid=valid, fresh=fresh)
         out = out.transpose(0, 2, 1, 3).reshape(b, s,
                                                 self.num_heads * d)
         if self.gate:
@@ -581,118 +626,4 @@ class GroupedQueryAttention(Module):
                 out = out * jax.nn.sigmoid(x @ params["wg"])
         with jax.named_scope("attn/out"):
             out = out @ params["wo"]
-        return out if cache is None else (out, new_cache)
-
-    # -------------------------------------------------------- pieces
-    def _own_mask(self, s: int):
-        """``[1, 1, 1, S, S]``: new token i sees new token j."""
-        i = jnp.arange(s)[:, None]
-        j = jnp.arange(s)[None, :]
-        ok = j <= i
-        if self.window is not None:
-            ok = ok & (i - j < self.window)
-        return ok[None, None, None]
-
-    @staticmethod
-    def _attend(q, parts):
-        """Soft-max attention of ``q`` over the concatenation of
-        ``parts`` = ``[(k_t, v_t, mask), ...]``, each ``[B, Hkv, D,
-        Sk]`` with a mask broadcastable to ``[B, Hkv, G, Sq, Sk]``."""
-        scores = [jnp.where(m, _grouped_scores(q, kt),
-                            jnp.finfo(jnp.float32).min)
-                  for kt, _, m in parts]
-        w = jax.nn.softmax(jnp.concatenate(scores, axis=-1), axis=-1)
-        out, at = None, 0
-        for kt, vt, _ in parts:
-            n = kt.shape[-1]
-            o = _grouped_values(w[..., at:at + n], vt)
-            out, at = (o if out is None else out + o), at + n
-        return out
-
-    def _global_step(self, q, k_t, v_t, cache, offsets, qpos,
-                     attend_len):
-        """Position p at column p: write the new columns at each row's
-        offset, then attend the first ``attend_len`` columns."""
-        with jax.named_scope("attn/kv_write"):
-            new = self._write(cache, k_t, v_t, offsets, qpos, None)
-            ck, cv = new["k"], new["v"]
-        al = ck.shape[3] if attend_len is None else min(int(attend_len),
-                                                        ck.shape[3])
-        with jax.named_scope("attn/core"):
-            out = None
-            if q.shape[2] == 1:
-                from bigdl_tpu import kernels as _kernels
-                out = _kernels.decode_attention(
-                    q[:, :, 0, :], ck, cv, offsets + 1, attend_len=al)
-                if out is not None:
-                    out = out[:, :, None, :]
-            if out is None:
-                mask = (jnp.arange(al)[None, None, :]
-                        <= qpos[:, :, None])[:, None, None]
-                out = self._attend(q, [(ck[..., :al], cv[..., :al],
-                                        mask)])
-        return out, new
-
-    def _ring_step(self, q, k_t, v_t, cache, offsets, qpos, attend_len,
-                   valid):
-        """Position p at column ``p mod window``. One new token a row
-        with the decode kernel on: write it, then the kernel reads the
-        ``min(p + 1, window)`` live columns. Otherwise (a prefill chunk,
-        a verify step, kernels off): attend what the ring held BEFORE
-        this call, told apart by the position each column must hold,
-        together with the new tokens themselves, then write the new
-        tokens that stay inside the window."""
-        w, s = self.window, q.shape[2]
-        cols = cache["k"].shape[3]
-        c = cols if attend_len is None else min(int(attend_len), cols)
-        with jax.named_scope("attn/kv_write"):
-            new = self._write(cache, k_t, v_t, offsets, qpos, valid)
-        if s == 1:
-            from bigdl_tpu import kernels as _kernels
-            with jax.named_scope("attn/core"):
-                out = _kernels.decode_attention(
-                    q[:, :, 0, :], new["k"], new["v"],
-                    jnp.minimum(offsets + 1, w), attend_len=c)
-            if out is not None:
-                return out[:, :, None, :], new
-        with jax.named_scope("attn/core"):
-            # the position column j held before this call: the largest
-            # p <= offset - 1 with p mod window == j (negative: none)
-            j = jnp.arange(c, dtype=jnp.int32)[None, :]
-            last = offsets[:, None] - 1
-            held = last - (last - j) % w                      # [B, C]
-            old = ((held[:, None, :] >= 0)
-                   & (held[:, None, :] > qpos[:, :, None] - w))
-            out = self._attend(
-                q, [(cache["k"][..., :c], cache["v"][..., :c],
-                     old[:, None, None]),
-                    (k_t, v_t, self._own_mask(s))])
-        return out, new
-
-    def _write(self, cache, k_t, v_t, offsets, qpos, valid):
-        """The new columns ``k_t`` / ``v_t`` ``[B, Hkv, D, S]`` into the
-        layer's entry: at each row's offset in a global layer; in a
-        ring at ``p mod window``, one column in place for a decode step,
-        else only the real tokens (``valid``) that stay inside the
-        window once the call is over."""
-        s = k_t.shape[3]
-
-        def upd(cc, u, p):  # cc: [Hkv,D,T], u: [Hkv,D,S]
-            return jax.lax.dynamic_update_slice(cc, u, (0, 0, p))
-
-        if self.window is None or s == 1:
-            at = offsets if self.window is None else offsets % self.window
-            return {"k": jax.vmap(upd)(cache["k"], k_t, at),
-                    "v": jax.vmap(upd)(cache["v"], v_t, at)}
-        w, cols = self.window, cache["k"].shape[3]
-        n = (jnp.full_like(offsets, s) if valid is None
-             else valid.astype(jnp.int32))
-        i = jnp.arange(s, dtype=jnp.int32)[None, :]
-        keep = (i < n[:, None]) & (i >= n[:, None] - w)
-        at = jnp.where(keep, qpos % w, cols)             # cols: dropped
-
-        def put(cc, u, a):   # [Hkv,D,cols], [Hkv,D,S], [S]
-            return cc.at[:, :, a].set(u, mode="drop")
-
-        return {"k": jax.vmap(put)(cache["k"], k_t, at),
-                "v": jax.vmap(put)(cache["v"], v_t, at)}
+        return out if cache is None else (out, cache)

@@ -79,8 +79,11 @@ _INST = register_agg_instruments(telemetry.registry())
 # the ONE flag the disarmed maybe_ship() fast path reads
 _ARMED = False
 _LOCK = threading.Lock()
-_STATE: dict = {"exporter": None, "interval_s": 1.0, "last": 0.0,
-                "path": None}
+# "last": when the last line was shipped on time.monotonic()'s clock,
+# -inf before the first (that clock starts near 0 at boot, so 0.0 would
+# gate the first ship of a long interval on a machine up for less)
+_STATE: dict = {"exporter": None, "interval_s": 1.0,
+                "last": float("-inf"), "path": None}
 
 
 def shipping() -> bool:
@@ -120,7 +123,7 @@ def start_shipping(directory: str, interval_s: float = 1.0,
             registry if registry is not None else telemetry.registry(),
             path, identity=ident, include_samples=True)
         _STATE["interval_s"] = max(float(interval_s), 0.0)
-        _STATE["last"] = 0.0
+        _STATE["last"] = float("-inf")
         _STATE["path"] = path
         _ARMED = True
     return path

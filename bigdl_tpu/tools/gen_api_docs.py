@@ -42,7 +42,6 @@ FAMILIES = {
                 "bigdl_tpu.kernels.dispatch",
                 "bigdl_tpu.kernels.flash_attention",
                 "bigdl_tpu.kernels.ragged_decode",
-                "bigdl_tpu.kernels.paged_decode",
                 "bigdl_tpu.kernels.int8_gemm",
                 "bigdl_tpu.kernels.common"],
     "autotune": ["bigdl_tpu.autotune", "bigdl_tpu.autotune.space",

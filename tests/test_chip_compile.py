@@ -244,21 +244,6 @@ def test_bundled_flash_escape_hatch_compiles(one_chip):
         lambda q, k, v: _flash_attention_tpu(q, k, v, True), q, k, v))
 
 
-def test_paged_decode_is_refused(one_chip):
-    """The record for ``paged_decode`` (no caller outside ``kernels/``,
-    ROADMAP C2): the TPU compiler refuses its ``(1, 1, d)`` blocks, in
-    these words. Nothing was spent on repairing it."""
-    from bigdl_tpu.kernels.paged_decode import paged_decode_attention
-
-    args = _on(one_chip, ((16, 12, 64), "float32"),
-               ((128, 12, 128, 64), "float32"),
-               ((128, 12, 128, 64), "float32"), ((16, 8), "int32"),
-               ((16,), "int32"))
-    with pytest.raises(Exception,
-                       match="last two dimensions of your block shape"):
-        _compile(paged_decode_attention, *args)
-
-
 # ------------------------------------------------- whole jitted steps
 
 @pytest.fixture(scope="module")
@@ -275,8 +260,8 @@ def lm():
     return model
 
 
-def _compile_decode(lm, one_chip, slots):
-    """The engine's own ``decode/1024`` program at ``slots`` slots,
+def _compile_program(lm, one_chip, slots, kind="decode"):
+    """The engine's own ``<kind>/1024`` program at ``slots`` slots,
     compiled at the policy a TPU gets by default (decode + int8 on, no
     interpreter); returns ``(compiled, donated cache specs, pallas
     dispatches taken by the trace)``."""
@@ -292,8 +277,8 @@ def _compile_decode(lm, one_chip, slots):
     programs = engine.abstract_programs(
         lm, abstract_tree(lm.get_parameters()),
         abstract_tree(lm.get_state()))
-    (decode,) = [p for p in programs if p[0] == f"decode/{MAX_LEN}"]
-    _, jitted, args = decode
+    (program,) = [p for p in programs if p[0] == f"{kind}/{MAX_LEN}"]
+    _, jitted, args = program
     args = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                        sharding=one_chip), args)
@@ -309,7 +294,7 @@ def test_decode_step_holds_the_kernel(one_chip, lm):
     """The engine's own decode program for the top rung, at the policy a
     TPU gets by default: its compiled text must contain the Mosaic
     kernel."""
-    compiled, _, taken = _compile_decode(lm, one_chip, SLOTS)
+    compiled, _, taken = _compile_program(lm, one_chip, SLOTS)
     assert taken == LM["layers"]
     assert _has_kernel(compiled)
 
@@ -328,7 +313,7 @@ def test_decode_step_holds_no_copy_of_the_cache(one_chip, lm):
     from bigdl_tpu.analysis.hlo import parse_hlo
 
     slots = 64
-    compiled, cache_leaves, _ = _compile_decode(lm, one_chip, slots)
+    compiled, cache_leaves, _ = _compile_program(lm, one_chip, slots)
     assert _has_kernel(compiled)
     layer_elems = slots * HEADS * MAX_LEN * HEAD_DIM
     layer_bytes = layer_elems * 4
@@ -578,33 +563,59 @@ def test_pattern_decode_step_aliases_both_kinds_of_cache(pattern_programs):
     assert text.count("bigdl_moe_gmm") >= 6
 
 
+@pytest.fixture(scope="module")
+def gpt2_prefill(one_chip, lm):
+    """The GPT-2 serve cell's prefill program (4 rows x rung 1024, 2
+    layers of GPT-2-small's widths) at the policy a TPU gets."""
+    compiled, _, taken = _compile_program(lm, one_chip, 64, "prefill")
+    return {"prefill": compiled, "taken": taken}
+
+
+@pytest.mark.parametrize("family,rows,vocab,hidden", [
+    ("pattern", 1, PD["vocab"], PD["hidden"]),
+    ("gpt2", 4, LM["vocab"], LM["hidden"])])
 def test_pattern_prefill_holds_no_scores_and_one_row_of_logits(
-        pattern_programs):
-    """Rung 4096 at 48 heads: the einsum form in one shot would hold
-    ``[4, 48, 4096, 4096]`` float32 scores (12.9 GB) and ``[4, 4096,
-    25024]`` logits. The engine decides by itself: where the model's
-    attention is scoreless at the rung (compiled kernels, 4096 <= the
-    window) ONE row in one shot through the bundled flash kernel, else
-    (interpreting, as the CPU does) ``[1, 512]`` pieces. The compiled
-    one-shot program holds no result as large as one row's ``heads x
-    4096 x 4096`` scores, no logits but one position a row, the flash
-    kernel twice and the grouped product six times."""
+        family, rows, vocab, hidden, request):
+    """Every served decoder's prefill returns ``[rows, V]`` and holds
+    no logits but one position a row (the engine hands each model
+    ``logits_at``; no ``[rows, Sq, V]`` array is sliced afterwards).
+
+    *pattern*, rung 4096 at 48 heads: the einsum form in one shot would
+    hold ``[4, 48, 4096, 4096]`` float32 scores (12.9 GB) and ``[4,
+    4096, 25024]`` logits. The engine decides by itself: where the
+    model's attention is scoreless at the rung (compiled kernels, 4096
+    <= the window) ONE row in one shot through the bundled flash
+    kernel, else (interpreting, as the CPU does) ``[1, 512]`` pieces.
+    The compiled one-shot program holds no result as large as one row's
+    ``heads x 4096 x 4096`` scores, the flash kernel twice and the
+    grouped product six times. *gpt2*, 4 rows of rung 1024 at a head of
+    64: not scoreless, so the einsum form and NO kernel
+    (``decode_attn_roofline`` counts every ``tpu_custom_call`` of a
+    trace as the decode kernel)."""
     from bigdl_tpu.analysis.hlo import parse_hlo
 
-    assert pattern_programs["pieces"] == (1, 512)
-    assert pattern_programs["shape"] == (1, 4096)
-    compiled = pattern_programs["prefill"]
-    assert compiled.as_text().count("tpu_custom_call") >= 8
+    programs = request.getfixturevalue(
+        {"pattern": "pattern_programs", "gpt2": "gpt2_prefill"}[family])
+    compiled = programs["prefill"]
     module = parse_hlo(compiled.as_text())
-    one_shot = PD["heads"] * 4096 * 4096
     ops = [op for _, op in module.find_ops()
            if op.opcode not in ("tuple", "parameter")]
+    wide = [(op.name, op.result_type) for op in ops
+            if f",{vocab}]" in op.result_type.split("{")[0]
+            and op.result_elements() > hidden * vocab]
+    assert not wide, wide
+    logits = jax.tree.leaves(compiled.out_info)[0]
+    assert logits.shape == (rows, vocab)
+    if family == "gpt2":
+        assert programs["taken"] == 0
+        assert "tpu_custom_call" not in compiled.as_text()
+        return
+    assert programs["pieces"] == (1, 512)
+    assert programs["shape"] == (1, 4096)
+    assert compiled.as_text().count("tpu_custom_call") >= 8
+    one_shot = PD["heads"] * 4096 * 4096
     big = [(op.name, op.result_type) for op in ops
            if op.result_elements() >= one_shot]
     assert not big, big
-    wide = [(op.name, op.result_type) for op in ops
-            if f",{PD['vocab']}]" in op.result_type.split("{")[0]
-            and op.result_elements() > PD["hidden"] * PD["vocab"]]
-    assert not wide, wide
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 << 30

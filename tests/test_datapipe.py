@@ -192,12 +192,21 @@ def test_padding_efficiency_math():
     assert packed_eff > 0.9 > dp.padding_efficiency(lens, 128)
 
 
-def test_packed_forward_bit_exact_vs_unpacked():
+# float32 reduction order differs between programs of different shapes
+# (a document alone in a [1, L] or padded [1, S] row against the same
+# document inside a packed slab): observed 1e-7 to 6e-7 on logits of
+# order 1. A leak across documents moves them by more than 1e-4 (the
+# control below), so this tolerance still tells the two apart.
+SHAPE_TOL = dict(rtol=1e-5, atol=1e-6)
+
+
+def test_packed_forward_agrees_with_unpacked():
     """THE segment-mask correctness assert: every document's logits in
-    a packed slab are BIT-IDENTICAL to running that document alone —
-    both as a padded row (same slab width) and as an unpadded [1, L]
-    forward. Any cross-document attention leak, positional-embedding
-    offset, or mask slip breaks bitwise equality."""
+    a packed slab agree with running that document alone (to float32
+    reduction order, ``SHAPE_TOL``) — both as a padded row (same slab
+    width) and as an unpadded [1, L] forward. Any cross-document
+    attention leak, positional-embedding offset, or mask slip moves
+    them by orders of magnitude more."""
     m = _tiny_lm()
     p, st = m.get_parameters(), m.get_state()
     docs = _docs(7, lo=4, hi=10, seed=1)
@@ -220,17 +229,19 @@ def test_packed_forward_bit_exact_vs_unpacked():
             p0[0, :n] = np.arange(n)
             ref = np.asarray(m.apply(p, st, [t0, s0, p0],
                                      training=False)[0])
-            assert np.array_equal(packed[r, at], ref[0, :n])
+            np.testing.assert_allclose(packed[r, at], ref[0, :n],
+                                       **SHAPE_TOL)
             # truly unpacked [1, L] forward
             ref2 = np.asarray(m.apply(p, st, x[None].astype(np.int32),
                                       training=False)[0])
-            assert np.array_equal(packed[r, at], ref2[0])
+            np.testing.assert_allclose(packed[r, at], ref2[0],
+                                       **SHAPE_TOL)
             checked += 1
     assert checked >= 7
 
 
 def test_packed_forward_differs_without_segment_mask():
-    """Control for the bit-exact assert: the SAME packed tokens with a
+    """Control for the agreement assert: the SAME packed tokens with a
     single all-ones segment plane (mask off) must NOT reproduce the
     per-document forwards — otherwise the exactness test proves
     nothing."""

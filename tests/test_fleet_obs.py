@@ -383,14 +383,26 @@ class TestShipper:
         row = next(x for x in rows if x["name"] == "train/x/events")
         assert row["series"][0]["value"] == 8.0
 
-    def test_interval_gate(self, tmp_path):
+    @pytest.mark.parametrize("uptime_s", [None, 5.0])
+    def test_interval_gate(self, tmp_path, monkeypatch, uptime_s):
+        """The first ship is free whatever ``time.monotonic()`` reads:
+        that clock starts near 0 at boot, and a shipper that took 0.0
+        for "never shipped" gated its first line on a machine up for
+        less than the interval (``uptime_s=5.0`` pins that; ``None`` is
+        the machine's own clock)."""
+        if uptime_s is not None:
+            from types import SimpleNamespace
+            monkeypatch.setattr(
+                agg, "time", SimpleNamespace(monotonic=lambda: uptime_s))
         r = MetricsRegistry()
         agg.start_shipping(str(tmp_path), interval_s=3600.0,
                            registry=r, identity={"pid": 1})
-        assert agg.maybe_ship() is not None   # first ship is free
-        assert agg.maybe_ship() is None       # gated
-        assert agg.maybe_ship(force=True) is not None
-        agg.stop_shipping(final=False)
+        try:
+            assert agg.maybe_ship() is not None   # first ship is free
+            assert agg.maybe_ship() is None       # gated
+            assert agg.maybe_ship(force=True) is not None
+        finally:
+            agg.stop_shipping(final=False)
 
     def test_disabled_maybe_ship_overhead_bounded(self):
         """Disarmed maybe_ship() must be ONE module-flag check — safe

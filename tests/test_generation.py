@@ -128,11 +128,13 @@ def test_greedy_decode_bit_identical_to_full_reforward_every_step():
         svc.shutdown()
 
 
-def test_prefill_logits_bitwise_and_decode_logits_tight():
-    """Engine-level exactness: prefill logits are BITWISE equal to the
-    padded full-sequence forward (same program shape), and decode-step
-    logits agree to float32 reduction order (the single-query GEMM is
-    a different — smaller — program by design)."""
+def test_prefill_and_decode_logits_agree_with_full_forward():
+    """Engine-level exactness: prefill and decode-step logits agree
+    with the padded full-sequence forward to float32 reduction order
+    (they are different programs by design: the prefill multiplies one
+    row by the head and attends its own tokens, a decode step is a
+    single-query product; observed 1e-7 to 6e-7 on logits of order 1,
+    so ``atol=1e-5`` is ample), and the greedy token is the same."""
     import jax
     import jax.numpy as jnp
 
@@ -159,8 +161,6 @@ def test_prefill_logits_bitwise_and_decode_logits_tight():
         padded[0, :len(toks)] = toks
         full = np.asarray(fwd(sv.params, sv.state,
                               jnp.asarray(padded)))[0, len(toks) - 1]
-        if step == 0:  # prefill: identical program shape => bitwise
-            assert np.array_equal(full, logits[0])
         np.testing.assert_allclose(logits[0], full, atol=1e-5, rtol=0)
         nxt = int(np.argmax(logits[0]))
         assert nxt == int(np.argmax(full))
@@ -484,7 +484,7 @@ def test_decode_step_records_its_cache_columns(attend_len, positions,
     from bigdl_tpu.kernels.ragged_decode import block_columns, kv_tile
 
     layout = [(2, 16, 4)] * 2 + [(2, 16, 512)] * 2
-    kv = KVCache(4, 4, 2, 512, 16, dtype="float32", layout=layout)
+    kv = KVCache(4, 512, layout, dtype="float32")
     model = SimpleNamespace(num_heads=4)
     positions = np.asarray(positions, np.int32)
     active = np.array([True, False, False, True])

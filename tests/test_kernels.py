@@ -187,11 +187,16 @@ class TestFlashAttention:
                                    np.asarray(ref), atol=2e-4,
                                    rtol=1e-4)
 
-    def test_packed_slab_bit_exact_vs_unpacked(self):
+    def test_packed_slab_agrees_with_unpacked(self):
         """THE packed-slab contract with the kernel enabled: every
-        document's logits in a packed slab are BIT-IDENTICAL to
-        running that document alone through the same kernel — the
-        datapipe guarantee (test_datapipe) survives the pallas path."""
+        document's logits in a packed slab agree with running that
+        document alone through the same kernel — the datapipe
+        guarantee (test_datapipe) survives the pallas path. The
+        tolerance is float32 reduction order between programs of
+        different shapes (a ``[1, L]`` row against the slab; observed
+        1e-7 to 6e-7 on logits of order 1); a leak across documents
+        moves them by more than 1e-4 (test_datapipe's control), and the
+        bitwise form of the leak-proof property is the next test's."""
         import bigdl_tpu.datapipe.packing as dp
 
         m = _tiny_lm()
@@ -210,8 +215,10 @@ class TestFlashAttention:
                     alone = np.asarray(m.apply(
                         p, st, toks[row, at][None].astype(np.int32),
                         training=False)[0])
-                    assert np.array_equal(packed[row, at], alone[0]), \
-                        f"row {row} seg {sid} leaked across documents"
+                    np.testing.assert_allclose(
+                        packed[row, at], alone[0], rtol=1e-5, atol=1e-6,
+                        err_msg=f"row {row} seg {sid} leaked across "
+                                f"documents")
                     checked += 1
         assert checked >= 7
 

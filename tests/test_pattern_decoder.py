@@ -245,6 +245,68 @@ def test_the_prefix_cache_refuses_entries_of_several_kinds(cut):
         svc.shutdown()
 
 
+# ------------------------------- the engine's contract, and no more
+
+#: what ``generation/engine.py``'s docstring says a served decoder
+#: provides, and what ``ModelRegistry.load`` asks of any module
+_CONTRACT = ("apply", "cache_layout", "cache_dtype", "scoreless_prefill",
+             "num_layers", "num_heads", "max_len", "vocab_size")
+_REGISTRY = ("ensure_initialized", "get_parameters", "get_state")
+
+
+class _ContractOnly:
+    """A decoder that shows exactly the written contract of ``model``:
+    any other attribute the engine or the cache might probe for
+    (``hidden_size``, ``head_dim``, ``window``, ...) is not there."""
+
+    def __init__(self, model, without=()):
+        self._model = model
+        self._names = set(_CONTRACT + _REGISTRY) - set(without)
+
+    def __getattr__(self, name):
+        if name.startswith("_") or name not in self._names:
+            raise AttributeError(
+                f"{name!r} is not in the served-decoder contract")
+        return getattr(self._model, name)
+
+
+def _tiny_gpt():
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.utils.random import RandomGenerator
+
+    RandomGenerator.set_seed(SEED)
+    model = TransformerLM(vocab_size=64, hidden_size=32, num_layers=2,
+                          num_heads=4, max_len=64).evaluate()
+    model.ensure_initialized()
+    return model
+
+
+@pytest.mark.parametrize("family", ["gpt2", "pattern"])
+def test_a_decoder_is_served_on_the_written_contract_alone(family):
+    """``KVCache.for_model`` and the engine's three programs ask a
+    model for what the contract lists and nothing else: each family,
+    shown through :class:`_ContractOnly`, loads and serves the tokens
+    it serves unwrapped; without ``cache_layout`` the load fails with
+    the plain ``AttributeError`` that names it."""
+    model = _tiny_gpt() if family == "gpt2" else build(tiny())
+    prompt = np.random.RandomState(4).randint(0, 64, 11).astype(np.int32)
+
+    def served(decoder):
+        svc = GenerationService(config=GenerationConfig(
+            slots=2, max_len=32, length_buckets=[16, 32],
+            max_new_tokens=8))
+        try:
+            svc.load("lm", decoder)
+            return list(svc.generate("lm", prompt).result(120))
+        finally:
+            svc.shutdown()
+
+    tokens = served(_ContractOnly(model))
+    assert len(tokens) == 8 and tokens == served(model)
+    with pytest.raises(AttributeError, match="cache_layout"):
+        served(_ContractOnly(model, without=("cache_layout",)))
+
+
 # ------------------------------------------------------ the expert layer
 
 def _dense_form(params, x, idx, w, offset, gated, act):

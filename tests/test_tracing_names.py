@@ -346,8 +346,6 @@ def _kernel_cases():
     from bigdl_tpu.kernels.flash_attention import (
         blockwise_flash_attention, flash_attention)
     from bigdl_tpu.kernels.int8_gemm import pallas_quantized_matmul
-    from bigdl_tpu.kernels.paged_decode import (paged_decode_attention,
-                                                paged_view)
     from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
 
     def grad_of(attn):
@@ -358,24 +356,15 @@ def _kernel_cases():
                                             interpret=True)
     blockwise = lambda q, k, v: blockwise_flash_attention(
         q, k, v, causal=True, interpret=True)
-    cache = jnp.zeros((2, 2, 16, 8), jnp.float32)   # [slots, H, T, D]
+    cache = jnp.zeros((2, 2, 8, 16), jnp.float32)   # [slots, H, D, T]
     lengths = jnp.ones((2,), jnp.int32)
-
-    def paged(q, k, v):
-        kp, vp, table = paged_view(k, v, 8)
-        return paged_decode_attention(q, kp, vp, table, lengths,
-                                      interpret=True)
 
     return {
         "ragged_decode": (
             lambda q, k, v: ragged_decode_attention(q, k, v, lengths,
                                                     interpret=True),
-            # the contiguous kernel reads [slots, H, D, T]
-            (jnp.zeros((2, 2, 8)), cache.swapaxes(2, 3),
-             cache.swapaxes(2, 3)),
+            (jnp.zeros((2, 2, 8)), cache, cache),
             {"bigdl_ragged_decode"}),
-        "paged_decode": (paged, (jnp.zeros((2, 2, 8)), cache, cache),
-                         {"bigdl_paged_decode"}),
         "int8_gemm": (
             lambda x, w, xs, ws: pallas_quantized_matmul(
                 x, w, xs, ws, interpret=True),
@@ -395,8 +384,8 @@ def _kernel_cases():
 
 
 @pytest.mark.parametrize("kernel", [
-    "ragged_decode", "paged_decode", "int8_gemm", "flash_fwd",
-    "flash_grad", "blockwise_fwd", "blockwise_grad"])
+    "ragged_decode", "int8_gemm", "flash_fwd", "flash_grad",
+    "blockwise_fwd", "blockwise_grad"])
 def test_every_pallas_call_has_its_name(kernel):
     fn, args, names = _kernel_cases()[kernel]
     found = _pallas_names(jax.make_jaxpr(fn)(*args).jaxpr)
@@ -404,8 +393,8 @@ def test_every_pallas_call_has_its_name(kernel):
 
 
 def test_no_pallas_call_site_is_left_unnamed():
-    """The walk above meets nine sites (the ninth: the expert layer's
-    ``bigdl_moe_gmm``); a tenth added to ``kernels/`` without a
+    """The walk above meets eight sites (the eighth: the expert layer's
+    ``bigdl_moe_gmm``); a ninth added to ``kernels/`` without a
     ``name=`` shows here."""
     import ast
 
@@ -422,7 +411,7 @@ def test_no_pallas_call_site_is_left_unnamed():
                 assert named and named[0].startswith("bigdl_"), \
                     f"{path}:{node.lineno}: pallas_call without name="
                 sites.append(named[0])
-    assert len(sites) == len(set(sites)) == 9
+    assert len(sites) == len(set(sites)) == 8
 
 
 # -------------------------------------------------- the train window
