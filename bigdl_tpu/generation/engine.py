@@ -71,6 +71,7 @@ via the compile counter in tests/test_fleet.py.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from collections import Counter
 from typing import Dict, Sequence, Set, Tuple
@@ -145,13 +146,17 @@ def _record_moe(stats, kind: str) -> None:
 
 
 def _record_kv(model, kv: KVCache, positions, active,
-               attend_len: int) -> None:
+               attend_len: int, kernel_layers: int = 0) -> None:
     """With the span tracer on, one decode step's cache columns into a
     ring record (``serving/decode/kv``): ``valid_columns``, the columns
     the live slots attend summed over the layers (``c``, or ``min(c,
     window)`` in a ring), and ``fetched_columns``, the same rounded up
     to the whole tiles the ragged decode kernel fetches, by its own
-    tile function. Host arithmetic on the lengths vector only."""
+    tile function; ``written_columns``, the new columns the step writes
+    (one a live slot and layer), and ``kernel_written_columns``, those
+    of the ``kernel_layers`` layers whose decode kernel wrote them
+    itself (counted when the step's program was traced). Host
+    arithmetic on the lengths vector only."""
     if not telemetry.enabled():
         return
     c = positions[active].astype(np.int64) + 1
@@ -165,7 +170,9 @@ def _record_kv(model, kv: KVCache, positions, active,
         fetched += layers * int((-(-n // tile) * tile).sum())
     telemetry.tracer().record(
         "serving/decode/kv", 0.0,
-        args={"valid_columns": valid, "fetched_columns": fetched})
+        args={"valid_columns": valid, "fetched_columns": fetched,
+              "written_columns": len(c) * len(kv.layout),
+              "kernel_written_columns": len(c) * kernel_layers})
 
 
 class DecodeEngine:
@@ -208,6 +215,9 @@ class DecodeEngine:
         # decode-loop thread registers while metrics readers iterate
         self._lock = threading.Lock()
         self._keys: Dict[Tuple, Set[Tuple]] = {}
+        # per decode program key: the layers whose decode kernel also
+        # wrote the step's cache column, counted when it was traced
+        self._kernel_layers: Dict[Tuple, int] = {}
 
     # ------------------------------------------------------- programs
     # items one program call processes (program-profile MFU basis):
@@ -289,19 +299,27 @@ class DecodeEngine:
         return jax.jit(serving_prefill, donate_argnums=(2, 3))
 
     @staticmethod
-    def _decode_jit(model, attend_len: int, on_trace):
+    def _decode_jit(model, attend_len: int, on_trace,
+                    kernel_wrote=lambda layers: None):
         """The raw decode-step jit for length bucket ``attend_len``
-        (donated cache) — shared like :meth:`_prefill_jit`."""
+        (donated cache) — shared like :meth:`_prefill_jit`. Each trace
+        tells ``kernel_wrote`` in how many layers the decode kernel
+        took the cache write with it (the dispatch's ``decode_write``
+        count)."""
         import jax
         import jax.numpy as jnp
+
+        from bigdl_tpu.kernels.dispatch import taken_in_thread
 
         def serving_decode(params, state, k, v, tokens, positions, active):
             on_trace()
             pos = jnp.where(active, positions.astype(jnp.int32), 0)
+            before = taken_in_thread("decode_write")
             logits, new_state, cache = model.apply(
                 params, state, tokens[:, None], training=False,
                 cache={"k": k, "v": v}, positions=pos,
                 attend_len=attend_len, live=active)
+            kernel_wrote(taken_in_thread("decode_write") - before)
             logits = logits[:, 0, :]
             # the greedy token of every slot rides along: a step whose
             # requests are all greedy copies [slots] ids to the host,
@@ -394,10 +412,12 @@ class DecodeEngine:
         (free) row at position 0, which the slot's next prefill
         re-writes before anything can attend it."""
         model = servable.model
+        key = servable.key + ("decode", attend_len)
         return self._program(
             servable, "decode", attend_len,
-            lambda on_trace: self._decode_jit(model, attend_len,
-                                              on_trace))
+            lambda on_trace: self._decode_jit(
+                model, attend_len, on_trace,
+                functools.partial(self._kernel_layers.__setitem__, key)))
 
     def verify_program(self, servable, attend_len: int):
         """The compiled speculative-verify step for length bucket
@@ -602,7 +622,8 @@ class DecodeEngine:
         with telemetry.span("serving/decode/device_wait"):
             if width == 1:      # host work behind the device's
                 _record_kv(servable.model, kv, positions, active,
-                           attend_len)
+                           attend_len, self._kernel_layers.get(
+                               servable.key + ("decode", attend_len), 0))
             jax.block_until_ready(wanted)
         with telemetry.span("serving/decode/logits_d2h"):
             host = np.asarray(wanted)
@@ -667,3 +688,4 @@ class DecodeEngine:
             keys = self._keys.pop(key, ())
         for k in keys:
             self.cache.drop(k)
+            self._kernel_layers.pop(k, None)

@@ -10,7 +10,8 @@ ONE gate in front of them:
   bit-faithfully), custom-VJP backward, no materialized [S, S];
 - :mod:`~bigdl_tpu.kernels.ragged_decode` — ragged decode
   attention for the generation engine: reads only ``lengths[i]`` valid
-  KV per slot instead of the bucket max;
+  KV per slot instead of the bucket max, and writes the step's new K/V
+  column into the cache itself;
 - :mod:`~bigdl_tpu.kernels.int8_gemm` — fused dequant-int8-GEMM
   completing the BigQuant serving story over the calibrated scales;
 - :mod:`~bigdl_tpu.kernels.moe_gmm` — the routed expert layer's

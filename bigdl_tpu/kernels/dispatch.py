@@ -14,9 +14,11 @@ configuration is byte-identical to the pre-kernel tree.
 Dispatch decisions happen at TRACE time (config and shapes are
 static), so the per-trace counters below count compiled-program
 routing, not per-step calls: ``kernels/dispatch/pallas`` (label
-``op=flash|decode|int8|gmm``) vs ``kernels/dispatch/reference`` (labels
-``op=...`` plus ``reason=config|shape|vmem`` so a `diagnose` dump
-attributes every decline).
+``op=flash|decode|decode_write|int8|gmm``; ``decode_write`` is a second
+label of the decode kernel, for the cache column it writes) vs
+``kernels/dispatch/reference`` (labels ``op=...`` plus
+``reason=config|shape|vmem`` so a `diagnose` dump attributes every
+decline).
 """
 from __future__ import annotations
 
@@ -36,7 +38,8 @@ __all__ = ["attention", "decode_attention", "int8_matmul",
 # REAL instruments on import, not a hand-maintained name list
 _C_PALLAS = telemetry.counter(
     "kernels/dispatch/pallas",
-    "traces routed to a pallas kernel (label op=flash|decode|int8)")
+    "traces routed to a pallas kernel (label op=flash|decode|"
+    "decode_write|int8|gmm)")
 _C_REFERENCE = telemetry.counter(
     "kernels/dispatch/reference",
     "traces declined by the dispatch layer to the pure-jnp reference "
@@ -51,11 +54,15 @@ _C_REFERENCE = telemetry.counter(
 _TRACE = threading.local()
 
 
-def taken_in_thread() -> int:
+def taken_in_thread(op: Optional[str] = None) -> int:
     """Monotonic count of pallas dispatches taken on this thread —
     snapshot before and after a ``lower()``/trace to learn whether the
-    traced program actually contains a kernel."""
-    return getattr(_TRACE, "taken", 0)
+    traced program actually contains a kernel. With ``op``, the count
+    under that one label (``decode_write``: the decode kernels that
+    also wrote their step's cache column)."""
+    if op is None:
+        return getattr(_TRACE, "taken", 0)
+    return getattr(_TRACE, "by_op", {}).get(op, 0)
 
 
 def _declined(op: str, reason: str) -> None:
@@ -66,9 +73,13 @@ def _declined(op: str, reason: str) -> None:
     _C_REFERENCE.inc(op=op, reason=reason)
 
 
-def _taken(op: str) -> None:
-    _C_PALLAS.inc(op=op)
+def _taken(*ops: str) -> None:
+    """One kernel taken, counted under each of its labels."""
     _TRACE.taken = getattr(_TRACE, "taken", 0) + 1
+    by_op = _TRACE.__dict__.setdefault("by_op", {})
+    for op in ops:
+        _C_PALLAS.inc(op=op)
+        by_op[op] = by_op.get(op, 0) + 1
 
 
 def _floating(*arrays) -> bool:
@@ -140,30 +151,40 @@ def attention(q, k, v, *, causal: bool = False, segment_ids=None,
                            interpret=interpret)
 
 
-def decode_attention(q, k, v, lengths, *, attend_len: int = None,
+def decode_attention(q, k, v, lengths, *, new_k, new_v, write_at,
+                     attend_len: int = None,
                      sm_scale: Optional[float] = None):
-    """Ragged-decode dispatch: ``q [slots, H, D]`` (one token per
-    slot), ``k``/``v`` one layer's whole ``[slots, Hkv, D, T]`` cache
-    (``H`` a multiple of ``Hkv``: grouped-query attention),
-    ``lengths`` the host per-slot valid-KV vector, ``attend_len`` the
-    (static) ladder rung. Returns the kernel result
-    (:mod:`bigdl_tpu.kernels.ragged_decode` — reads only
-    ``lengths[i]`` columns per slot) when ``decode`` is enabled and the
-    shapes qualify, else **None** (the caller's length-masked einsum
-    path runs)."""
+    """Ragged-decode dispatch, the step's cache write included: ``q
+    [slots, H, D]`` (one token per slot), ``k``/``v`` one layer's whole
+    ``[slots, Hkv, D, T]`` cache as it stands BEFORE the step (``H`` a
+    multiple of ``Hkv``: grouped-query attention), ``new_k``/``new_v``
+    ``[slots, Hkv, D]`` the new token's key and value in the cache's
+    dtype, ``write_at`` (int32 ``[slots]``) the column they go to,
+    ``lengths`` the host per-slot valid-KV vector with the new column
+    counted, ``attend_len`` the (static) ladder rung. Returns ``(out,
+    k, v)`` from the kernel (:mod:`bigdl_tpu.kernels.ragged_decode` —
+    reads only ``lengths[i]`` columns per slot and writes the new one
+    in place, the cache aliased through) when ``decode`` is enabled and
+    the shapes qualify, else **None**: nothing is written, and the
+    caller writes the column itself and runs its length-masked einsum
+    path. A taken dispatch counts under ``op=decode`` and, for the
+    column it wrote, ``op=decode_write``."""
     if not _config.enabled("decode"):
         _declined("decode", "config")
         return None
     if (k.ndim != 4 or v.shape != k.shape or q.ndim != 3
             or q.shape[0] != k.shape[0] or q.shape[2] != k.shape[2]
-            or q.shape[1] % k.shape[1] or not _floating(q, k, v)):
+            or q.shape[1] % k.shape[1] or not _floating(q, k, v)
+            or new_k.shape != k.shape[:3] or new_v.shape != k.shape[:3]
+            or not new_k.dtype == new_v.dtype == k.dtype == v.dtype):
         _declined("decode", "shape")
         return None
     from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
 
-    _taken("decode")
+    _taken("decode", "decode_write")
     return ragged_decode_attention(
-        q, k, v, lengths, attend_len=attend_len, sm_scale=sm_scale,
+        q, k, v, lengths, write_at, new_k, new_v, attend_len=attend_len,
+        sm_scale=sm_scale,
         interpret=_config.get_config().resolve_interpret())
 
 

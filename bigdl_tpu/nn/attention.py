@@ -410,7 +410,12 @@ def _write_columns(cache, k_t, v_t, offsets, qpos, valid, window):
     layer's entry: at each row's offset in a global layer; in a ring at
     ``p mod window``, one column in place for a decode step, else only
     the real tokens (``valid``) that stay inside the window once the
-    call is over. (XLA clamps an out-of-range start into the buffer;
+    call is over. Prefill chunks and ``verify`` steps (``S > 1``) write
+    here; a decode step's one column is the ragged decode kernel's own
+    (:func:`cached_attention`), so the ``S == 1`` arm is the fallback's
+    alone: XLA makes a ``while`` over the rows of it, each turn a
+    read-modify-write across ``Hkv x D / 8`` vector tiles.
+    (XLA clamps an out-of-range start into the buffer;
     the engine passes in-range offsets for live rows, and a clamped
     write into a FREE slot is re-written by that slot's next prefill
     before any mask exposes it.)"""
@@ -462,22 +467,38 @@ def cached_attention(q, k_t, v_t, cache, offsets, *,
     attend only each other, through the bundled flash kernel where
     :func:`scoreless` says it fits.
 
-    One new token a row goes through the ragged decode kernel
-    (``kernels.decode_attention``, which reads ``lengths`` columns of
-    the whole entry) where the dispatch takes it; everything else, and
-    a declined dispatch, through the length-masked :func:`_attend`. A
-    ring's prefill chunk or verify step attends what the ring held
-    BEFORE the call, told apart by the position each column must hold,
-    together with the new tokens themselves."""
+    One new token a row (not ``fresh``) is offered to the ragged
+    decode kernel FIRST (``kernels.decode_attention``), with the entry
+    as it stands, the new column and where it goes: where the dispatch
+    takes it, the kernel attends the new token with the ``lengths - 1``
+    other columns and writes the column in place, and nothing else
+    touches the entry. Everything else, and a declined dispatch (config
+    off, a non-floating cache, a shape), writes through
+    :func:`_write_columns` and attends through the length-masked
+    :func:`_attend`. A ring's prefill chunk or verify step attends what
+    the ring held BEFORE the call, told apart by the position each
+    column must hold, together with the new tokens themselves."""
     s, d = q.shape[2], q.shape[3]
     qpos = offsets[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
     cols = cache["k"].shape[3]
     c = cols if attend_len is None else min(int(attend_len), cols)
+    if s == 1 and not fresh:
+        from bigdl_tpu import kernels as _kernels
+        with jax.named_scope("attn/core"):
+            taken = _kernels.decode_attention(
+                q[:, :, 0, :], cache["k"], cache["v"],
+                offsets + 1 if window is None
+                else jnp.minimum(offsets + 1, window),
+                new_k=k_t[..., 0], new_v=v_t[..., 0],
+                write_at=offsets if window is None else offsets % window,
+                attend_len=c)
+        if taken is not None:
+            out, k, v = taken
+            return out[:, :, None, :], {"k": k, "v": v}
     with jax.named_scope("attn/kv_write"):
         new = _write_columns(cache, k_t, v_t, offsets, qpos, valid,
                              window)
     with jax.named_scope("attn/core"):
-        out = None
         if fresh and scoreless(s, d, window):
             g = q.shape[1] // k_t.shape[1]
             out = _flash_attention_tpu(
@@ -485,20 +506,12 @@ def cached_attention(q, k_t, v_t, cache, offsets, *,
                 jnp.repeat(jnp.swapaxes(v_t, 2, 3), g, axis=1), True)
         elif fresh:
             out = _attend(q, [(k_t, v_t, _own_mask(s, window))])
-        elif s == 1:
-            from bigdl_tpu import kernels as _kernels
-            lengths = offsets + 1 if window is None \
-                else jnp.minimum(offsets + 1, window)
-            out = _kernels.decode_attention(
-                q[:, :, 0, :], new["k"], new["v"], lengths, attend_len=c)
-            if out is not None:
-                out = out[:, :, None, :]
-        if out is None and window is None:
+        elif window is None:
             mask = (jnp.arange(c)[None, None, :]
                     <= qpos[:, :, None])[:, None, None]
             out = _attend(q, [(new["k"][..., :c], new["v"][..., :c],
                                mask)])
-        elif out is None:
+        else:
             # the position column j held before this call: the largest
             # p <= offset - 1 with p mod window == j (negative: none)
             j = jnp.arange(c, dtype=jnp.int32)[None, :]

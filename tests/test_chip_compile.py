@@ -82,6 +82,23 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _decode_operands(sharding, slots, hq, hkv, d, t, dtype):
+    """``ragged_decode_attention``'s operands: q, one layer's K and V,
+    lengths, write_at and the step's new K and V column."""
+    cache, new = ((slots, hkv, d, t), dtype), ((slots, hkv, d), dtype)
+    return _on(sharding, ((slots, hq, d), dtype), cache, cache,
+               ((slots,), "int32"), ((slots,), "int32"), new, new)
+
+
+def _compile_decode(args, **kwargs):
+    """The kernel alone, the cache donated as the engine donates it."""
+    from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
+
+    return jax.jit(
+        lambda *a: ragged_decode_attention(*a, **kwargs),
+        donate_argnums=(1, 2)).lower(*args).compile()
+
+
 def _grad_of(attn):
     def loss(q, k, v, *seg):
         return attn(q, k, v, *seg).astype(jnp.float32).sum()
@@ -98,16 +115,9 @@ def test_ragged_decode_compiles_at_ladder_rungs(one_chip, t, dtype):
     ladder up to ``max_len`` 1024 — below one lane tile, one tile,
     several tiles — each reading the whole ``[slots, H, D, max_len]``
     cache through a block of that rung's width."""
-    from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
-
-    q, k, v, lengths = _on(
-        one_chip, ((SLOTS, HEADS, HEAD_DIM), dtype),
-        ((SLOTS, HEADS, HEAD_DIM, MAX_LEN), dtype),
-        ((SLOTS, HEADS, HEAD_DIM, MAX_LEN), dtype), ((SLOTS,), "int32"))
-    assert _has_kernel(_compile(
-        lambda q, k, v, n: ragged_decode_attention(q, k, v, n,
-                                                   attend_len=t),
-        q, k, v, lengths))
+    args = _decode_operands(one_chip, SLOTS, HEADS, HEADS, HEAD_DIM,
+                            MAX_LEN, dtype)
+    assert _has_kernel(_compile_decode(args, attend_len=t))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -115,25 +125,15 @@ def test_ragged_decode_compiles_at_ladder_rungs(one_chip, t, dtype):
 def test_ragged_decode_compiles_at_short_caches(one_chip, t, dtype):
     """A cache shorter than a lane tile, or not a whole number of them
     (a service with a small ``max_len``): the block is all of ``T``."""
-    from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
-
-    q, k, v, lengths = _on(
-        one_chip, ((SLOTS, HEADS, HEAD_DIM), dtype),
-        ((SLOTS, HEADS, HEAD_DIM, t), dtype),
-        ((SLOTS, HEADS, HEAD_DIM, t), dtype), ((SLOTS,), "int32"))
-    assert _has_kernel(_compile(ragged_decode_attention, q, k, v,
-                                lengths))
+    args = _decode_operands(one_chip, SLOTS, HEADS, HEADS, HEAD_DIM, t,
+                            dtype)
+    assert _has_kernel(_compile_decode(args))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_ragged_decode_compiles_at_head_dim_128(one_chip, dtype):
-    from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
-
-    q, k, v, lengths = _on(
-        one_chip, ((16, 8, 128), dtype), ((16, 8, 128, 512), dtype),
-        ((16, 8, 128, 512), dtype), ((16,), "int32"))
-    assert _has_kernel(_compile(ragged_decode_attention, q, k, v,
-                                lengths))
+    args = _decode_operands(one_chip, 16, 8, 8, 128, 512, dtype)
+    assert _has_kernel(_compile_decode(args))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -299,17 +299,41 @@ def test_decode_step_holds_the_kernel(one_chip, lm):
     assert _has_kernel(compiled)
 
 
+def _root_opcode(module, op):
+    """``op``'s opcode, or its root's where it is a fusion."""
+    fused = module.computations.get(op.called.get("calls", ""))
+    if op.opcode != "fusion" or fused is None:
+        return op.opcode
+    return next(o.opcode for o in fused.ops if o.is_root)
+
+
+def _cache_writes_outside_the_kernel(module, shapes):
+    """Operations of a parsed decode step that write a layer's cache
+    entry any other way than the decode kernel does: a
+    ``dynamic-update-slice`` (alone or as a fusion's root) whose result
+    has one of the entries' ``shapes``, and a ``while`` that carries
+    one (the per-slot loop XLA made of ``_write_columns``; the expert
+    layer's dispatch plan keeps a loop over small int32 vectors)."""
+    dims = {"[" + ",".join(map(str, shape)) + "]" for shape in shapes}
+    return [(op.name, _root_opcode(module, op), op.result_type)
+            for _, op in module.find_ops()
+            if _root_opcode(module, op) in ("while", "dynamic-update-slice")
+            and any(d in op.result_type for d in dims)]
+
+
 def test_decode_step_holds_no_copy_of_the_cache(one_chip, lm):
     """The serve cell's decode program (64 slots x 1024, GPT-2-small
-    widths, 2 layers): the cache is stored as the kernel reads it, so
-    the compiled step aliases every donated cache leaf to its output,
-    keeps less than one layer's K in temporaries, and holds no ``copy``
-    and no ``slice`` (alone or as a fusion's root) of a whole layer's
-    K or V. What may remain at that shape are the in-place
-    ``dynamic-update-slice``s of the new columns. (The stacked
-    ``[layers, slots, H, T, D]`` cache read 1.41 GB of temporaries
-    here: two transposing copies, a slice and a stack rewrite per
-    layer and K/V.)"""
+    widths, 2 layers): the cache is stored as the kernel reads it and
+    the kernel writes the step's new columns itself, so the compiled
+    step aliases every donated cache leaf to its output, keeps less
+    than one layer's K in temporaries, and holds no ``copy`` and no
+    ``slice`` (alone or as a fusion's root) of a whole layer's K or V,
+    no ``dynamic-update-slice`` whose result is a layer's entry and no
+    ``while`` at all: nothing but the kernel touches an entry. (The
+    stacked ``[layers, slots, H, T, D]`` cache read 1.41 GB of
+    temporaries here: two transposing copies, a slice and a stack
+    rewrite per layer and K/V; ``_write_columns``' per-slot loop then
+    took 1,536 read-modify-write turns a step.)"""
     from bigdl_tpu.analysis.hlo import parse_hlo
 
     slots = 64
@@ -326,18 +350,15 @@ def test_decode_step_holds_no_copy_of_the_cache(one_chip, lm):
     assert mem.temp_size_in_bytes < layer_bytes, mem.temp_size_in_bytes
 
     module = parse_hlo(compiled.as_text())
-
-    def root_opcode(op):
-        fused = module.computations.get(op.called.get("calls", ""))
-        if op.opcode != "fusion" or fused is None:
-            return op.opcode
-        return next(o.opcode for o in fused.ops if o.is_root)
-
-    moved = [(op.name, root_opcode(op), op.result_type)
+    moved = [(op.name, _root_opcode(module, op), op.result_type)
              for _, op in module.find_ops()
              if op.result_elements() >= layer_elems
-             and root_opcode(op) in ("copy", "slice", "transpose")]
+             and _root_opcode(module, op) in ("copy", "slice", "transpose")]
     assert not moved, moved
+    written = _cache_writes_outside_the_kernel(
+        module, {a.shape for a in cache_leaves})
+    assert not written, written
+    assert not list(module.find_ops("while"))
 
 
 def _train_step_args(lm, optim, policy, replicated, batch_sharding,
@@ -443,16 +464,10 @@ def test_grouped_product_compiles(one_chip, rows, tile_m):
 def test_ragged_decode_compiles_with_grouped_heads(one_chip, t):
     """48 query heads over 8 K/V heads of 128: the query block is the
     ``[6, 128]`` group, the cache block ``[128, rung]``."""
-    from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
-
-    s, d = PD["slots"], PD["head_dim"]
-    cache = ((s, PD["kv_heads"], d, PD["max_len"]), "bfloat16")
-    q, k, v, n = _on(one_chip, ((s, PD["heads"], d), "bfloat16"), cache,
-                     cache, ((s,), "int32"))
-    assert _has_kernel(_compile(
-        lambda q, k, v, n: ragged_decode_attention(q, k, v, n,
-                                                   attend_len=t),
-        q, k, v, n))
+    args = _decode_operands(one_chip, PD["slots"], PD["heads"],
+                            PD["kv_heads"], PD["head_dim"], PD["max_len"],
+                            "bfloat16")
+    assert _has_kernel(_compile_decode(args, attend_len=t))
 
 
 @pytest.mark.parametrize("slots,hq,hkv,d,t,rung,dtype", [
@@ -468,21 +483,24 @@ def test_ragged_decode_compiles_at_the_serve_cells(one_chip, slots, hq,
     """Both serve cells' decode kernels at their real shapes, the K/V
     tile on the grid at the width the kernel sizes for the shape (one
     tile a GPT-2 row, 2048 columns of Trinity's), running max, sum and
-    accumulator in VMEM scratch across a slot-head's tiles."""
-    from bigdl_tpu.kernels.ragged_decode import (block_columns, kv_tile,
-                                                 ragged_decode_attention)
+    accumulator in VMEM scratch across a slot-head's tiles - and the
+    step's new column written by the same kernel: a ``[D, 128]`` lane
+    tile a slot-head cut out of the fetched K/V tile at a dynamic,
+    lane-aligned offset (all 64 columns of the short cache), K and V
+    aliased to their outputs with nothing held beside them."""
+    from bigdl_tpu.kernels.ragged_decode import block_columns, kv_tile
 
     tile = kv_tile(block_columns(t, rung), d, hq // hkv,
                    np.dtype(dtype).itemsize)
     assert tile == {1024: 1024, 64: 64}.get(t, 2048)
-    cache = ((slots, hkv, d, t), dtype)
-    q, k, v, n = _on(one_chip, ((slots, hq, d), dtype), cache, cache,
-                     ((slots,), "int32"))
-    compiled = _compile(
-        lambda q, k, v, n: ragged_decode_attention(q, k, v, n,
-                                                   attend_len=rung),
-        q, k, v, n)
+    compiled = _compile_decode(
+        _decode_operands(one_chip, slots, hq, hkv, d, t, dtype),
+        attend_len=rung)
     assert "bigdl_ragged_decode" in compiled.as_text()
+    cache_bytes = 2 * slots * hkv * d * t * np.dtype(dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 100
 
 
 @pytest.fixture(scope="module")
@@ -547,8 +565,10 @@ def test_pattern_decode_step_aliases_both_kinds_of_cache(pattern_programs):
     """A ring of 4096 columns and a whole context of 6144 side by side,
     8 K/V heads each: the compiled decode step aliases every leaf of
     both kinds to its output, keeps temporaries under a hundredth of
-    the cache, and holds the decode kernel (2) and the grouped product
-    (2 layers x gate, up, down)."""
+    the cache (under one layer's K), and holds the decode kernel (2)
+    and the grouped product (2 layers x gate, up, down); the kernel
+    writes both kinds' new columns, so no ``dynamic-update-slice``
+    results in an entry and no ``while`` carries one."""
     compiled, leaves = (pattern_programs["decode"],
                         pattern_programs["cache"])
     assert [a.shape[3] for a in leaves] == [4096, 6144, 4096, 6144]
@@ -561,6 +581,11 @@ def test_pattern_decode_step_aliases_both_kinds_of_cache(pattern_programs):
     text = compiled.as_text()
     assert text.count("bigdl_ragged_decode") >= 2
     assert text.count("bigdl_moe_gmm") >= 6
+    from bigdl_tpu.analysis.hlo import parse_hlo
+
+    written = _cache_writes_outside_the_kernel(
+        parse_hlo(text), {a.shape for a in leaves})
+    assert not written, written
 
 
 @pytest.fixture(scope="module")

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 import bigdl_tpu.telemetry as telemetry
-from bigdl_tpu import faults
+from bigdl_tpu import faults, kernels
 from bigdl_tpu.generation import (GenerationConfig, GenerationService,
                                   KVCache, SamplingParams, Sampler,
                                   SlotAllocator, TokenStream)
@@ -494,11 +494,44 @@ def test_decode_step_records_its_cache_columns(attend_len, positions,
     assert not telemetry.tracer().spans()
     telemetry.enable()
     try:
-        _record_kv(model, kv, positions, active, attend_len)
+        _record_kv(model, kv, positions, active, attend_len, 3)
         (rec,) = [r for r in telemetry.tracer().spans()
                   if r.name == "serving/decode/kv"]
         assert (rec.args["valid_columns"],
                 rec.args["fetched_columns"]) == want
+        # two live slots, four layers; the kernel wrote in three
+        assert (rec.args["written_columns"],
+                rec.args["kernel_written_columns"]) == (8, 6)
+    finally:
+        telemetry.disable()
+        telemetry.tracer().clear()
+
+
+@pytest.mark.parametrize("config, share", [
+    (kernels.KernelConfig.off(), 0.0),                 # _write_columns
+    (kernels.KernelConfig(decode_attention=True), 1.0),    # the kernel
+], ids=["fallback_wrote", "kernel_wrote"])
+def test_decode_step_records_who_wrote_its_columns(config, share):
+    """``serving/decode/kv``'s ``kernel_written_columns`` over
+    ``written_columns``, as ``decode_kv_write_in_kernel_share.serve``
+    reads it: 0 from a service whose decode program fell back to
+    ``_write_columns`` in every layer, 1 from one whose decode kernel
+    took the write - counted by the dispatch when each program was
+    traced and kept by the engine beside the program."""
+    telemetry.tracer().clear()
+    telemetry.enable()
+    try:
+        with kernels.use(config):
+            svc = _service()
+            svc.generate("lm", [1, 2, 3], max_new_tokens=4).result(60)
+            svc.shutdown()
+        recs = [r.args for r in telemetry.tracer().spans()
+                if r.name == "serving/decode/kv"]
+        assert len(recs) >= 3
+        for a in recs:
+            assert a["written_columns"] == 2    # one live slot, 2 layers
+            assert a["kernel_written_columns"] \
+                == share * a["written_columns"]
     finally:
         telemetry.disable()
         telemetry.tracer().clear()

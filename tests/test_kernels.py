@@ -458,6 +458,21 @@ def _decode_operands(slots, h, d, t, seed, dtype="float32"):
             jnp.asarray(r.standard_normal((slots, h, d, t)), dtype))
 
 
+def _attend_cache(q, k, v, lengths, **kwargs):
+    """The kernel over a cache that already holds every slot's newest
+    column: handed that column (``lengths - 1``) again as the step's
+    new token, it attends the columns ``< lengths`` and writes back
+    what is there. Returns the output alone."""
+    at = jnp.clip(lengths.astype(jnp.int32), 1,
+                  kwargs.get("attend_len", k.shape[3])) - 1
+    newest = lambda c: jnp.take_along_axis(  # noqa: E731
+        c, at[:, None, None, None], axis=3)[..., 0]
+    out, k2, v2 = ragged_decode_attention(q, k, v, lengths, at, newest(k),
+                                          newest(v), **kwargs)
+    assert k2.shape == k.shape and v2.dtype == v.dtype
+    return out
+
+
 def _masked_decode_reference(q, k, v, lengths):
     """The length-masked einsum over a ``[slots, H, D, T]`` cache."""
     f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
@@ -479,8 +494,7 @@ class TestRaggedDecode:
         q, k, v = _decode_operands(slots, h, d, t, seed=10)
         for n in range(1, t + 1):
             lengths = jnp.full((slots,), n, jnp.int32)
-            out = ragged_decode_attention(q, k, v, lengths,
-                                          interpret=True)
+            out = _attend_cache(q, k, v, lengths, interpret=True)
             ref = _masked_decode_reference(q, k, v, lengths)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        atol=1e-5, rtol=0,
@@ -493,7 +507,7 @@ class TestRaggedDecode:
         q, k, v = _decode_operands(slots, h, d, t, seed=11)
         lengths = jnp.asarray(np.array([1, 127, 128, 129, 300, 384],
                                        np.int32))
-        out = ragged_decode_attention(q, k, v, lengths, interpret=True)
+        out = _attend_cache(q, k, v, lengths, interpret=True)
         ref = _masked_decode_reference(q, k, v, lengths)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=0)
@@ -511,7 +525,7 @@ class TestRaggedDecode:
         slots, h, d = 3, 2, 16
         q, k, v = _decode_operands(slots, h, d, t, 13, dtype)
         lengths = jnp.asarray(np.array([1, max(1, t // 2), t], np.int32))
-        out = ragged_decode_attention(q, k, v, lengths, interpret=True)
+        out = _attend_cache(q, k, v, lengths, interpret=True)
         assert out.shape == (slots, h, d) and out.dtype == q.dtype
         ref = _masked_decode_reference(q, k, v, lengths)
         np.testing.assert_allclose(
@@ -530,16 +544,15 @@ class TestRaggedDecode:
         q, k, v = _decode_operands(slots, h, d, t, seed=14)
         lengths = jnp.asarray(np.array([1, attend_len // 2, attend_len],
                                        np.int32))
-        out = ragged_decode_attention(q, k, v, lengths,
-                                      attend_len=attend_len,
-                                      interpret=True)
+        out = _attend_cache(q, k, v, lengths, attend_len=attend_len,
+                            interpret=True)
         ref = _masked_decode_reference(q, k[..., :attend_len],
                                        v[..., :attend_len], lengths)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=0)
         # poison everything past the rung: the answer must not move
         poison = jnp.arange(t) >= attend_len
-        out_p = ragged_decode_attention(
+        out_p = _attend_cache(
             q, jnp.where(poison, jnp.nan, k), jnp.where(poison, 1e9, v),
             lengths, attend_len=attend_len, interpret=True)
         assert np.array_equal(np.asarray(out_p), np.asarray(out))
@@ -566,7 +579,7 @@ class TestRaggedDecode:
         lengths = np.asarray(lens, np.int32)
         past = np.arange(a)[None, None, None, :] \
             >= lengths[:, None, None, None]
-        out = ragged_decode_attention(
+        out = _attend_cache(
             q, jnp.asarray(np.where(past, np.nan, k), dtype),
             jnp.asarray(np.where(past, np.nan, v), dtype),
             jnp.asarray(lengths), interpret=True)
@@ -613,17 +626,102 @@ class TestRaggedDecode:
     def test_dispatch_shapes_and_toggle(self):
         q, kv, _ = _decode_operands(2, 2, 8, 16, seed=12)
         lengths = jnp.asarray(np.array([3, 9], np.int32))
+        step = dict(new_k=kv[..., 0], new_v=kv[..., 1],
+                    write_at=lengths - 1)
         with kernels.use(OFF):
-            assert kernels.decode_attention(q, kv, kv, lengths) is None
+            assert kernels.decode_attention(q, kv, kv, lengths,
+                                            **step) is None
         with kernels.use(ON):
-            out = kernels.decode_attention(q, kv, kv, lengths)
-            assert out is not None and out.shape == (2, 2, 8)
+            wrote = kernels.dispatch.taken_in_thread("decode_write")
+            out, k, v = kernels.decode_attention(q, kv, kv, lengths,
+                                                 **step)
+            assert out.shape == (2, 2, 8)
+            assert k.shape == v.shape == kv.shape
+            assert kernels.dispatch.taken_in_thread(
+                "decode_write") == wrote + 1
             # a [B,H,S,D] query is the training shape, not decode's
-            assert kernels.decode_attention(kv, kv, kv, lengths) is None
+            assert kernels.decode_attention(kv, kv, kv, lengths,
+                                            **step) is None
             # nor is a [slots,H,T,D] cache: time is the last axis
             assert kernels.decode_attention(
                 q, jnp.swapaxes(kv, 2, 3), jnp.swapaxes(kv, 2, 3),
-                lengths) is None
+                lengths, **step) is None
+            # new columns of another dtype than the cache's: declined,
+            # as _write_columns would refuse them
+            assert kernels.decode_attention(
+                q, kv, kv, lengths, new_k=step["new_k"].astype("bfloat16"),
+                new_v=step["new_v"], write_at=lengths - 1) is None
+            assert kernels.dispatch.taken_in_thread(
+                "decode_write") == wrote + 1
+
+    @pytest.mark.parametrize(
+        "hkv, g, d, t, dtype, window, attend_len, offsets", [
+            # MHA f32 D 64 over 1024 columns: the GPT-2 serve cell's row
+            (2, 1, 64, 1024, "float32", None, None, [0, 550, 700, 1023]),
+            # grouped bf16 G 6 D 128 over 4096: Trinity's, two tiles
+            (1, 6, 128, 4096, "bfloat16", None, None,
+             [5, 2047, 2048, 4095]),
+            # a ring that has not wrapped: write_at == lengths - 1
+            (2, 2, 16, 256, "float32", 256, None, [0, 100, 127, 255]),
+            # a ring that has: the oldest token is overwritten and not
+            # attended, write_at != lengths - 1
+            (2, 2, 16, 256, "float32", 256, None, [256, 300, 511, 1000]),
+            # a rung below T, the write in the last of four tiles
+            (1, 6, 128, 12288, "bfloat16", None, 8192,
+             [6144, 7000, 8191, 100]),
+            # lengths == 1: one tile walked, its every column masked
+            (2, 1, 64, 1024, "float32", None, None, [0, 0, 0, 0]),
+            # a free slot's offset out of range: clamped to the last
+            # column, as XLA clamps _write_columns' start
+            (2, 2, 16, 256, "float32", None, None, [9, 5000, 40, 255]),
+            # T shorter than a lane tile, and not a whole number of them
+            (2, 2, 16, 100, "float32", None, None, [0, 50, 98, 99]),
+            (2, 2, 16, 200, "bfloat16", None, None, [0, 127, 128, 199]),
+        ], ids=["mha_f32_1024", "gqa_bf16_4096", "ring_not_wrapped",
+                "ring_wrapped", "rung_below_T_last_tile", "lengths_1",
+                "free_slot_out_of_range", "T_under_a_lane_tile",
+                "T_no_whole_lane_tiles"])
+    def test_writes_the_new_column_as_write_columns_does(
+            self, hkv, g, d, t, dtype, window, attend_len, offsets):
+        """The kernel, handed the cache as it stands and the step's new
+        K/V column, against ``_write_columns`` followed by the
+        length-masked ``_attend`` (what a declined dispatch runs): the
+        returned K and V bit-equal in EVERY element - the written
+        column and all the untouched ones, other slots' rows, columns
+        past the rung - and the output inside the decode equivalence
+        tests' tolerance."""
+        from bigdl_tpu.nn.attention import _attend, _write_columns
+
+        slots = len(offsets)
+        r = np.random.default_rng(t + d + g)
+        arr = lambda *shape: jnp.asarray(  # noqa: E731
+            r.standard_normal(shape), dtype)
+        q, k_t, v_t = (arr(slots, hkv * g, 1, d), arr(slots, hkv, d, 1),
+                       arr(slots, hkv, d, 1))
+        cache = {"k": arr(slots, hkv, d, t), "v": arr(slots, hkv, d, t)}
+        offsets = jnp.asarray(offsets, jnp.int32)
+        c = t if attend_len is None else attend_len
+        want = _write_columns(cache, k_t, v_t, offsets, offsets[:, None],
+                              None, window)
+        lengths = jnp.clip(offsets + 1, 1, c if window is None else window)
+        at = offsets if window is None else offsets % window
+        mask = jnp.arange(c)[None, None, :] < lengths[:, None, None]
+        ref = _attend(q, [(want["k"][..., :c], want["v"][..., :c],
+                           mask[:, None, None])])[:, :, 0]
+        out, k, v = ragged_decode_attention(
+            q[:, :, 0], cache["k"], cache["v"], lengths, at, k_t[..., 0],
+            v_t[..., 0], attend_len=c, interpret=True)
+        bits = lambda a: np.asarray(a).view(  # noqa: E731
+            {2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+        assert np.array_equal(bits(k), bits(want["k"]))
+        assert np.array_equal(bits(v), bits(want["v"]))
+        assert out.dtype == q.dtype
+        live = np.asarray(offsets) < t          # a free slot's output
+        np.testing.assert_allclose(             # is never consumed
+            np.asarray(out.astype(jnp.float32))[live],
+            np.asarray(ref.astype(jnp.float32))[live], rtol=0,
+            atol=1e-5 if dtype == "float32" else 2e-2)
+        assert np.isfinite(np.asarray(out.astype(jnp.float32))).all()
 
 
 # ----------------------------------------------------------- int8 GEMM

@@ -361,8 +361,9 @@ def _kernel_cases():
 
     return {
         "ragged_decode": (
-            lambda q, k, v: ragged_decode_attention(q, k, v, lengths,
-                                                    interpret=True),
+            lambda q, k, v: ragged_decode_attention(
+                q, k, v, lengths, lengths - 1, k[..., 0], v[..., 0],
+                interpret=True),
             (jnp.zeros((2, 2, 8)), cache, cache),
             {"bigdl_ragged_decode"}),
         "int8_gemm": (
