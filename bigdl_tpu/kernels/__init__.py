@@ -6,8 +6,9 @@ MKL + the BigQuant int8 GEMM, PAPER.md L0); the TPU-native analogue is
 ONE gate in front of them:
 
 - :mod:`~bigdl_tpu.kernels.flash_attention` — fused flash attention
-  for training: q-tiled, segment-mask aware (packed datapipe slabs run
-  bit-faithfully), custom-VJP backward, no materialized [S, S];
+  for training: q-tiled, causal chunks skipped, segment-mask aware
+  (packed datapipe slabs run bit-faithfully), custom-VJP backward, no
+  materialized [S, S];
 - :mod:`~bigdl_tpu.kernels.ragged_decode` — ragged decode
   attention for the generation engine: reads only ``lengths[i]`` valid
   KV per slot instead of the bucket max, and writes the step's new K/V
@@ -22,9 +23,9 @@ ONE gate in front of them:
   :func:`grouped_matmul`: config + shape
   eligibility in, kernel result or None (= run your jnp path) out;
 - :mod:`~bigdl_tpu.kernels.config` — :class:`KernelConfig` and the
-  ``BIGDL_KERNELS`` env toggle; decode + int8 + gmm default ON on real TPU
-  (flash stays opt-in until the bench KERNELS trajectory justifies
-  it), everything OFF on CPU, and kernels run under the pallas
+  ``BIGDL_KERNELS`` env toggle; every kernel default ON on real TPU
+  (flash since PR 35: which shapes take it is the dispatch's measured
+  rule), everything OFF on CPU, and kernels run under the pallas
   *interpreter* everywhere but real TPU so tier-1 on CPU executes the
   real kernel bodies.
 

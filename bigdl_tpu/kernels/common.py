@@ -3,7 +3,14 @@ params) — one home so a tiling policy change is fixed in exactly one
 place."""
 from __future__ import annotations
 
-__all__ = ["fit_block", "sublanes", "tpu_compiler_params"]
+__all__ = ["FLASH_VMEM_LIMIT_MB", "fit_block", "sublanes",
+           "tpu_compiler_params"]
+
+#: scoped VMEM (MiB) the full-row flash kernels ask the compiler for a
+#: program, of a v5e's 128 (its default grants 16). The ONE number
+#: for that limit: the dispatch's default working-set budget is this
+#: less a margin (``KernelConfig.resolve_vmem_budget``)
+FLASH_VMEM_LIMIT_MB = 32
 
 
 def sublanes(dtype) -> int:
@@ -30,10 +37,13 @@ def fit_block(dim: int, preferred: int, align: int = 1) -> int:
     return b if b > 0 else dim
 
 
-def tpu_compiler_params(dimension_semantics):
+def tpu_compiler_params(dimension_semantics, vmem_limit_bytes=None):
     """TPU compiler params for a kernel grid: the accumulator-carrying
-    axis is "arbitrary" (sequential), everything else parallel."""
+    axis is "arbitrary" (sequential), everything else parallel.
+    ``vmem_limit_bytes`` raises the scoped VMEM one program may take
+    over the compiler's default (16 MiB on a v5e, of 128)."""
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        dimension_semantics=tuple(dimension_semantics))
+        dimension_semantics=tuple(dimension_semantics),
+        vmem_limit_bytes=vmem_limit_bytes)

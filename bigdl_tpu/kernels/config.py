@@ -5,11 +5,13 @@ SURVEY.md §1); ours is a *runtime policy*: a :class:`KernelConfig`
 names which pallas kernels the dispatch layer
 (:mod:`bigdl_tpu.kernels.dispatch`) may select, everything else runs
 the pure-jnp reference path. The default is resolved lazily from the
-backend — **decode + int8 + gmm on on real TPU** (they skip work the
-reference cannot skip), **flash opt-in even there** (XLA's fused einsum
-stays the default at the lengths it can hold until a measurement on
-today's code says otherwise — ROADMAP A5), **everything off on CPU** —
-and the ``BIGDL_KERNELS`` env var overrides it without touching code:
+backend — **every kernel on on a real TPU** (decode, int8 and gmm skip
+work the reference cannot skip; flash since PR 35, whose measured table
+is in PERF.md section 6: a layer of ``[4,16,1024,64]`` attention,
+forward + backward, takes 0.68 ms fused against 2.89 ms as einsums, and
+the dispatch declines the short lengths where the einsum form won),
+**everything off on CPU** — and the ``BIGDL_KERNELS`` env var overrides
+it without touching code:
 
 - ``BIGDL_KERNELS=1`` / ``on`` / ``all`` — every kernel on;
 - ``BIGDL_KERNELS=0`` / ``off`` — every kernel off;
@@ -57,11 +59,10 @@ class KernelConfig:
     ``lengths[i]`` valid KV per slot); ``int8_matmul`` — the fused
     dequant-int8-GEMM serving kernel; ``grouped_matmul`` — the routed
     expert layer's grouped product. ``interpret=None`` auto-selects
-    the pallas interpreter off-TPU; ``block_q``/``block_k`` are the
-    FLASH kernels' preferred tile sizes (shrunk to the largest divisor
-    of the actual dimension, so ragged test shapes stay eligible) and
-    nothing else reads them: the ragged decode kernel sizes its K/V
-    tile itself from the shapes it is handed
+    the pallas interpreter off-TPU. No field holds a tile size: the
+    flash dispatch derives chunk and tile from the shape
+    (:func:`bigdl_tpu.kernels.dispatch.flash_route`), the ragged decode
+    kernel its K/V tile from the shapes it is handed
     (:func:`bigdl_tpu.kernels.ragged_decode.kv_tile`)."""
 
     flash_attention: bool = False
@@ -69,25 +70,25 @@ class KernelConfig:
     int8_matmul: bool = False
     grouped_matmul: bool = False
     interpret: Optional[bool] = None
-    block_q: int = 128
-    block_k: int = 128
-    #: compiled-mode VMEM working-set budget (MiB) for one flash
-    #: program; ``None`` reads ``BIGDL_VMEM_BUDGET_MB`` and falls back
-    #: to the 12 MiB default — inside the 16 MiB of scoped VMEM the
-    #: v5e compiler grants one program (tests/test_chip_compile.py;
-    #: dispatch module docstring has the budget math)
+    #: compiled-mode VMEM working-set budget (MiB) for one full-row
+    #: flash program; ``None`` reads ``BIGDL_VMEM_BUDGET_MB`` and falls
+    #: back to the default: the scoped VMEM those kernels ask the
+    #: compiler for (``common.FLASH_VMEM_LIMIT_MB``) less 4 MiB, which
+    #: is inside what it takes (tests/test_chip_compile.py;
+    #: ``dispatch._flash_vmem_bytes`` has the budget math). A budget
+    #: over the limit only moves where the compiler refuses
     vmem_budget_mb: Optional[int] = None
-    #: whether shapes past the VMEM budget route to the blockwise
-    #: long-context flash kernel (key dimension tiled through VMEM)
-    #: instead of declining to the einsum reference
+    #: whether shapes past the VMEM budget, which the full-row kernel
+    #: cannot hold, route to the blockwise long-context flash kernel
+    #: (key dimension tiled through VMEM) instead of declining to the
+    #: einsum reference
     long_context: bool = True
 
     @classmethod
     def all_on(cls, **kw) -> "KernelConfig":
-        """Every kernel enabled — ``BIGDL_KERNELS=1`` and the test/
-        bench on-legs. (The real-TPU *default* is decode + int8 + gmm;
-        flash stays opt-in there until a measurement justifies the
-        flip — see the module docstring.)"""
+        """Every kernel enabled — ``BIGDL_KERNELS=1``, the test/bench
+        on-legs, and since PR 35 the real-TPU default (module
+        docstring)."""
         return cls(flash_attention=True, decode_attention=True,
                    int8_matmul=True, grouped_matmul=True, **kw)
 
@@ -136,7 +137,7 @@ class KernelConfig:
     def resolve_vmem_budget(self) -> int:
         """The effective flash VMEM budget in BYTES: an explicit
         ``vmem_budget_mb`` wins, else ``BIGDL_VMEM_BUDGET_MB``, else
-        the 12 MiB default the PR 11 kernel shipped with."""
+        the kernels' own limit less 4 MiB."""
         mb = self.vmem_budget_mb
         if mb is None:
             env = os.environ.get("BIGDL_VMEM_BUDGET_MB")
@@ -148,7 +149,8 @@ class KernelConfig:
                         f"BIGDL_VMEM_BUDGET_MB={env!r} is not an "
                         f"integer MiB count") from None
         if mb is None:
-            mb = 12
+            from bigdl_tpu.kernels.common import FLASH_VMEM_LIMIT_MB
+            mb = FLASH_VMEM_LIMIT_MB - 4
         if mb <= 0:
             raise ValueError(
                 f"flash VMEM budget must be positive, got {mb} MiB")
@@ -166,12 +168,10 @@ def _default() -> KernelConfig:
     if env is not None:
         cfg = KernelConfig.from_env(env)
     elif backend == "tpu":
-        # decode + int8 replace work the einsum path cannot skip;
-        # flash stays OPT-IN on TPU (XLA's fused einsum is the default
-        # at every length it can hold) — promote it via
-        # BIGDL_KERNELS=1/flash once a measurement justifies the flip
-        cfg = KernelConfig(decode_attention=True, int8_matmul=True,
-                           grouped_matmul=True)
+        # every kernel: which shapes each takes is the dispatch's to
+        # decide from what it measured (flash_route declines the short
+        # sequences where the einsum form is faster)
+        cfg = KernelConfig.all_on()
     else:
         cfg = KernelConfig.off()
     if cfg.any_enabled:

@@ -17,7 +17,7 @@ routing, not per-step calls: ``kernels/dispatch/pallas`` (label
 ``op=flash|decode|decode_write|int8|gmm``; ``decode_write`` is a second
 label of the decode kernel, for the cache column it writes) vs
 ``kernels/dispatch/reference`` (labels ``op=...`` plus
-``reason=config|shape|vmem`` so a `diagnose` dump attributes every
+``reason=config|shape|vmem|mesh`` so a `diagnose` dump attributes every
 decline).
 """
 from __future__ import annotations
@@ -25,14 +25,16 @@ from __future__ import annotations
 import threading
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu.kernels import config as _config
-from bigdl_tpu.kernels.common import fit_block, sublanes
+from bigdl_tpu.kernels.common import fit_block
 
 __all__ = ["attention", "decode_attention", "int8_matmul",
-           "grouped_matmul", "taken_in_thread"]
+           "grouped_matmul", "flash_route", "taken_in_thread",
+           "declined_in_thread"]
 
 # module-level registration so `tools.check --telemetry-audit` sees the
 # REAL instruments on import, not a hand-maintained name list
@@ -43,7 +45,7 @@ _C_PALLAS = telemetry.counter(
 _C_REFERENCE = telemetry.counter(
     "kernels/dispatch/reference",
     "traces declined by the dispatch layer to the pure-jnp reference "
-    "(labels op=flash|decode|int8, reason=config|shape|vmem)")
+    "(labels op=flash|decode|int8, reason=config|shape|vmem|mesh)")
 
 
 # trace-scoped routing evidence: tracing happens on the caller's
@@ -65,12 +67,24 @@ def taken_in_thread(op: Optional[str] = None) -> int:
     return getattr(_TRACE, "by_op", {}).get(op, 0)
 
 
+def declined_in_thread(op: str) -> int:
+    """:func:`taken_in_thread`'s twin: dispatches of ``op`` declined on
+    this thread, whatever the reason. Taken over taken + declined
+    across a trace is the share of a program's calls that run as the
+    kernel (``train/optimizer/attn_in_kernel_share``)."""
+    return getattr(_TRACE, "declined", {}).get(op, 0)
+
+
 def _declined(op: str, reason: str) -> None:
     # reason= makes declines attributable in `diagnose`: "config" (the
     # active KernelConfig disabled the op), "shape" (ineligible dtype/
-    # rank/alignment), "vmem" (over the flash working-set budget with
-    # the blockwise long-context path switched off)
+    # rank/alignment, or a length at which the plain form measured
+    # faster), "vmem" (over the flash working-set budget with no
+    # kernel left to take it), "mesh" (the partitioner may split the
+    # program over devices, and it cannot split a Mosaic kernel)
     _C_REFERENCE.inc(op=op, reason=reason)
+    by_op = _TRACE.__dict__.setdefault("declined", {})
+    by_op[op] = by_op.get(op, 0) + 1
 
 
 def _taken(*ops: str) -> None:
@@ -86,33 +100,129 @@ def _floating(*arrays) -> bool:
     return all(jnp.issubdtype(a.dtype, jnp.floating) for a in arrays)
 
 
-def _flash_vmem_bytes(q, block_q: int) -> int:
-    """Upper-bound VMEM working set of ONE flash grid program — the
-    BACKWARD kernel's, which dominates: f32 casts of the full K and V
-    blocks, the two [S, D] f32 dK/dV scratch accumulators, and four
-    f32 [block_q, S] strips (scores, p, dp, ds). The forward (K+V at
-    input dtype + three strips) is strictly smaller, so budgeting on
-    the backward keeps jax.grad from OOMing at shapes the forward
-    alone would have accepted."""
-    s, d = q.shape[-2], q.shape[-1]
-    bq = fit_block(s, block_q, align=sublanes(q.dtype))
-    kv_inputs = 2 * s * d * q.dtype.itemsize
-    kv_f32 = 2 * s * d * 4        # in-kernel f32 casts of K and V
-    scratch = 2 * s * d * 4       # dK/dV accumulators
-    strips = 4 * bq * s * 4       # scores / p / dp / ds
-    tiles = 4 * bq * d * 4        # q, o, do, dq tiles
-    return kv_inputs + kv_f32 + scratch + strips + tiles
+# The flash selection rests on one table: one layer's causal attention
+# alone on a v5e, forward + backward, ms by device time (PERF.md
+# section 6, PR 35, calls t1 and t3; bf16 unless said):
+#
+#   [B,H,S,D]        einsum  full-row chunk 128/256/512  blockwise 256/512/1024
+#   [4,16, 256,64]    0.035   0.216 / 0.118 /   -          0.134 /   -   /  -
+#   [4,16, 512,64]    0.501   0.636 / 0.327 / 0.217        0.501 / 0.285 /  -
+#   [4,16, 640,64]    0.935   0.941 /   -   /   -            -
+#   [4,16, 896,64]    1.706   1.751 /   -   /   -            -
+#   [4,16,1024,64]    2.891   2.173 / 1.017 / 0.678        1.768 / 1.075 / 0.999
+#   [4,16,1152,64]    3.503   chunk 384: 0.965               -
+#   [4,16,1280,64]    4.352     -   / 1.514 /   -            -
+#   [8,12,1024,64]    4.265   3.324 / 1.585 / 1.066        2.667 / 1.627 / 1.502
+#   [4,16,1024,64]f32 3.957   2.352 / 1.232 / 0.887        2.131 / 1.375 / 1.091
+#   [2,16,2048,64]    5.490   3.817 / 1.636 / 1.004        2.985 / 1.690 / 1.716
+#   [1,16,4096,64]   15.602   7.102 / 2.868 / 1.656        5.249 / 2.821 / 2.740
+#   [1,16,8192,64]      -       -   /   -   / 6.038          -   /10.469 /  -
+#   [1,8,10752,64]      -       -   /   -   / 5.046          -   / 8.562 /  -
+#   [1,8,16384,64]      -     (past the kernel's VMEM)       -   /19.730 /  -
+#
+# so: under 512 keys the einsum form wins (3.4x at 256) and the
+# dispatch declines; from 512 on the full-row kernel wins at every
+# length it can hold (1.7x over blockwise at 8192 and 10752, the
+# budget's edge), at the largest chunk that divides the length; past
+# its VMEM the blockwise kernel takes over at 512 tiles (1024 reads
+# the same and holds four times the scores). A side of 128 is on
+# neither path: in the full-row form it reads what the einsums read
+# at 640 and 896 (forward alone 2.7x and 1.3x theirs), in the
+# blockwise form it was not measured. So a length whose largest
+# lane-aligned divisor up to 512 is 128 (640, 896, 1408) declines,
+# like one that is no multiple of 128 at all (600, 900), whose blocks
+# no compile for a v5e covers.
+
+#: side of the full-row kernels' square score chunk and of the
+#: blockwise kernels' tile, where the length divides by it
+_FLASH_BLOCK = 512
+#: the smallest side the table supports
+_FLASH_MIN_BLOCK = 256
+#: compiled, a shorter sequence stays with the einsum form
+_FLASH_MIN_SEQ = 512
+
+
+def _flash_vmem_bytes(s: int, d: int, itemsize: int, chunk: int) -> int:
+    """Estimated VMEM working set of ONE full-row grid program, the
+    larger of the two kernels'. Backward: K and V whole and the dK / dV
+    output blocks, each double-buffered at the input dtype, the two
+    ``[S, D]`` float32 dK / dV accumulators, three float32 ``[chunk,
+    chunk]`` score temporaries and the q / do / dq tiles. Forward: K
+    and V, the ``[S, chunk]`` float32 score strip (what binds from
+    S = 2048 on), one chunk of scores and the tiles. Budgeting on the
+    larger keeps jax.grad from refusing what the forward alone would
+    have taken. The estimate errs high: compiled for a v5e at the
+    kernels' ``common.FLASH_VMEM_LIMIT_MB``, the first length the
+    compiler refuses has an estimate of 37 MiB or more at every head
+    size, dtype and chunk probed (PERF.md section 6, PR 35), and the
+    default budget is that limit less 4 MiB
+    (``KernelConfig.vmem_budget_mb``)."""
+    kv = 2 * 2 * s * d * itemsize
+    backward = (2 * kv + 2 * s * d * 4 + 3 * chunk * chunk * 4
+                + 6 * chunk * d * 4)
+    forward = kv + s * chunk * 4 + chunk * chunk * 4 + 4 * chunk * d * 4
+    return max(backward, forward)
+
+
+def flash_route(shape, itemsize: int, *, segmented: bool, interpret: bool,
+                vmem_budget: int, long_context: bool = True):
+    """Which form ``[B, H, S, D]`` attention takes, from what the
+    dispatch can see: ``("full", chunk)``, ``("blockwise", tile)`` or
+    ``("declined", reason)``. The rule and the measurements it rests
+    on are in the comment above; chunk and tile follow from the shape.
+    Packed slabs (``segmented``) keep the full-row kernel's
+    bit-exact-per-token contract, so they take the full-row form or
+    decline — never the blockwise one, whose rescaling rounds by where
+    the tile boundaries fall. Under the interpreter nothing is timed
+    and nothing is laid out in lanes, so no length is too short or
+    too odd: tier-1 runs the kernel bodies at test sizes."""
+    s, d = int(shape[-2]), int(shape[-1])
+    if interpret:
+        side = fit_block(s, _FLASH_BLOCK)
+    else:
+        if s < _FLASH_MIN_SEQ or s % 128:
+            return "declined", "shape"
+        side = fit_block(s, _FLASH_BLOCK, align=128)
+        if side < _FLASH_MIN_BLOCK:
+            return "declined", "shape"
+    if _flash_vmem_bytes(s, d, itemsize, side) <= vmem_budget:
+        return "full", side
+    # past the budget the full-row kernel would OOM Mosaic (an error,
+    # not a fallback): the blockwise kernel tiles the key axis through
+    # VMEM too — unless it is switched off or the slab is packed; then
+    # decline so nn.attention's einsum / bundled-flash routes keep the
+    # escape hatch
+    if segmented or not long_context:
+        return "declined", "vmem"
+    return "blockwise", side
+
+
+def _partitioned() -> bool:
+    """Whether the partitioner may split the program being traced over
+    devices. It cannot split a Mosaic kernel: jax refuses to lower one
+    ("Mosaic kernels cannot be automatically partitioned") unless the
+    program runs on one device or every axis of its mesh is manual.
+    Inside a ``shard_map`` the trace says which axes are manual. A
+    plain ``jax.jit`` learns its devices from its arguments, after the
+    trace: there only a process that sees one device is sure of one
+    (``DistriOptimizer`` on a data mesh runs its step as such a jit)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
+        return set(mesh.manual_axes) != set(mesh.axis_names)
+    return jax.device_count() > 1
 
 
 def attention(q, k, v, *, causal: bool = False, segment_ids=None,
               sm_scale: Optional[float] = None):
-    """Flash-attention dispatch for ``[B, H, S, D]`` q/k/v: the tiled
+    """Flash-attention dispatch for ``[B, H, S, D]`` q/k/v: a fused
     pallas kernel (:mod:`bigdl_tpu.kernels.flash_attention`, segment-
     mask aware, differentiable) when the active config enables
-    ``flash`` and the shapes qualify — else **None**, telling the
-    caller to run its jnp path (``nn.attention.dot_product_attention``
-    falls through to the einsum form, which itself still routes
-    HBM-busting lengths to jax's bundled flash kernel)."""
+    ``flash``, :func:`flash_route` finds a form for the shape and,
+    compiled, the program is one device's (:func:`_partitioned`) —
+    else **None**, telling the caller to run its jnp path
+    (``nn.attention.dot_product_attention`` falls through to the
+    einsum form, which itself still routes HBM-busting lengths to
+    jax's bundled flash kernel)."""
     if not _config.enabled("flash"):
         _declined("flash", "config")
         return None
@@ -122,33 +232,26 @@ def attention(q, k, v, *, causal: bool = False, segment_ids=None,
         return None
     cfg = _config.get_config()
     interpret = cfg.resolve_interpret()
-    if _flash_vmem_bytes(q, cfg.block_q) > cfg.resolve_vmem_budget():
-        # past the working-set budget the full-K-row kernel would OOM
-        # Mosaic (an error, not a fallback): route to the blockwise
-        # long-context kernel — key axis tiled through VMEM with
-        # online-softmax rescaling — unless it is switched off, in
-        # which case decline so nn.attention's einsum/bundled-flash
-        # routes keep the escape hatch. The budget gate applies in
-        # interpret mode too, so CPU tier-1 exercises the same routing
-        # a TPU would take (shrink vmem_budget_mb to steer small test
-        # shapes down the blockwise path).
-        if not cfg.long_context:
-            _declined("flash", "vmem")
-            return None
-        from bigdl_tpu.kernels.flash_attention import (
-            blockwise_flash_attention)
-
-        _taken("flash")
-        return blockwise_flash_attention(
-            q, k, v, segment_ids, causal=causal, sm_scale=sm_scale,
-            block_q=cfg.block_q, block_k=cfg.block_k,
-            interpret=interpret)
-    from bigdl_tpu.kernels.flash_attention import flash_attention
+    form, how = flash_route(
+        q.shape, q.dtype.itemsize, segmented=segment_ids is not None,
+        interpret=interpret, vmem_budget=cfg.resolve_vmem_budget(),
+        long_context=cfg.long_context)
+    if form == "declined":
+        _declined("flash", how)
+        return None
+    if not interpret and _partitioned():
+        _declined("flash", "mesh")
+        return None
+    from bigdl_tpu.kernels import flash_attention as _flash
 
     _taken("flash")
-    return flash_attention(q, k, v, segment_ids, causal=causal,
-                           sm_scale=sm_scale, block_q=cfg.block_q,
-                           interpret=interpret)
+    if form == "blockwise":
+        return _flash.blockwise_flash_attention(
+            q, k, v, segment_ids, causal=causal, sm_scale=sm_scale,
+            block_q=how, block_k=how, interpret=interpret)
+    return _flash.flash_attention(q, k, v, segment_ids, causal=causal,
+                                  sm_scale=sm_scale, block_q=how,
+                                  interpret=interpret)
 
 
 def decode_attention(q, k, v, lengths, *, new_k, new_v, write_at,

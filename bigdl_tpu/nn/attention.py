@@ -42,11 +42,14 @@ def _flash_attention_tpu(q, k, v, causal: bool):
                            block_sizes=sizes)
 
 
-# Route to the bundled flash kernel when the materialized [S,S] score
-# matrix would not comfortably fit HBM: XLA's fused einsum is the
-# default at every length it can hold, so the kernel is a MEMORY escape
-# hatch and the router keys on bytes. (Which of the two is faster at
-# which length on today's code is not measured — ROADMAP A5.)
+# What is left for the einsum form after the kernel dispatch declined
+# (short sequences, free-form masks, dropout, kernels off) still goes
+# to jax's bundled flash kernel when the materialized [S,S] score
+# matrix would not comfortably fit HBM: a MEMORY escape hatch, keyed on
+# bytes. Speed is the dispatch's business: on a v5e the fused kernel
+# reads 0.68 ms a layer of [4,16,1024,64] against the einsum form's
+# 2.89, and the einsum form wins under 512 keys (PERF.md section 6,
+# PR 35: the measured table).
 _FLASH_SCORE_BYTES = 2 << 30
 
 
@@ -68,9 +71,12 @@ def dot_product_attention(q, k, v, *, causal: bool = False, mask=None,
     """Scaled dot-product attention. q,k,v: [B, H, S, D].
 
     The first router is the kernel dispatch layer
-    (``bigdl_tpu.kernels``): with the flash kernel enabled
-    (``KernelConfig``/``BIGDL_KERNELS``) eligible shapes run the
-    fused pallas flash attention. Masking is EITHER ``mask`` (an
+    (``bigdl_tpu.kernels``): with the flash kernel enabled (the
+    default on a TPU; ``KernelConfig``/``BIGDL_KERNELS``) the shapes
+    its measured rule takes run the fused pallas flash attention, in
+    a program of one device (one the partitioner splits over a mesh
+    keeps the einsum form: docs/kernels.md). Masking is EITHER
+    ``mask`` (an
     arbitrary boolean ``[B, 1, S, S]`` — never kernel-eligible, the
     kernel cannot honor a free-form mask) OR ``segments`` (the packed
     datapipe slab's ``[B, S]`` segment-id plane — the same-segment

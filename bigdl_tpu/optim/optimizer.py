@@ -56,6 +56,11 @@ _WINDOW_GAP = telemetry.histogram(
     "train/optimizer/window_gap_ms",
     "host ms from one fused window's block_until_ready returning to the "
     "next window's dispatch returning, within one optimize() call")
+_ATTN_IN_KERNEL = telemetry.histogram(
+    "train/optimizer/attn_in_kernel_share",
+    "of the attention calls in one traced window program, the share "
+    "the flash dispatch took as a fused kernel (taken over taken + "
+    "declined); one observation a trace that asked the dispatch")
 # mixed-precision observability (Optimizer.set_precision): the loss
 # scale and cumulative skipped steps are read off the (already-fetched)
 # scaler state once per host sync; the policy/bytes gauges are set once
@@ -493,6 +498,13 @@ def build_eval_step(module: Module, out_sharding=None, precision=None):
         train_program_name(module, "eval"), "train",
         jax.jit(eval_step, out_shardings=out_sharding),
         items_for=lambda args, kwargs: _batch_rows(args[2]))
+
+
+def _flash_dispatches():
+    """(taken, declined) flash dispatches on this thread so far."""
+    from bigdl_tpu.kernels import dispatch
+    return (dispatch.taken_in_thread("flash"),
+            dispatch.declined_in_thread("flash"))
 
 
 def make_host_window(step):
@@ -1942,6 +1954,7 @@ class Optimizer:
                 t1 = time.time()
                 # the launch as a live span: what follows it up to the
                 # end of optimizer/compute is the wait on the device
+                asked = _flash_dispatches()
                 with telemetry.span("optimizer/window/dispatch",
                                     step=state["neval"], steps=k_now):
                     if rotating or device_feed:
@@ -1952,6 +1965,12 @@ class Optimizer:
                         params, opt_state, model_state, losses = \
                             host_window_fn(params, opt_state, model_state,
                                            keys, lrs, inp, tgt)
+                # a dispatch that traced its program asked the flash
+                # dispatch once an attention call, on this thread
+                took, declined = (now - before for now, before
+                                  in zip(_flash_dispatches(), asked))
+                if took + declined:
+                    _ATTN_IN_KERNEL.observe(took / (took + declined))
                 if window_synced is not None:
                     _WINDOW_GAP.observe(
                         (time.monotonic() - window_synced) * 1e3)

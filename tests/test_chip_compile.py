@@ -69,6 +69,15 @@ def four_chips(topo, no_persistent_cache):
     return Mesh(np.array(topo.devices).reshape(4), ("data",))
 
 
+@pytest.fixture
+def one_chip_host(monkeypatch):
+    """A one-chip machine's process sees one device, and there the
+    flash dispatch takes its kernel under a plain ``jit``
+    (``dispatch._partitioned``); this one sees tier-1's eight CPU
+    devices, so say one, as the tests say ``interpret=False``."""
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+
+
 def _on(sharding, *shape_dtypes):
     return [jax.ShapeDtypeStruct(s, np.dtype(d), sharding=sharding)
             for s, d in shape_dtypes]
@@ -172,9 +181,11 @@ def test_blockwise_flash_compiles_fwd_and_grad(one_chip, shape, dtype):
     assert _has_kernel(_compile(_grad_of(attn), *args))
 
 
-def test_full_row_flash_is_refused_past_vmem_and_dispatch_knows(one_chip):
-    """At ``[1, 8, 8192, 128]`` the full-row kernel asks for more
-    scoped VMEM than a v5e program may have — the compiler says so —
+def test_full_row_flash_is_refused_past_vmem_and_dispatch_knows(
+        one_chip, one_chip_host):
+    """At ``[1, 8, 8192, 128]`` float32 the full-row kernel, at the
+    chunk the dispatch would give it, asks for more VMEM than the
+    32 MiB it may have — the compiler says so —
     and the dispatch layer's estimate routes that shape to the
     blockwise kernel instead, which compiles, forward and grad."""
     from bigdl_tpu import kernels
@@ -185,8 +196,8 @@ def test_full_row_flash_is_refused_past_vmem_and_dispatch_knows(one_chip):
     args = _on(one_chip, (shape, "float32"), (shape, "float32"),
                (shape, "float32"))
     with pytest.raises(Exception, match="(?i)vmem"):
-        _compile(lambda q, k, v: flash_attention(q, k, v, causal=True),
-                 *args)
+        _compile(_grad_of(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=512)), *args)
 
     def attn(q, k, v):
         out = kernels.attention(q, k, v, causal=True)
@@ -200,23 +211,107 @@ def test_full_row_flash_is_refused_past_vmem_and_dispatch_knows(one_chip):
 
 
 def test_flash_vmem_budget_is_inside_what_the_compiler_takes(one_chip):
-    """The largest shapes the 12 MiB estimate still hands to the
-    full-row kernel compile (backward included): the estimate errs on
-    the safe side of the compiler's 16 MiB scoped limit."""
-    from bigdl_tpu.kernels import dispatch
+    """The largest shapes the default estimate (the 32 MiB the kernels
+    ask the compiler for, less 4) still hands to the full-row kernel
+    compile, backward included, at the chunk the dispatch gives them -
+    512, the 256 and 384 of lengths no 512 divides, with and without a
+    segment plane. The estimate errs high: the first length the
+    compiler refuses is priced at 37 MiB or more (PERF.md section 6,
+    PR 35). From S = 2048 on it is the forward's ``[S, chunk]`` score
+    strip that binds."""
+    from bigdl_tpu.kernels import KernelConfig, dispatch
     from bigdl_tpu.kernels.flash_attention import flash_attention
 
-    def attn(q, k, v):
-        return flash_attention(q, k, v, causal=True)
+    budget = KernelConfig().resolve_vmem_budget()
+    assert budget == 28 << 20
+    for shape, dtype, chunk, segmented in (
+            ((1, 2, 10752, 64), "bfloat16", 512, False),
+            ((1, 2, 8704, 64), "float32", 512, True),
+            ((1, 2, 7680, 128), "bfloat16", 512, True),
+            ((1, 2, 4608, 128), "float32", 512, False),
+            ((1, 2, 18176, 64), "bfloat16", 256, True),
+            ((1, 2, 8960, 128), "bfloat16", 256, False),
+            ((1, 2, 1152, 64), "bfloat16", 384, False)):
+        itemsize = np.dtype(dtype).itemsize
+        assert dispatch.flash_route(
+            shape, itemsize, segmented=segmented, interpret=False,
+            vmem_budget=budget) == ("full", chunk), (shape, dtype)
+        # ... and from 2048 on, the next length of that chunk is over
+        assert shape[2] < 2048 or dispatch._flash_vmem_bytes(
+            shape[2] + 512, shape[3], itemsize, chunk) > budget, shape
 
-    for shape, dtype in (((1, 2, 3072, 64), "float32"),
-                         ((1, 2, 2048, 128), "float32"),
-                         ((1, 2, 2560, 128), "bfloat16")):
-        q = jax.ShapeDtypeStruct(shape, np.dtype(dtype))
-        assert dispatch._flash_vmem_bytes(q, 128) <= 12 << 20
+        def attn(q, k, v, *seg):
+            return flash_attention(q, k, v, *seg, causal=True,
+                                   block_q=chunk)
+
         args = _on(one_chip, (shape, dtype), (shape, dtype),
                    (shape, dtype))
+        if segmented:
+            args += _on(one_chip, ((shape[0], shape[2]), "int32"))
         assert _has_kernel(_compile(_grad_of(attn), *args))
+
+
+CELL = (4, 16, 1024, 64)    # gpt2m_train_b4s1024: one layer's q, k, v
+
+
+def test_dispatched_attention_compiles_at_the_train_cells_shape(
+        one_chip, one_chip_host):
+    """``kernels.attention`` as a TPU's default policy has it (flash
+    on, compiled) at the train cell's ``[4, 16, 1024, 64]`` bfloat16:
+    the dispatch takes the kernel, forward and gradient."""
+    from bigdl_tpu import kernels
+    from bigdl_tpu.kernels import KernelConfig
+
+    def attn(q, k, v):
+        out = kernels.attention(q, k, v, causal=True)
+        assert out is not None, "dispatch declined the train cell's shape"
+        return out
+
+    args = _on(one_chip, *[(CELL, "bfloat16")] * 3)
+    with kernels.use(KernelConfig(flash_attention=True, interpret=False)):
+        assert "bigdl_flash_fwd" in _compile(attn, *args).as_text()
+        text = _compile(_grad_of(attn), *args).as_text()
+    assert "bigdl_flash_fwd" in text and "bigdl_flash_bwd" in text
+
+
+def test_a_transformer_block_keeps_no_score_matrix(one_chip,
+                                                   one_chip_host):
+    """One ``TransformerBlock``'s ``value_and_grad`` at the train
+    cell's shape (batch 4 x 1024 tokens, 1024 wide, 16 heads, bfloat16
+    activations): the compiled program holds the flash kernels and no
+    ``[4, 16, 1024, 1024]`` array of any dtype - with flash off it
+    holds several."""
+    from bigdl_tpu import kernels
+    from bigdl_tpu.kernels import KernelConfig
+    from bigdl_tpu.models.transformer import TransformerBlock
+
+    b, heads, s, d = CELL
+    block = TransformerBlock(heads * d, heads, 4 * heads * d).training()
+    params = jax.eval_shape(block.init, jax.random.PRNGKey(0))
+    state = block.initial_state()
+
+    def loss(params, x):
+        half = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+        out, _ = block.apply(half, state, x, training=True)
+        return out.astype(jnp.float32).sum()
+
+    args = (jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), params),
+            *_on(one_chip, ((b, s, heads * d), "bfloat16")))
+    scores = f"[{b},{heads},{s},{s}]"
+
+    def compiled_under(config):
+        # a new function a policy: the policy is read when a function
+        # is traced, and jax keeps the trace of one it has seen
+        with kernels.use(config):
+            return _compile(jax.value_and_grad(
+                lambda p, x: loss(p, x)), *args).as_text()
+
+    text = compiled_under(KernelConfig(flash_attention=True,
+                                       interpret=False))
+    assert "bigdl_flash_fwd" in text and "bigdl_flash_bwd" in text
+    assert scores not in text
+    assert scores in compiled_under(KernelConfig.off())
 
 
 def test_int8_gemm_compiles(one_chip):
@@ -431,6 +526,68 @@ def test_zero2_step_on_four_chips_has_its_collectives(four_chips, lm):
     counts = collective_counts(step.lower(*args).compile())
     assert counts["reduce-scatter"]["total"] > 0, counts
     assert counts["all-gather"]["total"] > 0, counts
+
+
+def test_a_partitioned_step_keeps_the_einsum_form(four_chips, lm):
+    """The same step at the policy a TPU gets by default (flash on,
+    compiled). The step is a plain ``jit`` that the partitioner splits
+    over the data axis, and it cannot split a Mosaic kernel - the
+    full-row kernel, called past the dispatch with its batch sharded,
+    is refused at lowering - so the dispatch declines every layer's
+    attention (``reason=mesh``) and the step compiles in the einsum
+    form, as before the default changed."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu import kernels
+    from bigdl_tpu.kernels import KernelConfig
+    from bigdl_tpu.kernels.flash_attention import flash_attention
+    from bigdl_tpu.optim import Adam
+    from bigdl_tpu.optim.optimizer import build_train_step
+    from bigdl_tpu.precision import PrecisionPolicy
+
+    mesh = four_chips
+    shape = (LM["batch"], LM["heads"], LM["seq"], 64)
+    qkv = _on(NamedSharding(mesh, P("data")), *[(shape, "bfloat16")] * 3)
+    with pytest.raises(NotImplementedError, match="partitioned"):
+        jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=512)).lower(*qkv)
+
+    policy, optim = PrecisionPolicy.named("bf16_mixed"), Adam(3e-4)
+    args = _train_step_args(lm, optim, policy, NamedSharding(mesh, P()),
+                            NamedSharding(mesh, P("data")))
+    with kernels.use(KernelConfig(flash_attention=True, interpret=False)):
+        step = build_train_step(lm, nn.SequenceCrossEntropyCriterion(),
+                                optim, mesh=mesh, precision=policy)
+        asked = (kernels.dispatch.taken_in_thread("flash"),
+                 kernels.dispatch.declined_in_thread("flash"))
+        text = step.lower(*args).compile().as_text()
+    assert kernels.dispatch.taken_in_thread("flash") == asked[0]
+    assert (kernels.dispatch.declined_in_thread("flash")
+            == asked[1] + LM["layers"])
+    assert "bigdl_flash" not in text
+
+
+def test_inside_a_manual_mesh_the_kernel_is_taken(four_chips):
+    """What a layer that owns the mesh can do about it: inside a
+    ``shard_map`` over every axis the program is one device's again,
+    the dispatch sees that in the trace and takes the kernel, and the
+    compiler lowers it - each chip its two rows of the batch."""
+    from bigdl_tpu import kernels
+    from bigdl_tpu.kernels import KernelConfig
+
+    def attn(q, k, v):
+        out = kernels.attention(q, k, v, causal=True)
+        assert out is not None, "dispatch declined inside a manual mesh"
+        return out
+
+    rows = P("data")
+    sharded = jax.shard_map(attn, mesh=four_chips, in_specs=rows,
+                            out_specs=rows, check_vma=False)
+    args = _on(NamedSharding(four_chips, rows),
+               *[((8, 12, 1024, 64), "bfloat16")] * 3)
+    with kernels.use(KernelConfig(flash_attention=True, interpret=False)):
+        assert "bigdl_flash_fwd" in _compile(sharded, *args).as_text()
+        text = _compile(_grad_of(sharded), *args).as_text()
+    assert "bigdl_flash_fwd" in text and "bigdl_flash_bwd" in text
 
 
 # ------------------------- a decoder of unlike layers (ISSUE 28's cell)

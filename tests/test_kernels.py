@@ -80,6 +80,35 @@ class TestKernelConfig:
         assert kernels.active_label() == "reference"
         assert not kernels.enabled("flash")
 
+    @pytest.mark.parametrize("backend, env, flash", [
+        ("tpu", None, True),     # PR 35: the measured default
+        ("cpu", None, False),
+        ("tpu", "0", False),     # the switch still switches it off
+        ("tpu", "decode,int8", False),
+    ])
+    def test_default_by_backend(self, monkeypatch, backend, env, flash):
+        """``_default()`` turns flash on for a ``tpu`` backend and for
+        no other; ``BIGDL_KERNELS`` still overrides it."""
+        from bigdl_tpu.kernels import config
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if env is None:
+            monkeypatch.delenv("BIGDL_KERNELS", raising=False)
+        else:
+            monkeypatch.setenv("BIGDL_KERNELS", env)
+        cfg = config._default()
+        assert cfg.flash_attention is flash
+        assert cfg.decode_attention is (backend == "tpu" and env != "0")
+        # on a TPU nothing interprets unless asked to
+        assert cfg.resolve_interpret() is (backend != "tpu")
+
+    def test_no_field_holds_a_tile_size(self):
+        """Chunk and tile follow from the shape (``flash_route``): the
+        config has seven fields and none of them is a block size."""
+        import dataclasses
+        names = [f.name for f in dataclasses.fields(kernels.KernelConfig)]
+        assert len(names) == 7
+        assert not [n for n in names if n.startswith("block")]
+
     def test_use_scope_restores(self):
         before = kernels.get_config()
         with kernels.use(ON):
@@ -267,7 +296,7 @@ class TestFlashAttention:
         from bigdl_tpu.kernels import dispatch, flash_attention as fa
         big = jax.ShapeDtypeStruct((1, 1, 32768, 128), jnp.bfloat16)
         cfg = kernels.KernelConfig.all_on(interpret=False)
-        assert dispatch._flash_vmem_bytes(big, cfg.block_q) \
+        assert dispatch._flash_vmem_bytes(32768, 128, 2, 512) \
             > cfg.resolve_vmem_budget()
         with kernels.use(kernels.KernelConfig.all_on(
                 interpret=False, long_context=False)):
@@ -289,8 +318,7 @@ class TestFlashAttention:
         fa.blockwise_flash_attention = spy
         try:
             with kernels.use(kernels.KernelConfig.all_on(
-                    interpret=True, vmem_budget_mb=1, block_q=64,
-                    block_k=64)):
+                    interpret=True, vmem_budget_mb=1)):
                 q, k, v = _qkv(b=1, h=1, s=1024, d=16, seed=13)
                 out = kernels.attention(q, k, v, causal=True)
         finally:
@@ -301,12 +329,14 @@ class TestFlashAttention:
                                    atol=1e-5, rtol=0)
 
     def test_vmem_budget_env_and_bounds(self):
-        """BIGDL_VMEM_BUDGET_MB overrides the 12 MiB default; an
-        explicit vmem_budget_mb wins over the env; nonsense values are
-        loud."""
+        """BIGDL_VMEM_BUDGET_MB overrides the default (the 32 MiB the
+        kernels ask the compiler for, less 4); an explicit
+        vmem_budget_mb wins over the env; nonsense values are loud."""
         import os
+        from bigdl_tpu.kernels.common import FLASH_VMEM_LIMIT_MB
         cfg = kernels.KernelConfig.all_on()
-        assert cfg.resolve_vmem_budget() == 12 * 1024 * 1024
+        assert cfg.resolve_vmem_budget() == BUDGET
+        assert BUDGET == (FLASH_VMEM_LIMIT_MB - 4) << 20
         os.environ["BIGDL_VMEM_BUDGET_MB"] = "3"
         try:
             assert cfg.resolve_vmem_budget() == 3 * 1024 * 1024
@@ -353,6 +383,231 @@ class TestFlashAttention:
             out = np.asarray(m.apply(p, st, toks, training=False)[0])
         np.testing.assert_allclose(out, ref, atol=1e-4, rtol=0)
         assert np.array_equal(out.argmax(-1), ref.argmax(-1))
+
+
+# ------------------------------------------------ the flash selection
+
+BUDGET = 28 << 20     # the default: the kernels' 32 MiB less 4
+
+
+class TestFlashRoute:
+    """``dispatch.flash_route``: full-row, blockwise or declined, from
+    the shape alone (PERF.md section 6, PR 35 has the measured table
+    the rule rests on)."""
+
+    @pytest.mark.parametrize("shape, itemsize, segmented, want", [
+        # the train cell and its neighbours in the measured table
+        ((4, 16, 1024, 64), 2, False, ("full", 512)),
+        ((8, 12, 1024, 64), 2, False, ("full", 512)),
+        ((4, 16, 1024, 64), 4, False, ("full", 512)),
+        ((4, 16, 512, 64), 2, False, ("full", 512)),
+        ((2, 16, 2048, 64), 2, False, ("full", 512)),
+        ((1, 16, 4096, 64), 2, False, ("full", 512)),
+        # no 512 divides 768: the largest lane-aligned divisor under it
+        ((4, 16, 768, 64), 2, False, ("full", 384)),
+        # under 512 keys the einsum form measured faster
+        ((4, 16, 256, 64), 2, False, ("declined", "shape")),
+        ((4, 16, 384, 64), 2, False, ("declined", "shape")),
+        # held by the full-row kernel at the 32 MiB it asks for
+        ((1, 16, 8192, 64), 2, False, ("full", 512)),
+        ((1, 8, 4096, 128), 2, False, ("full", 512)),
+        ((1, 2, 10752, 64), 2, False, ("full", 512)),
+        # past the full-row kernel's VMEM: the blockwise kernel
+        ((1, 2, 11264, 64), 2, False, ("blockwise", 512)),
+        ((1, 8, 16384, 64), 2, False, ("blockwise", 512)),
+        ((1, 1, 32768, 128), 2, False, ("blockwise", 512)),
+        ((1, 1, 33024, 128), 2, False, ("blockwise", 384)),
+        # a packed slab takes the full-row form or none
+        ((4, 16, 1024, 64), 2, True, ("full", 512)),
+        ((1, 16, 8192, 64), 2, True, ("full", 512)),
+        ((1, 8, 16384, 64), 2, True, ("declined", "vmem")),
+        # no 512 divides these: the largest lane-aligned divisor
+        ((4, 16, 1152, 64), 2, False, ("full", 384)),
+        ((4, 16, 1280, 64), 2, False, ("full", 256)),
+        # ... which at 128 is on neither kernel's measured path
+        ((4, 16, 640, 64), 2, False, ("declined", "shape")),
+        ((4, 16, 896, 64), 2, False, ("declined", "shape")),
+        ((1, 16, 1664, 64), 2, False, ("declined", "shape")),
+        ((1, 1, 33664, 128), 2, False, ("declined", "shape")),
+        # no whole number of lane tiles: blocks nobody compiled
+        ((4, 16, 600, 64), 2, False, ("declined", "shape")),
+        ((4, 16, 900, 64), 2, True, ("declined", "shape")),
+        ((1, 4, 5000, 64), 2, False, ("declined", "shape")),
+    ])
+    def test_compiled_rule(self, shape, itemsize, segmented, want):
+        assert dispatch_route(shape, itemsize, segmented,
+                              interpret=False) == want
+
+    def test_long_context_off_declines_past_the_budget(self):
+        from bigdl_tpu.kernels.dispatch import flash_route
+        assert flash_route((1, 8, 16384, 64), 2, segmented=False,
+                           interpret=False, vmem_budget=BUDGET,
+                           long_context=False) == ("declined", "vmem")
+
+    @pytest.mark.parametrize("shape, want", [
+        ((2, 2, 32, 8), ("full", 32)),      # no length is too short
+        ((2, 4, 256, 64), ("full", 256)),
+        ((1, 1, 1100, 8), ("full", 275)),   # any divisor will do
+    ])
+    def test_the_interpreter_takes_test_sizes(self, shape, want):
+        assert dispatch_route(shape, 4, False, interpret=True) == want
+
+    def test_the_estimate_counts_the_forwards_strip(self):
+        """From S = 2048 on the forward's ``[S, chunk]`` float32 score
+        strip is what binds, not the backward's accumulators."""
+        from bigdl_tpu.kernels.dispatch import _flash_vmem_bytes
+        strip = 4096 * 512 * 4
+        assert _flash_vmem_bytes(4096, 64, 2, 512) > strip
+        assert _flash_vmem_bytes(10752, 64, 2, 512) <= BUDGET
+        assert _flash_vmem_bytes(11264, 64, 2, 512) > BUDGET
+
+
+def dispatch_route(shape, itemsize, segmented, interpret):
+    from bigdl_tpu.kernels.dispatch import flash_route
+    return flash_route(shape, itemsize, segmented=segmented,
+                       interpret=interpret, vmem_budget=BUDGET)
+
+
+class TestDispatchedAttention:
+    """The path ``nn.attention`` takes with flash on, against the
+    einsum form it replaces, at cuts of the train cell's shape with
+    its head size: ``[2, 4, 256, 64]`` (one chunk) and ``[1, 2, 1024,
+    64]`` (the cell's two chunks of 512: the diagonal one and the one
+    left of it)."""
+
+    @pytest.mark.parametrize("segmented", [False, True])
+    @pytest.mark.parametrize("dtype, tol", [("float32", 2e-5),
+                                            ("bfloat16", 3e-2)])
+    @pytest.mark.parametrize("shape", [(2, 4, 256, 64), (1, 2, 1024, 64)])
+    def test_forward_and_grad_match_the_einsum_form(self, shape, dtype,
+                                                    tol, segmented):
+        r = np.random.default_rng(40)
+        q, k, v, cot = (jnp.asarray(r.standard_normal(shape), dtype)
+                        for _ in range(4))
+        seg = mask = None
+        if segmented:
+            seg = jnp.asarray(np.sort(r.integers(0, 3, shape[::2]),
+                                      axis=1).astype(np.int32))
+            mask = seg[:, None, :, None] == seg[:, None, None, :]
+        f32 = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
+
+        def loss(attn):
+            return lambda *a: (attn(*a).astype(jnp.float32)
+                               * cot.astype(jnp.float32)).sum()
+
+        def fused(q_, k_, v_):
+            with kernels.use(kernels.KernelConfig(flash_attention=True,
+                                                  interpret=True)):
+                out = kernels.attention(q_, k_, v_, causal=True,
+                                        segment_ids=seg)
+            assert out is not None
+            return out
+
+        ref = lambda *a: _ref_attention(*a, causal=True,  # noqa: E731
+                                        mask=mask)
+        before = kernels.dispatch.taken_in_thread("flash")
+        np.testing.assert_allclose(f32(fused(q, k, v)), f32(ref(q, k, v)),
+                                   atol=tol, rtol=0)
+        assert kernels.dispatch.taken_in_thread("flash") == before + 1
+        got = jax.grad(loss(fused), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype
+            np.testing.assert_allclose(f32(a), f32(b), atol=10 * tol,
+                                       rtol=0)
+
+    def test_declined_twin_counts_every_reason(self):
+        q, k, v = _qkv()
+        d0 = kernels.dispatch.declined_in_thread("flash")
+        with kernels.use(OFF):
+            assert kernels.attention(q, k, v) is None          # config
+        with kernels.use(ON):
+            assert kernels.attention(q[:, 0], k[:, 0], v[:, 0]) is None
+        assert kernels.dispatch.declined_in_thread("flash") == d0 + 2
+
+
+class TestPartitionedPrograms:
+    """The partitioner cannot split a Mosaic kernel, and jax refuses to
+    lower one in a program it may split: compiled, the flash dispatch
+    takes the kernel only where the trace shows one device's program -
+    a process that sees one device, or a ``shard_map`` with every mesh
+    axis manual - and declines with ``reason=mesh`` elsewhere
+    (``DistriOptimizer``'s step on a data mesh is a plain ``jit``).
+    Traced here, never lowered; tests/test_chip_compile.py compiles the
+    same cases for a described four-chip mesh."""
+
+    SHAPE = (4, 2, 2048, 64)      # a quarter of it still a kernel's
+    COMPILED = kernels.KernelConfig(flash_attention=True, interpret=False)
+
+    @staticmethod
+    def _mesh(**axes):
+        n = math.prod(axes.values())
+        return jax.sharding.Mesh(
+            np.array(jax.devices()[:n]).reshape(tuple(axes.values())),
+            tuple(axes))
+
+    def _took(self, wrap=lambda f: f):
+        """Whether a trace of the dispatched attention, through
+        ``wrap``, took the kernel - and the reasons it gave if not."""
+        import bigdl_tpu.telemetry as telemetry
+        declined = telemetry.registry().counter(
+            "kernels/dispatch/reference")
+        took = []
+
+        def attn(q, k, v):
+            out = kernels.attention(q, k, v, causal=True)
+            took.append(out is not None)
+            return q if out is None else out
+
+        x = jax.ShapeDtypeStruct(self.SHAPE, jnp.bfloat16)
+        before = declined.value(op="flash", reason="mesh")
+        with kernels.use(self.COMPILED):
+            jax.eval_shape(wrap(attn), x, x, x)
+        after = declined.value(op="flash", reason="mesh")
+        assert after == before + (took == [False])
+        return took == [True]
+
+    def test_a_process_with_one_device_takes_the_kernel(self, monkeypatch):
+        monkeypatch.setattr(jax, "device_count", lambda: 1)
+        assert self._took()
+        assert self._took(jax.jit)
+
+    def test_a_plain_jit_beside_other_devices_declines(self):
+        assert jax.device_count() > 1          # conftest's eight
+        assert not self._took()
+        assert not self._took(jax.jit)
+
+    @pytest.mark.parametrize("axes, manual, took", [
+        ({"data": 4}, None, True),                  # all of one axis
+        ({"data": 2, "model": 2}, None, True),      # all of two
+        ({"data": 2, "seq": 2}, {"seq"}, False),    # Ulysses beside DP
+        ({"data": 1, "seq": 4}, {"seq"}, False),    # jax asks by name
+    ])
+    def test_inside_a_shard_map_the_manual_axes_decide(self, axes, manual,
+                                                       took):
+        from jax.sharding import PartitionSpec as P
+        mesh = self._mesh(**axes)
+        first = next(iter(axes))
+        spec = P(first) if manual is None else P(None, None, "seq")
+
+        def wrap(f):
+            # check_vma off, as every shard_map of this repo has it:
+            # pallas_call states no varying axes for what it returns
+            kw = {} if manual is None else dict(
+                axis_names=frozenset(manual))
+            return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=spec,
+                                         out_specs=spec, check_vma=False,
+                                         **kw))
+
+        assert self._took(wrap) is took
+
+    def test_the_interpreter_is_not_asked(self):
+        """Interpreted, a kernel is plain jax operations, which the
+        partitioner splits like any others: tier-1 keeps running the
+        kernel bodies on its eight CPU devices."""
+        q, k, v = _qkv()
+        with kernels.use(ON):
+            assert kernels.attention(q, k, v, causal=True) is not None
 
 
 # -------------------------------------------- blockwise (long-context)
@@ -602,7 +857,7 @@ class TestRaggedDecode:
         """At every rung of the default ladders up to both serve cells'
         lengths (and caches no lane tile divides) the tile divides the
         block and is whole lane tiles, or is the whole block; it comes
-        from the shapes alone — ``KernelConfig.block_k`` is flash's."""
+        from the shapes alone, as the flash kernels' chunk does."""
         import inspect
 
         from bigdl_tpu.serving.compile_cache import BucketLadder
@@ -614,10 +869,10 @@ class TestRaggedDecode:
                 tile = kv_tile(a, d, g, itemsize)
                 assert a % tile == 0
                 assert tile % 128 == 0 or tile == a
-                with kernels.use(kernels.KernelConfig.all_on(block_k=256)):
-                    assert kv_tile(a, d, g, itemsize) == tile
+        # no knob: not an argument, and since PR 35 not a config field
         assert "block_k" not in inspect.signature(
             ragged_decode_attention).parameters
+        assert not hasattr(kernels.KernelConfig(), "block_k")
         # the two serve cells: one tile a GPT-2 row, 2048 columns of a
         # ring or of the global entry
         assert kv_tile(1024, 64, 1, 4) == 1024

@@ -477,3 +477,41 @@ def test_the_gap_histogram_skips_each_calls_first_window(windowed_run):
     assert all(0.0 < ms < 60e3 for ms in last)
     gap = telemetry.registry().get("train/optimizer/window_gap_ms")
     assert gap.kind == "histogram" and telemetry.NAME_RE.match(gap.name)
+
+
+@pytest.mark.parametrize("config, share", [
+    (None, 0.0),                    # the CPU default: every layer declines
+    ("flash", 1.0),                 # flash on: every layer takes the kernel
+])
+def test_a_traced_window_observes_the_attention_share(config, share):
+    """``train/optimizer/attn_in_kernel_share``: when a dispatch traces
+    its window program, the flash dispatches taken over taken +
+    declined in that trace, always on; a window that traces nothing
+    observes nothing. The benchmark's ``program_histogram`` reader
+    reads it as ``train_attn_in_kernel_share.train``."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu import kernels, optim
+    from bigdl_tpu.dataset import DataSet, Sample, SampleToMiniBatch
+    from bigdl_tpu.optim.optimizer import Optimizer
+    from bigdl_tpu.optim.trigger import max_iteration
+
+    RandomGenerator.set_seed(9)
+    rows = np.random.RandomState(1).randint(0, 50, (32, 9)).astype(np.int32)
+    ds = DataSet.array([Sample(r[:-1], r[1:]) for r in rows]) \
+        .transform(SampleToMiniBatch(4))
+    opt = Optimizer(_model().training(), ds,
+                    nn.SequenceCrossEntropyCriterion(), batch_size=4)
+    opt.set_optim_method(optim.Adam(learning_rate=1e-3))
+    opt.set_steps_per_sync(2)
+    hist = telemetry.registry().get("train/optimizer/attn_in_kernel_share")
+    assert hist.kind == "histogram" and telemetry.NAME_RE.match(hist.name)
+    before = hist.count()
+    policy = kernels.KernelConfig(flash_attention=True, interpret=True) \
+        if config == "flash" else kernels.config._default()
+    assert policy.flash_attention is (config == "flash")
+    with kernels.use(policy):
+        opt.set_end_when(max_iteration(6))      # three windows, one trace
+        opt.optimize()
+    assert hist.count() == before + 1
+    assert hist.samples()[-1] == share
+
