@@ -230,10 +230,8 @@ def serving_footprint(cand: Candidate) -> Dict[str, float]:
     key_spec = jax.eval_shape(jax.random.PRNGKey,
                               jax.ShapeDtypeStruct((), jnp.uint32))
     params = jax.eval_shape(model.init, key_spec)
-    k_sds, v_sds = KVCache.spec_for_model(model, int(cfg["slots"]),
-                                          max_len)
-    return {"arg_bytes": float(_tree_bytes(params)
-                               + _tree_bytes([k_sds, v_sds])),
+    cache = KVCache.spec_for_model(model, int(cfg["slots"]), max_len)
+    return {"arg_bytes": float(_tree_bytes(params) + _tree_bytes(cache)),
             "out_bytes": 0.0,
             "temp_bytes": float(cfg["prefix_cache_bytes"])}
 

@@ -47,6 +47,20 @@ import numpy as np
 import bigdl_tpu.telemetry as telemetry
 
 
+def _refuse(kv) -> None:
+    """A cache this store cannot cut blocks out of, by type."""
+    from bigdl_tpu.generation.kv_cache import RecurrentStateError
+
+    if kv.recurrent:
+        raise RecurrentStateError(
+            "the prefix cache stores a prompt's K/V columns up to a "
+            "position; a recurrent state has no columns, and a snapshot "
+            "of it at that position is not built")
+    raise ValueError(
+        f"the prefix cache stores one kind of K/V block; this cache "
+        f"keeps {sorted(set(kv.layout))}")
+
+
 @functools.lru_cache(maxsize=64)
 def _seed_program(cache_shape, dtype_str, rung):
     """The donated seed-copy program for one (cache geometry, rung):
@@ -315,6 +329,8 @@ class PrefixCache:
         prefilled slot: ``[layers, heads, head_dim, rung]`` for K and
         V. Columns past the real prompt length ride along (the rung
         pads them) but are never attended."""
+        if not kv.uniform:
+            _refuse(kv)
         return _extract_program(int(rung))(kv.k, kv.v, np.int32(slot))
 
     @staticmethod
@@ -326,10 +342,12 @@ class PrefixCache:
         copy runs as a donated compiled splice (no full-cache copy),
         so a full-prefix hit's TTFT is one dynamic_update_slice plus
         the first decode step."""
+        if not kv.uniform:
+            _refuse(kv)
         fn = _seed_program((kv.layers,) + kv.k[0].shape,
                            str(np.dtype(kv.dtype)), entry.rung)
-        kv.k, kv.v = fn(kv.k, kv.v, entry.k, entry.v,
-                        np.int32(slot))
+        k, v = fn(kv.k, kv.v, entry.k, entry.v, np.int32(slot))
+        kv.entries = tuple({"k": a, "v": b} for a, b in zip(k, v))
         kv.lengths[slot] = entry.length
 
     # ------------------------------------------------------- introspect

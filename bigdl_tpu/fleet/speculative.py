@@ -40,7 +40,7 @@ import numpy as np
 import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu import faults
 from bigdl_tpu.generation.engine import DecodeEngine
-from bigdl_tpu.generation.kv_cache import KVCache
+from bigdl_tpu.generation.kv_cache import KVCache, RecurrentStateError
 from bigdl_tpu.generation.sampling import Sampler, SamplingParams
 from bigdl_tpu.serving.compile_cache import BucketLadder, CompileCache
 from bigdl_tpu.serving.registry import Servable
@@ -124,6 +124,14 @@ class SpeculativeDecoder:
                                             self.config.max_len)
         self._draft_kv = KVCache.for_model(draft_model, self.config.slots,
                                            self.config.max_len)
+        for role, kv in (("target", self._target_kv),
+                         ("draft", self._draft_kv)):
+            if kv.recurrent:
+                raise RecurrentStateError(
+                    f"the {role} model keeps a recurrent state: rejected "
+                    "drafts are rolled back by resetting a slot's length, "
+                    "and a state would need a snapshot at the last "
+                    "accepted position, which is not built")
         r = metrics if metrics is not None else telemetry.registry()
         inst = register_speculative_instruments(r)
         self._c_proposed = inst["proposed"]

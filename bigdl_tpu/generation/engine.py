@@ -16,10 +16,14 @@ grows — one compile *per token*. This engine pins every shape instead:
   sequences never scan the whole preallocated ``max_len``.
 
 The cache every program takes and returns is
-:class:`~bigdl_tpu.generation.kv_cache.KVCache`'s own form: ``k`` and
-``v`` each a tuple of per-layer ``[slots, heads, head_dim, max_len]``
-arrays (time last: ``head_dim`` 64 on the lanes would pad 2x), donated
-as pytrees and written in place — a decode step holds no copy of it.
+:class:`~bigdl_tpu.generation.kv_cache.KVCache`'s own form, ONE pytree:
+a tuple of one entry a layer, each a dict of arrays of the kind the
+model declared — keys and values ``{"k", "v"}`` ``[slots, heads,
+head_dim, columns]`` (time last: ``head_dim`` 64 on the lanes would pad
+2x), a recurrent state's named arrays ``[slots, ...]`` with no time
+axis, or ``{}`` — donated and written in place: a decode step holds no
+copy of it. A prefill gathers its rows' entries by slot id and scatters
+them back (the column slice only where an entry has columns).
 
 **A prefill's shape follows from the model.** A rung whose one-shot
 ``[prefill_rows, heads, rung, rung]`` float32 scores would pass
@@ -27,9 +31,9 @@ as pytrees and written in place — a decode step holds no copy of it.
 chunk]`` pieces through the same per-rung program (``prefill_shape``):
 no option asks for it, and a rung that fits keeps the program it had.
 A model whose layers keep different cache entries
-(``KVCache.layout``: rings for window layers) has each layer's entry
-gathered, attended and written back at its own width
-(``min(rung, columns)``). Every program also returns the model's
+(``KVCache.layout``: rings for window layers, states for state-space
+layers) has each layer's entry gathered, attended and written back at
+its own width (``min(rung, columns)``; a state whole). Every program also returns the model's
 ``MOE_STATS_KEY`` state leaves (``[expert layers, 3]``; nothing for a
 model without experts), recorded on the host with the logits.
 
@@ -46,18 +50,26 @@ attribute has a default):
 
 - ``apply(params, state, tokens, *, training, cache, positions,
   attend_len, logits_at=None, live=None, fresh=False) -> (logits,
-  state, cache)``: one KV-cached step over ``tokens [B, S]``. ``cache``
-  is ``{"k", "v"}``, each a tuple of one array a layer in
-  ``cache_layout``'s shapes with ``B`` rows; ``positions`` (int32
-  ``[B]``) each row's write offset; ``attend_len`` (static) the rung.
-  ``logits_at`` (int32 ``[B]``): return that one new position's logits
-  a row, ``[B, 1, V]``, the tokens past it being padding; without it
-  ``[B, S, V]``. ``live`` (bool ``[B]``): False for rows that are
-  padding or free decode slots. ``fresh`` (static): a one-shot prefill,
-  every offset 0 and nothing of the rows cached yet.
-- ``cache_layout(max_len) -> [(kv heads, head dim, columns), ...]``,
-  one entry a layer; ``cache_dtype()`` (None: the default type);
-  ``scoreless_prefill(rung) -> bool``: whether ``rung`` fresh tokens
+  state, cache)``: one cached step over ``tokens [B, S]``. ``cache`` is
+  a tuple of one entry a layer, ``B`` rows of ``cache_layout``'s
+  arrays (K/V entries cut to the rung's columns), returned in the same
+  form; ``positions`` (int32 ``[B]``) each row's write offset;
+  ``attend_len`` (static) the rung. ``logits_at`` (int32 ``[B]``):
+  return that one new position's logits a row, ``[B, 1, V]``, the
+  tokens past it being padding; without it ``[B, S, V]``. ``live``
+  (bool ``[B]``): False for rows that are padding or free decode slots
+  (they may compute garbage; a live row never reads another's).
+  ``fresh`` (static): a one-shot prefill, every offset 0 and nothing of
+  the rows cached yet. A recurrent entry obeys the same arguments: a
+  row at offset 0 (every row of a ``fresh`` call) starts from a ZERO
+  state whatever the slot held, a row at a later offset continues from
+  its entry, and the state stops at the row's last real token
+  (``logits_at``).
+- ``cache_layout(max_len)``: one entry a layer naming its kind —
+  ``("kv", kv heads, head dim, columns)``, ``("state", ((name, shape,
+  dtype), ...))`` or ``("none",)`` (``KVCache`` has their meaning);
+  ``cache_dtype()`` (None: the default type); ``scoreless_prefill(rung)
+  -> bool``: whether the layers that attend let ``rung`` fresh tokens
   attend each other without ``[rung, rung]`` scores.
 - the attributes ``num_layers``, ``num_heads``, ``max_len``,
   ``vocab_size``.
@@ -67,7 +79,10 @@ Speculative decoding (``bigdl_tpu.fleet.speculative``) adds one
 same cached incremental forward, adjudicated host-side — growing the
 documented bound to **at most 3 programs per (version, bucket)**
 (prefill, decode, verify), asserted structurally at registration and
-via the compile counter in tests/test_fleet.py.
+via the compile counter in tests/test_fleet.py. A verify step rewinds a
+slot to the last accepted position, which a recurrent entry cannot do:
+``verify_program`` refuses such a model with
+:class:`~bigdl_tpu.generation.kv_cache.RecurrentStateError`.
 """
 from __future__ import annotations
 
@@ -80,7 +95,8 @@ import numpy as np
 
 import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu.serving.compile_cache import BucketLadder, CompileCache
-from bigdl_tpu.generation.kv_cache import KVCache
+from bigdl_tpu.generation.kv_cache import (KVCache, RecurrentStateError,
+                                           has_recurrent)
 from bigdl_tpu.kernels.ragged_decode import block_columns, kv_tile
 
 #: float32 attention scores one prefill call may hold, ``rows x heads x
@@ -157,12 +173,12 @@ def _record_kv(model, kv: KVCache, positions, active,
     of the ``kernel_layers`` layers whose decode kernel wrote them
     itself (counted when the step's program was traced). Host
     arithmetic on the lengths vector only."""
-    if not telemetry.enabled():
+    if not telemetry.enabled() or not kv.kv_layout:
         return
     c = positions[active].astype(np.int64) + 1
     itemsize = kv.k[0].dtype.itemsize
     valid = fetched = 0
-    for (heads, d, columns), layers in Counter(kv.layout).items():
+    for (heads, d, columns), layers in Counter(kv.kv_layout).items():
         n = np.minimum(c, columns)
         tile = kv_tile(block_columns(columns, min(attend_len, columns)),
                        d, int(model.num_heads) // heads, itemsize)
@@ -171,8 +187,40 @@ def _record_kv(model, kv: KVCache, positions, active,
     telemetry.tracer().record(
         "serving/decode/kv", 0.0,
         args={"valid_columns": valid, "fetched_columns": fetched,
-              "written_columns": len(c) * len(kv.layout),
+              "written_columns": len(c) * len(kv.kv_layout),
               "kernel_written_columns": len(c) * kernel_layers})
+
+
+def _record_ssm(kv: KVCache, rows: int, kind: str) -> None:
+    """With the span tracer on, one program call's recurrent-state
+    traffic into a ring record (``serving/ssm/step``): ``slot_layers``,
+    the live rows times the layers that keep a state, and
+    ``state_bytes``, what the call must read AND write of them (every
+    array of those rows' states, twice). Host arithmetic only; nothing
+    for a model without such layers."""
+    if not telemetry.enabled() or not kv.state_layers:
+        return
+    telemetry.tracer().record(
+        "serving/ssm/step", 0.0,
+        args={"kind": kind, "slot_layers": rows * kv.state_layers,
+              "state_bytes": 2 * rows * kv.state_slot_bytes})
+
+
+def _gather(cache, ids, attend_len: int):
+    """Rows ``ids`` of every entry: K/V cut to the rung's columns, a
+    state whole."""
+    return tuple(
+        {n: a[ids, :, :, :min(attend_len, a.shape[3])] if n in ("k", "v")
+         else a[ids] for n, a in e.items()} for e in cache)
+
+
+def _scatter(cache, ids, rows, attend_len: int):
+    """:func:`_gather`'s inverse; out-of-range ids are dropped."""
+    return tuple(
+        {n: a.at[ids, :, :, :min(attend_len, a.shape[3])].set(
+            r[n], mode="drop") if n in ("k", "v")
+         else a.at[ids].set(r[n], mode="drop") for n, a in e.items()}
+        for e, r in zip(cache, rows))
 
 
 class DecodeEngine:
@@ -222,13 +270,13 @@ class DecodeEngine:
     # ------------------------------------------------------- programs
     # items one program call processes (program-profile MFU basis):
     # prefill computes rows x bucket prompt tokens, decode one token
-    # per slot — both read the tokens operand (positional arg 4)
+    # per slot — both read the tokens operand (positional arg 3)
     _PROFILE_ITEMS = {
-        "prefill": lambda args, kwargs: (args[4].shape[0]
-                                         * args[4].shape[1]),
-        "decode": lambda args, kwargs: args[4].shape[0],
-        "verify": lambda args, kwargs: (args[4].shape[0]
-                                        * args[4].shape[1]),
+        "prefill": lambda args, kwargs: (args[3].shape[0]
+                                         * args[3].shape[1]),
+        "decode": lambda args, kwargs: args[3].shape[0],
+        "verify": lambda args, kwargs: (args[3].shape[0]
+                                        * args[3].shape[1]),
     }
 
     #: the full program-kind vocabulary per ladder rung — the
@@ -270,7 +318,7 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        def serving_prefill(params, state, k, v, tokens, last_in_chunk,
+        def serving_prefill(params, state, cache, tokens, last_in_chunk,
                             slot_ids, offsets):
             on_trace()
             ids = slot_ids.astype(jnp.int32)
@@ -279,24 +327,19 @@ class DecodeEngine:
             # the layer's entry reaches into the rung (OOB padding rows
             # clamp to the last slot; their garbage output is never
             # read and their write-back below is dropped)
-            width = lambda a: min(attend_len, a.shape[3])
             with jax.named_scope("attn/kv_write"):
-                rows_k = tuple(a[ids, :, :, :width(a)] for a in k)
-                rows_v = tuple(a[ids, :, :, :width(a)] for a in v)
+                rows = _gather(cache, ids, attend_len)
             logits, new_state, rows = model.apply(
-                params, state, tokens, training=False,
-                cache={"k": rows_k, "v": rows_v},
+                params, state, tokens, training=False, cache=rows,
                 positions=offsets.astype(jnp.int32),
                 attend_len=attend_len, logits_at=last_at,
-                live=ids < k[0].shape[0], fresh=fresh)
+                live=ids < jax.tree.leaves(cache)[0].shape[0],
+                fresh=fresh)
             with jax.named_scope("attn/kv_write"):
-                k = tuple(a.at[ids, :, :, :width(a)].set(r, mode="drop")
-                          for a, r in zip(k, rows["k"]))
-                v = tuple(a.at[ids, :, :, :width(a)].set(r, mode="drop")
-                          for a, r in zip(v, rows["v"]))
-            return logits[:, 0, :], k, v, _moe_stats(new_state)
+                cache = _scatter(cache, ids, rows, attend_len)
+            return logits[:, 0, :], cache, _moe_stats(new_state)
 
-        return jax.jit(serving_prefill, donate_argnums=(2, 3))
+        return jax.jit(serving_prefill, donate_argnums=(2,))
 
     @staticmethod
     def _decode_jit(model, attend_len: int, on_trace,
@@ -311,13 +354,13 @@ class DecodeEngine:
 
         from bigdl_tpu.kernels.dispatch import taken_in_thread
 
-        def serving_decode(params, state, k, v, tokens, positions, active):
+        def serving_decode(params, state, cache, tokens, positions, active):
             on_trace()
             pos = jnp.where(active, positions.astype(jnp.int32), 0)
             before = taken_in_thread("decode_write")
             logits, new_state, cache = model.apply(
                 params, state, tokens[:, None], training=False,
-                cache={"k": k, "v": v}, positions=pos,
+                cache=cache, positions=pos,
                 attend_len=attend_len, live=active)
             kernel_wrote(taken_in_thread("decode_write") - before)
             logits = logits[:, 0, :]
@@ -326,10 +369,9 @@ class DecodeEngine:
             # not the [slots, V] logits (ties to the lowest id, as
             # np.argmax on the host breaks them)
             ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (logits, cache["k"], cache["v"],
-                    _moe_stats(new_state), ids)
+            return logits, cache, _moe_stats(new_state), ids
 
-        return jax.jit(serving_decode, donate_argnums=(2, 3))
+        return jax.jit(serving_decode, donate_argnums=(2,))
 
     @staticmethod
     def _verify_jit(model, attend_len: int, on_trace):
@@ -340,26 +382,25 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        def serving_verify(params, state, k, v, tokens, positions, active):
+        def serving_verify(params, state, cache, tokens, positions, active):
             on_trace()
             pos = jnp.where(active, positions.astype(jnp.int32), 0)
             logits, new_state, cache = model.apply(
-                params, state, tokens, training=False,
-                cache={"k": k, "v": v}, positions=pos,
-                attend_len=attend_len, live=active)
-            return logits, cache["k"], cache["v"], _moe_stats(new_state)
+                params, state, tokens, training=False, cache=cache,
+                positions=pos, attend_len=attend_len, live=active)
+            return logits, cache, _moe_stats(new_state)
 
-        return jax.jit(serving_verify, donate_argnums=(2, 3))
+        return jax.jit(serving_verify, donate_argnums=(2,))
 
     def prefill_program(self, servable, bucket: int):
         """The compiled prefill for prompt bucket ``bucket``:
-        ``(params, state, k, v, tokens[Bp,Sq], last_in_chunk[Bp],
-        slot_ids[Bp], offsets[Bp]) -> (logits[Bp,V], k', v')`` with the
-        cache (``k``, ``v``: per-layer tuples) donated. ``Sq`` is the
+        ``(params, state, cache, tokens[Bp,Sq], last_in_chunk[Bp],
+        slot_ids[Bp], offsets[Bp]) -> (logits[Bp,V], cache', expert
+        counts)`` with the cache (one entry a layer) donated. ``Sq`` is the
         bucket itself, or the engine's ``prefill_chunk`` for larger
         rungs — ONE token shape per rung either way, so chunking never
         adds a program. Padding rows
-        carry ``slot_ids == slots`` (out of bounds): their K/V scatter
+        carry ``slot_ids == slots`` (out of bounds): their scatter
         is dropped and their logits row is garbage the driver never
         reads."""
         model = servable.model
@@ -403,8 +444,8 @@ class DecodeEngine:
 
     def decode_program(self, servable, attend_len: int):
         """The compiled decode step for length bucket ``attend_len``:
-        ``(params, state, k, v, tokens[slots], positions[slots],
-        active[slots]) -> (logits[slots,V], k', v', expert counts,
+        ``(params, state, cache, tokens[slots], positions[slots],
+        active[slots]) -> (logits[slots,V], cache', expert counts,
         argmax ids[slots])``, cache donated.
         Each live slot writes its token's K/V at ``positions[s]`` and
         attends the first ``attend_len`` cache positions under the
@@ -421,20 +462,29 @@ class DecodeEngine:
 
     def verify_program(self, servable, attend_len: int):
         """The compiled speculative-verify step for length bucket
-        ``attend_len``: ``(params, state, k, v, tokens[slots, w],
-        positions[slots], active[slots]) -> (logits[slots, w, V], k',
-        v')``, cache donated. Row ``s`` writes K/V for its ``w`` input
+        ``attend_len``: ``(params, state, cache, tokens[slots, w],
+        positions[slots], active[slots]) -> (logits[slots, w, V],
+        cache', expert counts)``, cache donated. Row ``s`` writes K/V for its ``w`` input
         tokens at ``positions[s] .. positions[s]+w-1`` and
         ``logits[s, i]`` is the target distribution for the token
         AFTER input ``i`` — the adjudication rows speculative decoding
         accepts draft proposals against. One verify program per rung
         (``w`` is fixed per decoder config), the third and last kind
-        of the ≤ 3-per-(version, bucket) bound."""
+        of the ≤ 3-per-(version, bucket) bound. A model with a
+        recurrent entry is refused: rejected drafts would have to be
+        rolled back out of its state."""
         model = servable.model
-        return self._program(
-            servable, "verify", attend_len,
-            lambda on_trace: self._verify_jit(model, attend_len,
-                                              on_trace))
+
+        def build(on_trace):
+            if has_recurrent(model.cache_layout(attend_len)):
+                raise RecurrentStateError(
+                    f"{servable.name!r} keeps a recurrent state: a "
+                    "verify step needs a snapshot of the state at the "
+                    "last accepted position to rewind to, which is not "
+                    "built")
+            return self._verify_jit(model, attend_len, on_trace)
+
+        return self._program(servable, "verify", attend_len, build)
 
     def verify(self, servable, kv: KVCache, tokens: np.ndarray,
                positions: np.ndarray, active: np.ndarray):
@@ -463,33 +513,35 @@ class DecodeEngine:
         from bigdl_tpu.generation.kv_cache import KVCache
 
         bucket = max(self.ladder)
-        k_spec, v_spec = KVCache.spec_for_model(
-            model, self.slots, bucket, kv_dtype)
+        spec = KVCache.spec_for_model(model, self.slots, bucket, kv_dtype)
 
         def sds(shape, dtype):
             return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype))
 
         noop = lambda: None  # noqa: E731  on_trace hook, nothing to count
         rows, sq = self.prefill_shape(model, bucket)
-        return [
+        programs = [
             (f"prefill/{bucket}", self._prefill_jit(model, bucket, noop,
                                                     sq == bucket),
-             (params, state, k_spec, v_spec,
+             (params, state, spec,
               sds((rows, sq), np.int32), sds((rows,), np.int32),
               sds((rows,), np.int32), sds((rows,), np.int32))),
             (f"decode/{bucket}", self._decode_jit(model, bucket, noop),
-             (params, state, k_spec, v_spec,
+             (params, state, spec,
               sds((self.slots,), np.int32), sds((self.slots,), np.int32),
-              sds((self.slots,), bool))),
+              sds((self.slots,), bool)))]
+        if not has_recurrent(model.cache_layout(bucket)):
             # the speculative-verify rung (fleet.speculative): a
             # representative draft width of 4 — the verify program's
-            # donation/HBM contract is width-independent
-            (f"verify/{bucket}", self._verify_jit(model, bucket, noop),
-             (params, state, k_spec, v_spec,
-              sds((self.slots, 4), np.int32),
-              sds((self.slots,), np.int32),
-              sds((self.slots,), bool))),
-        ]
+            # donation/HBM contract is width-independent. Not for a
+            # model with a recurrent entry, which verify refuses
+            programs.append(
+                (f"verify/{bucket}", self._verify_jit(model, bucket, noop),
+                 (params, state, spec,
+                  sds((self.slots, 4), np.int32),
+                  sds((self.slots,), np.int32),
+                  sds((self.slots,), bool))))
+        return programs
 
     # ------------------------------------------------------ execution
     def prefill(self, servable, kv: KVCache, prompts: Sequence[np.ndarray],
@@ -547,8 +599,8 @@ class DecodeEngine:
                     last_in[r] = min(lens[i] - off, sq)
                 if not live:
                     continue
-                logits, kv.k, kv.v, stats = prog(
-                    servable.params, servable.state, kv.k, kv.v, tokens,
+                logits, kv.entries, stats = prog(
+                    servable.params, servable.state, kv.entries, tokens,
                     last_in, ids, offsets)
                 with telemetry.span("serving/prefill/device_wait"):
                     for r, i in enumerate(group):
@@ -556,6 +608,7 @@ class DecodeEngine:
                                 and (lens[i] - 1) // sq == c):
                             out[i] = np.asarray(logits[r])
                 _record_moe(stats, "prefill")
+                _record_ssm(kv, int((ids != self.slots).sum()), "prefill")
         for i, slot in enumerate(slot_ids):
             kv.lengths[slot] = lens[i]
         return np.stack(out), bucket
@@ -614,8 +667,8 @@ class DecodeEngine:
                        if active.any() else width)
             attend_len = self.ladder.bucket_for(longest)
             prog = program_for(servable, attend_len)
-            logits, kv.k, kv.v, stats, *ids = prog(
-                servable.params, servable.state, kv.k, kv.v,
+            logits, kv.entries, stats, *ids = prog(
+                servable.params, servable.state, kv.entries,
                 tokens.astype(np.int32), positions.astype(np.int32),
                 active.astype(bool))
             wanted = ids[0] if ids_only else logits
@@ -624,6 +677,7 @@ class DecodeEngine:
                 _record_kv(servable.model, kv, positions, active,
                            attend_len, self._kernel_layers.get(
                                servable.key + ("decode", attend_len), 0))
+                _record_ssm(kv, int(active.sum()), "decode")
             jax.block_until_ready(wanted)
         with telemetry.span("serving/decode/logits_d2h"):
             host = np.asarray(wanted)
@@ -661,14 +715,14 @@ class DecodeEngine:
             prompts = np.zeros((rows, sq), np.int32)
             # warmup exists to GATE on both programs of every rung
             # before the version takes traffic
-            _, kv.k, kv.v, _ = pre(
-                servable.params, servable.state, kv.k, kv.v, prompts,
+            _, kv.entries, _ = pre(
+                servable.params, servable.state, kv.entries, prompts,
                 np.ones((rows,), np.int32),
                 np.full((rows,), self.slots, np.int32),
                 np.zeros((rows,), np.int32))
             dec = self.decode_program(servable, rung)
-            out, kv.k, kv.v, *_ = dec(
-                servable.params, servable.state, kv.k, kv.v, dec_tokens,
+            out, kv.entries, *_ = dec(
+                servable.params, servable.state, kv.entries, dec_tokens,
                 dec_pos, inactive)
             jax.block_until_ready(out)  # bigdl: disable=sync-in-loop
         return self.compile_count(servable) - before

@@ -1,11 +1,12 @@
 """Preallocated, shape-bucketed KV cache + host-side slot accounting.
 
 The decode engine's whole memory story is ONE allocation per model
-version: per layer one K and one V array ``[slots, heads, head_dim,
-columns]`` (``columns`` is ``max_len``, already padded to the top rung
-of the service's length ladder, or a sliding-window layer's ring), an
-explicit per-slot ``lengths`` vector, and
-a host-side alloc/free bitmap. Requests *occupy slots* — admission is a
+version: per layer one entry of a declared kind - one K and one V array
+``[slots, heads, head_dim, columns]`` (``columns`` is ``max_len``,
+already padded to the top rung of the service's length ladder, or a
+sliding-window layer's ring), or a recurrent state's named arrays
+``[slots, ...]`` with no time axis, or nothing - an explicit per-slot
+``lengths`` vector, and a host-side alloc/free bitmap. Requests *occupy slots* — admission is a
 bitmap ``alloc()``, eviction a ``free()`` — so continuous batching never
 reshapes or reallocates device memory, which is exactly what keeps the
 decode program count bounded (every step runs at the same
@@ -74,27 +75,61 @@ class SlotAllocator:
         self._free.append(slot)
 
 
+class RecurrentStateError(ValueError):
+    """Raised by a feature that cuts a slot's cache at a position (the
+    prefix cache, speculative verify) when it is handed a model with a
+    recurrent entry: a state has no columns to cut, and a snapshot of
+    it at a position is not built."""
+
+
+def has_recurrent(layout) -> bool:
+    """Whether a ``cache_layout`` names a recurrent entry."""
+    return any(e[0] == "state" for e in layout)
+
+
+def _entry_arrays(entry, dtype) -> dict:
+    """``{name: (shape of one slot's row, dtype)}`` of one layout
+    entry."""
+    if entry[0] == "kv":
+        return {"k": (entry[1:], dtype), "v": (entry[1:], dtype)}
+    if entry[0] == "state":
+        return {name: (shape, dtype if dt is None else np.dtype(dt))
+                for name, shape, dt in entry[1]}
+    return {}
+
+
 class KVCache:
     """One model version's preallocated decode cache.
 
-    ``k``/``v`` are tuples of ``layers`` device arrays threaded
-    (donated) through every prefill/decode program call; ``lengths`` is
-    the explicit host-side int32 vector of per-slot sequence lengths (=
-    the next write position), and ``allocator`` the slot bitmap. A freed
-    slot's columns are NOT zeroed: every position a future occupant can
-    attend is re-written (prompt region by its prefill, each generated
-    position by the decode step that produces it) before the
-    length-masked causal mask ever exposes it.
+    ``entries`` is a tuple of one dict of device arrays a layer,
+    threaded (donated) as ONE pytree through every prefill/decode
+    program call; ``lengths`` is the explicit host-side int32 vector of
+    per-slot sequence lengths (= the next write position), and
+    ``allocator`` the slot bitmap.
 
-    **Entries of a declared kind per layer.** ``layout`` is one ``(kv
-    heads, head dim, columns)`` triple a layer; layer ``i``'s arrays are
-    ``[slots, heads_i, head_dim_i, columns_i]``. A layer that attends
-    its whole prefix keeps ``columns = max_len`` (position ``p`` at
-    column ``p``); a sliding-window layer keeps a RING of ``window``
-    columns (position ``p`` at column ``p mod window``;
-    ``nn.attention.cached_attention`` owns that rule). The layout comes
-    from the model: ``cache_layout(max_len)``, the cache's one entry
-    point in the engine's contract (``generation/engine.py``)."""
+    **Entries of a declared kind per layer.** ``layout`` is one tuple a
+    layer whose first element names the kind (the model declares it:
+    ``cache_layout(max_len)``, the cache's one entry point in the
+    engine's contract, ``generation/engine.py``):
+
+    - ``("kv", kv heads, head dim, columns)``: keys and values with a
+      time axis, ``{"k", "v"}`` each ``[slots, heads, head_dim,
+      columns]``. A layer that attends its whole prefix keeps ``columns
+      = max_len`` (position ``p`` at column ``p``); a sliding-window
+      layer keeps a RING of ``window`` columns (position ``p`` at column
+      ``p mod window``; ``nn.attention.cached_attention`` owns that
+      rule). A freed slot's columns are NOT zeroed: every position a
+      future occupant can attend is re-written (prompt region by its
+      prefill, each generated position by the decode step that produces
+      it) before the length-masked causal mask ever exposes it.
+    - ``("state", ((name, shape, dtype), ...))``: a recurrent state,
+      named arrays ``[slots, *shape]`` without a time axis, each of its
+      own dtype (None: the cache's). It is read and rewritten whole
+      every step and cannot be cut at a position; a slot handed to a new
+      request must start from zero, which the MODEL does (a row at
+      offset 0 drops what the slot held), so nothing is zeroed here
+      either.
+    - ``("none",)``: the layer keeps nothing, ``{}``."""
 
     def __init__(self, slots: int, max_len: int, layout, dtype=None):
         import jax.numpy as jnp
@@ -106,25 +141,46 @@ class KVCache:
         self.dtype = dtype if dtype is not None else Engine.default_dtype()
         self.layout = self._layout(layout, max_len)
         self.layers = len(self.layout)
-        # of the first layer's entry: K/V heads, which a grouped-query
-        # model has fewer of than the query heads it declares
-        self.heads, self.head_dim = self.layout[0][:2]
-        self.k = tuple(jnp.zeros((slots,) + e, self.dtype)
-                       for e in self.layout)
-        self.v = tuple(jnp.zeros((slots,) + e, self.dtype)
-                       for e in self.layout)
+        self.entries = tuple(
+            {name: jnp.zeros((slots,) + shape, dt) for name, (shape, dt)
+             in _entry_arrays(e, self.dtype).items()}
+            for e in self.layout)
+        # the layers that keep a recurrent state, and the bytes ONE
+        # slot's states take over all of them
+        states = [e for e, kind in zip(self.entries, self.layout)
+                  if kind[0] == "state"]
+        self.state_layers = len(states)
+        self.state_slot_bytes = sum(int(a.nbytes) // slots
+                                    for e in states for a in e.values())
         self.lengths = np.zeros((slots,), np.int32)
         self.allocator = SlotAllocator(slots)
 
     @staticmethod
     def _layout(layout, max_len: int) -> tuple:
-        layout = tuple(tuple(int(n) for n in e) for e in layout)
-        if not layout or any(
-                len(e) != 3 or not 1 <= e[2] <= max_len for e in layout):
-            raise ValueError(
-                f"cache layout {layout} does not describe layers of at "
-                f"most {max_len} columns")
-        return layout
+        """The layout as hashable tuples, checked."""
+        out = []
+        for e in layout:
+            kind = e[0] if len(e) else None
+            if kind == "kv" and len(e) == 4 \
+                    and 1 <= int(e[3]) <= max_len:
+                out.append(("kv",) + tuple(int(n) for n in e[1:]))
+            elif kind == "state" and len(e) == 2 and len(e[1]) \
+                    and not {"k", "v"} & {n for n, _, _ in e[1]}:
+                out.append(("state", tuple(
+                    (str(name), tuple(int(n) for n in shape),
+                     None if dt is None else np.dtype(dt).name)
+                    for name, shape, dt in e[1])))
+            elif kind == "none" and len(e) == 1:
+                out.append(("none",))
+            else:
+                raise ValueError(
+                    f"cache layout entry {e!r} is none of ('kv', heads, "
+                    f"head_dim, columns <= {max_len}), ('state', ((name, "
+                    "shape, dtype), ...)) with no array named 'k' or 'v' "
+                    "(the engine cuts those at a column), ('none',)")
+        if not out:
+            raise ValueError("an empty cache layout")
+        return tuple(out)
 
     @classmethod
     def _model_geometry(cls, model, max_len: int) -> tuple:
@@ -157,9 +213,9 @@ class KVCache:
     @classmethod
     def spec_for_model(cls, model, slots: int, max_len: int,
                        dtype=None):
-        """The ``(k, v)`` buffers :meth:`for_model` would allocate
-        (same derivation, same validation), as tuples of ``layers``
-        ``jax.ShapeDtypeStruct`` — nothing touches a device. The
+        """The ``entries`` pytree :meth:`for_model` would allocate
+        (same derivation, same validation) as
+        ``jax.ShapeDtypeStruct`` leaves — nothing touches a device. The
         static program verifier lowers the engine's prefill/decode
         jits over these instead of a live cache."""
         import jax
@@ -169,22 +225,48 @@ class KVCache:
         layout, declared = cls._model_geometry(model, max_len)
         dt = dtype if dtype is not None else (
             declared if declared is not None else Engine.default_dtype())
-        spec = tuple(jax.ShapeDtypeStruct((slots,) + e, dt)
-                     for e in layout)
-        return spec, spec
+        return tuple(
+            {name: jax.ShapeDtypeStruct((slots,) + shape, d)
+             for name, (shape, d) in _entry_arrays(e, dt).items()}
+            for e in layout)
+
+    # ---- views by kind
+    @property
+    def k(self) -> tuple:
+        """The K arrays of the layers that keep keys and values."""
+        return tuple(e["k"] for e in self.entries if "k" in e)
+
+    @property
+    def v(self) -> tuple:
+        """The V arrays of the same layers."""
+        return tuple(e["v"] for e in self.entries if "v" in e)
+
+    @property
+    def kv_layout(self) -> tuple:
+        """``(kv heads, head dim, columns)`` of each K/V layer."""
+        return tuple(e[1:] for e in self.layout if e[0] == "kv")
 
     @property
     def uniform(self) -> bool:
-        """Every layer's entry has the same shape (one kind)."""
-        return len(set(self.layout)) == 1
+        """Every layer keeps keys and values of one shape."""
+        return len(set(self.layout)) == 1 and self.layout[0][0] == "kv"
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer keeps a recurrent state."""
+        return has_recurrent(self.layout)
 
     def kind_bytes(self) -> dict:
         """Device bytes by kind of entry: ``window`` (rings shorter
-        than ``max_len``) and ``global`` (every position kept)."""
-        out = {"window": 0, "global": 0}
-        for e, a, b in zip(self.layout, self.k, self.v):
-            kind = "window" if e[2] < self.max_len else "global"
-            out[kind] += int(a.nbytes) + int(b.nbytes)
+        than ``max_len``), ``global`` (every position kept) and
+        ``state`` (recurrent arrays)."""
+        out = {"window": 0, "global": 0, "state": 0}
+        for kind, e in zip(self.layout, self.entries):
+            if kind[0] == "none":
+                continue
+            name = "state" if kind[0] == "state" else \
+                "window" if kind[3] < self.max_len else "global"
+            out[name] += sum(int(a.nbytes) for a in e.values())
         return out
 
     def occupancy(self) -> float:
@@ -197,11 +279,13 @@ class KVCache:
         return self.lengths[live] if live else np.zeros((0,), np.int32)
 
     def nbytes(self) -> int:
-        """Device bytes held by the K and V buffers."""
-        return sum(int(a.nbytes) for a in self.k + self.v)
+        """Device bytes held by every entry's arrays."""
+        return sum(int(a.nbytes) for e in self.entries
+                   for a in e.values())
 
     def __repr__(self) -> str:
-        return (f"KVCache(L={self.layers} slots={self.slots} "
-                f"H={self.heads} T={self.max_len} D={self.head_dim} "
-                f"{np.dtype(self.dtype).name}, "
+        kinds = {k: sum(1 for e in self.layout if e[0] == k)
+                 for k in ("kv", "state", "none")}
+        return (f"KVCache(L={self.layers} {kinds} slots={self.slots} "
+                f"T={self.max_len} {np.dtype(self.dtype).name}, "
                 f"live={len(self.allocator.live)})")

@@ -32,7 +32,7 @@ import numpy as np
 
 import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu.generation.engine import DecodeEngine
-from bigdl_tpu.generation.kv_cache import KVCache
+from bigdl_tpu.generation.kv_cache import KVCache, RecurrentStateError
 from bigdl_tpu.generation.loop import DecodeLoop
 from bigdl_tpu.generation.sampling import SamplingParams
 from bigdl_tpu.generation.stream import TokenStream
@@ -46,6 +46,13 @@ _G_WINDOW_BYTES = telemetry.gauge(
 _G_GLOBAL_BYTES = telemetry.gauge(
     "serving/cache/global_bytes",
     "device bytes of the newest cache's whole-context entries")
+_G_STATE_BYTES = telemetry.gauge(
+    "serving/cache/state_bytes",
+    "device bytes of the newest cache's recurrent-state entries")
+_G_SLOTS = telemetry.gauge(
+    "serving/cache/slots",
+    "slots of the newest cache: what its bytes by kind divide by for "
+    "the bytes one session holds")
 
 
 @dataclass
@@ -291,6 +298,12 @@ class GenerationService:
         declares; the bytes by kind go to the always-on gauges."""
         kv = KVCache.for_model(servable.model, self.config.slots,
                                self.config.max_len)
+        if self.prefix is not None and kv.recurrent:
+            raise RecurrentStateError(
+                f"{servable.name!r} keeps a recurrent state; the prefix "
+                "cache stores a prompt's columns up to a position and "
+                "would need a snapshot of the state there, which is not "
+                "built (prefix_cache_bytes=0 serves it)")
         if self.prefix is not None and not kv.uniform:
             raise ValueError(
                 f"{servable.name!r} keeps cache entries of several kinds "
@@ -299,6 +312,8 @@ class GenerationService:
         by_kind = kv.kind_bytes()
         _G_WINDOW_BYTES.set(by_kind["window"], model=servable.name)
         _G_GLOBAL_BYTES.set(by_kind["global"], model=servable.name)
+        _G_STATE_BYTES.set(by_kind["state"], model=servable.name)
+        _G_SLOTS.set(kv.slots, model=servable.name)
         return kv
 
     def generate(self, name: str, prompt, *,

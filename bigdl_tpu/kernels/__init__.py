@@ -13,14 +13,17 @@ ONE gate in front of them:
   attention for the generation engine: reads only ``lengths[i]`` valid
   KV per slot instead of the bucket max, and writes the step's new K/V
   column into the cache itself;
+- :mod:`~bigdl_tpu.kernels.ssm_decode` — one decode step of a
+  state-space layer: every slot's recurrent state read once, updated
+  and written back in place;
 - :mod:`~bigdl_tpu.kernels.int8_gemm` — fused dequant-int8-GEMM
   completing the BigQuant serving story over the calibrated scales;
 - :mod:`~bigdl_tpu.kernels.moe_gmm` — the routed expert layer's
   grouped product over sorted, tile-aligned token-expert pairs: an
   expert no pair fell on is never read;
 - :mod:`~bigdl_tpu.kernels.dispatch` — :func:`attention` /
-  :func:`decode_attention` / :func:`int8_matmul` /
-  :func:`grouped_matmul`: config + shape
+  :func:`decode_attention` / :func:`ssm_decode_step` / :func:`int8_matmul`
+  / :func:`grouped_matmul`: config + shape
   eligibility in, kernel result or None (= run your jnp path) out;
 - :mod:`~bigdl_tpu.kernels.config` — :class:`KernelConfig` and the
   ``BIGDL_KERNELS`` env toggle; every kernel default ON on real TPU
@@ -40,8 +43,10 @@ from bigdl_tpu.kernels.config import (KernelConfig, active_label,
                                       configure, enabled, get_config,
                                       interpret_mode, use)
 from bigdl_tpu.kernels.dispatch import (attention, decode_attention,
-                                        grouped_matmul, int8_matmul)
+                                        grouped_matmul, int8_matmul,
+                                        ssm_decode_step)
 
 __all__ = ["KernelConfig", "configure", "get_config", "use", "enabled",
            "interpret_mode", "active_label", "attention",
-           "decode_attention", "int8_matmul", "grouped_matmul"]
+           "decode_attention", "ssm_decode_step", "int8_matmul",
+           "grouped_matmul"]

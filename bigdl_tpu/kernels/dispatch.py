@@ -14,8 +14,9 @@ configuration is byte-identical to the pre-kernel tree.
 Dispatch decisions happen at TRACE time (config and shapes are
 static), so the per-trace counters below count compiled-program
 routing, not per-step calls: ``kernels/dispatch/pallas`` (label
-``op=flash|decode|decode_write|int8|gmm``; ``decode_write`` is a second
-label of the decode kernel, for the cache column it writes) vs
+``op=flash|decode|decode_write|ssm_decode|int8|gmm``; ``decode_write``
+is a second label of the decode kernel, for the cache column it writes)
+vs
 ``kernels/dispatch/reference`` (labels ``op=...`` plus
 ``reason=config|shape|vmem|mesh`` so a `diagnose` dump attributes every
 decline).
@@ -32,7 +33,7 @@ import bigdl_tpu.telemetry as telemetry
 from bigdl_tpu.kernels import config as _config
 from bigdl_tpu.kernels.common import fit_block
 
-__all__ = ["attention", "decode_attention", "int8_matmul",
+__all__ = ["attention", "decode_attention", "ssm_decode_step", "int8_matmul",
            "grouped_matmul", "flash_route", "taken_in_thread",
            "declined_in_thread"]
 
@@ -41,11 +42,12 @@ __all__ = ["attention", "decode_attention", "int8_matmul",
 _C_PALLAS = telemetry.counter(
     "kernels/dispatch/pallas",
     "traces routed to a pallas kernel (label op=flash|decode|"
-    "decode_write|int8|gmm)")
+    "decode_write|ssm_decode|int8|gmm)")
 _C_REFERENCE = telemetry.counter(
     "kernels/dispatch/reference",
     "traces declined by the dispatch layer to the pure-jnp reference "
-    "(labels op=flash|decode|int8, reason=config|shape|vmem|mesh)")
+    "(labels op=flash|decode|ssm_decode|int8|gmm, "
+    "reason=config|shape|vmem|mesh)")
 
 
 # trace-scoped routing evidence: tracing happens on the caller's
@@ -289,6 +291,38 @@ def decode_attention(q, k, v, lengths, *, new_k, new_v, write_at,
         q, k, v, lengths, write_at, new_k, new_v, attend_len=attend_len,
         sm_scale=sm_scale,
         interpret=_config.get_config().resolve_interpret())
+
+
+def ssm_decode_step(state, dec, dtx, bc):
+    """State-space decode dispatch: one token a slot through a Mamba-2
+    layer's recurrent state ``[slots, Hq, N, L]`` float32, with ``dec``
+    / ``dtx`` ``[slots, Hq, L]`` and ``bc [slots, N, 2 G]``
+    (:func:`bigdl_tpu.nn.ssm.decode_operands`). Returns ``(y, state)``
+    from the kernel (:mod:`bigdl_tpu.kernels.ssm_decode` - the state
+    read once and written in place, aliased through) when ``decode`` is
+    enabled (the decode kernels share the switch) and the shapes
+    qualify - compiled: whole lane tiles, ``N`` on whole sublane tiles
+    and a block of rows :func:`~bigdl_tpu.kernels.ssm_decode.state_rows`
+    can cut - else **None**: the caller runs its plain form."""
+    if not _config.enabled("decode"):
+        _declined("ssm_decode", "config")
+        return None
+    from bigdl_tpu.kernels.ssm_decode import ssm_decode_pallas, state_rows
+
+    interpret = _config.get_config().resolve_interpret()
+    groups = bc.shape[-1] // 2
+    ok = (state.ndim == 4 and groups > 0 and state.shape[1] % groups == 0
+          and state.dtype == dec.dtype == dtx.dtype == bc.dtype
+          == jnp.float32)
+    if ok:
+        hq, n, lanes = state.shape[1:]
+        ok = (state_rows(hq, hq // groups, n, lanes) is not None
+              and (interpret or (lanes % 128 == 0 and n % 8 == 0)))
+    if not ok:
+        _declined("ssm_decode", "shape")
+        return None
+    _taken("ssm_decode")
+    return ssm_decode_pallas(state, dec, dtx, bc, interpret=interpret)
 
 
 #: compiled (non-interpret) int8 tiles must fill the MXU: the same

@@ -171,9 +171,9 @@ class TransformerLM(Module):
 
     # ---- what the generation engine asks of a decoder it serves
     def cache_layout(self, max_len: int):
-        """``[(kv heads, head dim, columns), ...]``, one per layer:
-        every layer keeps every position of all its heads."""
-        return [(self.num_heads, self.hidden_size // self.num_heads,
+        """``[("kv", kv heads, head dim, columns), ...]``, one per
+        layer: every layer keeps every position of all its heads."""
+        return [("kv", self.num_heads, self.hidden_size // self.num_heads,
                  max_len)] * self.num_layers
 
     def cache_dtype(self):
@@ -256,8 +256,7 @@ class TransformerLM(Module):
                 x = params["embed"][tokens] + params["pos_embed"][idx]
         keys = (jax.random.split(rng, self.num_layers)
                 if rng is not None else [None] * self.num_layers)
-        new_state = {}
-        new_k, new_v = [], []
+        new_state, new_cache = {}, []
         for i, blk in enumerate(self.blocks):
             if cache is None:
                 # attn_segments only rides along for packed inputs:
@@ -274,16 +273,14 @@ class TransformerLM(Module):
                                   training=training, rng=keys[i],
                                   **mask_kw)
             else:
-                # each layer owns its K/V pair: handed in and collected
+                # each layer owns its entry: handed in and collected
                 # as it is, so nothing of the cache is sliced or stacked
-                x, st, layer_cache = blk.apply(
+                x, st, entry = blk.apply(
                     params[f"block_{i}"], state.get(f"block_{i}", {}), x,
-                    training=training, rng=keys[i],
-                    cache={"k": cache["k"][i], "v": cache["v"][i]},
+                    training=training, rng=keys[i], cache=cache[i],
                     positions=positions, attend_len=attend_len,
                     fresh=fresh)
-                new_k.append(layer_cache["k"])
-                new_v.append(layer_cache["v"])
+                new_cache.append(entry)
             new_state[f"block_{i}"] = st
         if logits_at is not None:
             x = jnp.take_along_axis(
@@ -297,7 +294,7 @@ class TransformerLM(Module):
                 logits = x @ params["lm_head"]
         if cache is None:
             return logits, new_state
-        return logits, new_state, {"k": tuple(new_k), "v": tuple(new_v)}
+        return logits, new_state, tuple(new_cache)
 
     def aux_loss(self, state) -> jnp.ndarray:
         """Total MoE load-balance loss across blocks."""

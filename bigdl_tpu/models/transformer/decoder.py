@@ -1,26 +1,33 @@
 """A decoder-only LM built from a per-layer pattern — for the decoders
-whose layers are not all alike: window and global attention mixed in
-one stack, dense and routed-expert FFNs, each layer's kinds read from a
-configuration.
+whose layers are not all alike: window and global attention and
+state-space mixers in one stack, dense and routed-expert FFNs, each
+layer's kinds read from a configuration.
 
-``pattern`` is one ``(attention kind, ffn kind)`` pair per layer:
-attention ``"window"`` (a query sees the last ``window`` positions; its
-cache entry is a ring of ``window`` columns) or ``"global"`` (the whole
-prefix); ffn ``"dense"`` (the gated form ``(silu(x Wgate) * (x Wup))
-Wdown``) or ``"experts"`` (:class:`bigdl_tpu.nn.moe.MoE`: sigmoid
-router with a stored bias, gated experts, one shared expert, the
-experts held here a slice of the router's width).
+``pattern`` is one ``(mixer kind, ffn kind)`` pair per layer. Mixer:
+``"window"`` (attention; a query sees the last ``window`` positions;
+its cache entry is a ring of ``window`` columns), ``"global"``
+(attention over the whole prefix), ``"ssm"`` (a Mamba-2 mixer,
+:class:`bigdl_tpu.nn.ssm.Mamba2Mixer`, whose entry is a recurrent state
+with no time axis) or ``"none"``. FFN: ``"dense"`` (the gated form
+``(silu(x Wgate) * (x Wup)) Wdown``), ``"experts"``
+(:class:`bigdl_tpu.nn.moe.MoE`: sigmoid router with a stored bias, one
+shared expert, the experts held here a slice of the router's width;
+gated or not, in a latent space or not, by the constructor's
+arguments) or ``"none"``.
 
-Every layer is the sandwich block ``a = x + norm(attn(norm(x)))``,
-``y = a + norm(ffn(norm(a)))`` with RMSNorm,
+``block_style`` says how a layer's parts sit on the residual stream:
+``"sandwich"`` is ``a = x + norm(mixer(norm(x)))``, ``y = a +
+norm(ffn(norm(a)))``; ``"prenorm"`` is ``x + f(norm(x))`` for each part
+the layer has. All norms RMSNorm. Attention is
 :class:`~bigdl_tpu.nn.attention.GroupedQueryAttention` (grouped K/V
-heads, q/k norm, an output gate; rotary positions on the layer kinds
-``rope_layers`` names, none on the others), an embedding scale and an
-untied head. It shares :class:`TransformerLM`'s entry points —
-``apply(params, state, tokens, cache=, positions=, attend_len=)`` — so
-the generation engine serves both through the same programs, and adds
-what a stack of unlike layers must tell the engine: ``cache_layout``
-(each layer's K/V heads, head size and columns) and ``cache_dtype``.
+heads; q/k norm and an output gate unless switched off; rotary
+positions on the layer kinds ``rope_layers`` names, none on the
+others), with an embedding scale and an untied head. It shares
+:class:`TransformerLM`'s entry points — ``apply(params, state, tokens,
+cache=, positions=, attend_len=)`` — so the generation engine serves
+both through the same programs, and adds what a stack of unlike layers
+must tell the engine: ``cache_layout`` (each layer's entry and its
+kind) and ``cache_dtype``.
 """
 from __future__ import annotations
 
@@ -34,10 +41,12 @@ from bigdl_tpu.nn.attention import GroupedQueryAttention
 from bigdl_tpu.nn.moe import MoE, gated_ffn
 from bigdl_tpu.nn.module import Module, adopt_or_init, adopt_state
 from bigdl_tpu.nn.norm import RMSNorm
+from bigdl_tpu.nn.ssm import Mamba2Mixer
 from bigdl_tpu.utils.engine import Engine
 
-_ATTN_KINDS = ("window", "global")
-_FFN_KINDS = ("dense", "experts")
+_MIXER_KINDS = ("window", "global", "ssm", "none")
+_FFN_KINDS = ("dense", "experts", "none")
+_STYLES = ("sandwich", "prenorm")
 
 
 class GatedFeedForward(Module):
@@ -62,54 +71,75 @@ class GatedFeedForward(Module):
 
 
 class PatternBlock(Module):
-    """One sandwich-norm layer of the kinds ``(attn_kind, ffn_kind)``."""
+    """One layer: a mixer (attention or a state-space mixer; params
+    under ``attn`` / ``ssm``), an FFN (``mlp``), or both, each under
+    the norms its ``style`` puts round it."""
 
-    def __init__(self, hidden_size: int, attn: GroupedQueryAttention,
-                 mlp: Module, norm_eps: float):
+    def __init__(self, hidden_size: int, mixer: Optional[Module],
+                 mlp: Optional[Module], norm_eps: float,
+                 style: str = "sandwich"):
         super().__init__()
-        self.attn, self.mlp = attn, mlp
-        self.norms = {n: RMSNorm(hidden_size, eps=norm_eps) for n in
-                      ("norm_in", "norm_post_attn", "norm_pre_mlp",
-                       "norm_post_mlp")}
+        self.mixer, self.mlp, self.style = mixer, mlp, style
+        self.mixer_key = "ssm" if isinstance(mixer, Mamba2Mixer) else "attn"
+        names = []
+        if mixer is not None:
+            names += ["norm_in"] + ["norm_post_attn"] * (style == "sandwich")
+        if mlp is not None:
+            names += ["norm_pre_mlp"] \
+                + ["norm_post_mlp"] * (style == "sandwich")
+        self.norms = {n: RMSNorm(hidden_size, eps=norm_eps) for n in names}
 
     def init(self, rng):
         ks = jax.random.split(rng, 6)
-        p = {"attn": adopt_or_init(self.attn, ks[0]),
-             "mlp": adopt_or_init(self.mlp, ks[1])}
+        p = {}
+        if self.mixer is not None:
+            p[self.mixer_key] = adopt_or_init(self.mixer, ks[0])
+        if self.mlp is not None:
+            p["mlp"] = adopt_or_init(self.mlp, ks[1])
         for k, (n, m) in zip(ks[2:], sorted(self.norms.items())):
             p[n] = adopt_or_init(m, k)
         return p
 
     def initial_state(self):
-        return {"mlp": adopt_state(self.mlp)}
+        return {} if self.mlp is None else {"mlp": adopt_state(self.mlp)}
 
     def _norm(self, params, name, x):
+        if name not in self.norms:        # the prenorm style has no post
+            return x
         with jax.named_scope("norm"):
             return self.norms[name].forward_fn(params[name], x)
 
     def apply(self, params, state, input, *, training=False, rng=None,
               cache=None, positions=None, attend_len=None, valid=None,
               token_mask=None, fresh=False):
-        h = self._norm(params, "norm_in", input)
+        """``cache`` is this layer's entry (``{}`` for a layer that
+        keeps nothing), returned as the third value when given."""
+        a, new_state = input, {}
+        if self.mixer is not None:
+            h = self._norm(params, "norm_in", input)
+            mp = params[self.mixer_key]
+            if cache is None:
+                h = self.mixer.forward_fn(mp, h, training=training)
+            else:
+                h, cache = self.mixer.forward_fn(
+                    mp, h, training=training, cache=cache,
+                    positions=positions, attend_len=attend_len,
+                    valid=valid, fresh=fresh)
+            a = input + self._norm(params, "norm_post_attn", h)
+        y = a
+        if self.mlp is not None:
+            h = self._norm(params, "norm_pre_mlp", a)
+            with jax.named_scope("mlp"):
+                routed = {"token_mask": token_mask} \
+                    if isinstance(self.mlp, MoE) else {}
+                h, mlp_state = self.mlp.apply(params["mlp"],
+                                              state.get("mlp", {}), h,
+                                              training=training, **routed)
+            new_state = {"mlp": mlp_state}
+            y = a + self._norm(params, "norm_post_mlp", h)
         if cache is None:
-            h = self.attn.forward_fn(params["attn"], h, training=training)
-        else:
-            h, cache = self.attn.forward_fn(
-                params["attn"], h, training=training, cache=cache,
-                positions=positions, attend_len=attend_len, valid=valid,
-                fresh=fresh)
-        a = input + self._norm(params, "norm_post_attn", h)
-        h = self._norm(params, "norm_pre_mlp", a)
-        with jax.named_scope("mlp"):
-            routed = {"token_mask": token_mask} \
-                if isinstance(self.mlp, MoE) else {}
-            h, mlp_state = self.mlp.apply(params["mlp"],
-                                          state.get("mlp", {}), h,
-                                          training=training, **routed)
-        y = a + self._norm(params, "norm_post_mlp", h)
-        if cache is None:
-            return y, {"mlp": mlp_state}
-        return y, {"mlp": mlp_state}, cache
+            return y, new_state
+        return y, new_state, cache
 
 
 class PatternDecoderLM(Module):
@@ -141,14 +171,22 @@ class PatternDecoderLM(Module):
                  router_experts: int = 0,
                  local_experts: Optional[Tuple[int, int]] = None,
                  top_k: int = 2, route_scale: float = 1.0,
-                 route_norm: bool = True, embed_scale: float = 1.0):
+                 route_norm: bool = True, embed_scale: float = 1.0,
+                 block_style: str = "sandwich", qk_norm: bool = True,
+                 attn_gate: bool = True, expert_activation: str = "silu",
+                 expert_gated: bool = True, latent_size: int = 0,
+                 ssm: Optional[dict] = None):
         super().__init__()
         if rope_layers not in ("window", "global", "all", "none"):
             raise ValueError(f"rope_layers={rope_layers!r}")
+        if block_style not in _STYLES:
+            raise ValueError(f"block_style={block_style!r}: {_STYLES}")
         for a, f in pattern:
-            if a not in _ATTN_KINDS or f not in _FFN_KINDS:
-                raise ValueError(f"layer kinds ({a!r}, {f!r}): attention "
-                                 f"{_ATTN_KINDS}, ffn {_FFN_KINDS}")
+            if (a not in _MIXER_KINDS or f not in _FFN_KINDS
+                    or a == f == "none"):
+                raise ValueError(f"layer kinds ({a!r}, {f!r}): mixer "
+                                 f"{_MIXER_KINDS}, ffn {_FFN_KINDS}, "
+                                 "at least one of them")
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.pattern = [tuple(p) for p in pattern]
@@ -162,37 +200,55 @@ class PatternDecoderLM(Module):
         offset, held = local_experts or (0, router_experts)
         self.blocks = []
         for a, f in self.pattern:
-            rope = rope_layers == "all" or rope_layers == a
-            attn = GroupedQueryAttention(
-                hidden_size, num_heads, num_kv_heads, head_dim,
-                window=window if a == "window" else None,
-                rope_theta=rope_theta if rope else None,
-                norm_eps=norm_eps)
+            mixer = mlp = None
+            if a == "ssm":
+                mixer = Mamba2Mixer(hidden_size, norm_eps=norm_eps,
+                                    **(ssm or {}))
+            elif a != "none":
+                rope = rope_layers == "all" or rope_layers == a
+                mixer = GroupedQueryAttention(
+                    hidden_size, num_heads, num_kv_heads, head_dim,
+                    window=window if a == "window" else None,
+                    rope_theta=rope_theta if rope else None,
+                    qk_norm=qk_norm, gate=attn_gate, norm_eps=norm_eps)
             if f == "experts":
-                mlp = MoE(hidden_size, expert_size, held, top_k, "silu",
-                          gated=True, scoring="sigmoid",
+                mlp = MoE(hidden_size, expert_size, held, top_k,
+                          expert_activation, gated=expert_gated,
+                          scoring="sigmoid",
                           router_experts=router_experts,
                           expert_offset=offset, router_bias=True,
                           route_norm=route_norm, route_scale=route_scale,
-                          shared_size=shared_size)
-            else:
+                          shared_size=shared_size, latent_size=latent_size)
+            elif f == "dense":
                 mlp = GatedFeedForward(hidden_size, ffn_size)
-            self.blocks.append(PatternBlock(hidden_size, attn, mlp,
-                                            norm_eps))
+            self.blocks.append(PatternBlock(hidden_size, mixer, mlp,
+                                            norm_eps, block_style))
         self.norm_f = RMSNorm(hidden_size, eps=norm_eps)
 
     # ---- what the generation engine asks of a stack of unlike layers
     def cache_layout(self, max_len: int):
-        """``[(kv heads, head dim, columns), ...]``, one per layer: a
-        window layer keeps a ring of ``window`` columns, a global one
-        every position."""
-        return [(self.num_kv_heads, self.head_dim,
-                 blk.attn.cache_columns(max_len)) for blk in self.blocks]
+        """One entry a layer, each naming its kind: ``("kv", kv heads,
+        head dim, columns)`` for attention (a window layer keeps a ring
+        of ``window`` columns, a global one every position),
+        ``("state", ((name, shape, dtype), ...))`` for a state-space
+        mixer's recurrent arrays, ``("none",)`` for a layer that keeps
+        nothing."""
+        out = []
+        for (a, _), blk in zip(self.pattern, self.blocks):
+            if a == "ssm":
+                out.append(("state", blk.mixer.cache_arrays()))
+            elif a == "none":
+                out.append(("none",))
+            else:
+                out.append(("kv", self.num_kv_heads, self.head_dim,
+                            blk.mixer.cache_columns(max_len)))
+        return out
 
     def scoreless_prefill(self, rung: int) -> bool:
-        """Whether every layer can prefill ``rung`` fresh tokens in one
-        shot without materialised attention scores."""
-        return all(blk.attn.scoreless(rung) for blk in self.blocks)
+        """Whether every layer that attends can prefill ``rung`` fresh
+        tokens in one shot without materialised attention scores."""
+        return all(blk.mixer.scoreless(rung) for blk in self.blocks
+                   if blk.mixer_key == "attn" and blk.mixer is not None)
 
     def cache_dtype(self):
         """The cache holds keys and values in the type the loaded
@@ -235,20 +291,18 @@ class PatternDecoderLM(Module):
             token_mask = live[:, None] if token_mask is None \
                 else token_mask & live[:, None]
             token_mask = jnp.broadcast_to(token_mask, tokens.shape)
-        new_state, new_k, new_v = {}, [], []
+        new_state, new_cache = {}, []
         for i, blk in enumerate(self.blocks):
             bp, bs = params[f"block_{i}"], state.get(f"block_{i}", {})
             if cache is None:
                 x, st = blk.apply(bp, bs, x, training=training,
                                   token_mask=token_mask)
             else:
-                x, st, layer_cache = blk.apply(
-                    bp, bs, x, training=training,
-                    cache={"k": cache["k"][i], "v": cache["v"][i]},
+                x, st, entry = blk.apply(
+                    bp, bs, x, training=training, cache=cache[i],
                     positions=positions, attend_len=attend_len,
                     valid=valid, token_mask=token_mask, fresh=fresh)
-                new_k.append(layer_cache["k"])
-                new_v.append(layer_cache["v"])
+                new_cache.append(entry)
             new_state[f"block_{i}"] = st
         if logits_at is not None:
             x = jnp.take_along_axis(
@@ -260,4 +314,4 @@ class PatternDecoderLM(Module):
                              preferred_element_type=jnp.float32)
         if cache is None:
             return logits, new_state
-        return logits, new_state, {"k": tuple(new_k), "v": tuple(new_v)}
+        return logits, new_state, tuple(new_cache)

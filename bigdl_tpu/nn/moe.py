@@ -34,15 +34,18 @@ from bigdl_tpu.utils.engine import Engine
 MOE_STATS_KEY = "moe_stats"
 
 _ACTIVATIONS = {"gelu": jax.nn.gelu, "relu": jax.nn.relu,
-                "silu": jax.nn.silu}
+                "silu": jax.nn.silu,
+                "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def gated_ffn(params, x, activation: str = "silu"):
     """``(act(x Wgate) * (x Wup)) Wdown``, no biases: the dense FFN of
-    a gated decoder and an expert layer's shared expert."""
+    a gated decoder and an expert layer's shared expert. Without a
+    ``w_gate`` leaf the plain form ``act(x Wup) Wdown``."""
     act = _ACTIVATIONS[activation]
-    return (act(x @ params["w_gate"]) * (x @ params["w_up"])) \
-        @ params["w_down"]
+    up = x @ params["w_up"]
+    hid = act(x @ params["w_gate"]) * up if "w_gate" in params else act(up)
+    return hid @ params["w_down"]
 
 
 def _pow2_at_least(n: int) -> int:
@@ -149,8 +152,12 @@ class MoE(Module):
     ``scoring="sigmoid"`` (independent scores in float32; chosen by
     ``score + router_bias``, weighted by the score alone, normalised
     when ``route_norm``, times ``route_scale``). ``gated`` experts are
-    ``(act(x Wgate) * (x Wup)) Wdown``; ``shared_size`` adds one gated
-    FFN of that width every token passes through.
+    ``(act(x Wgate) * (x Wup)) Wdown``, the others ``act(x Wup) Wdown``;
+    ``shared_size`` adds one FFN of that width, of the experts' form,
+    that every token passes through. ``latent_size``: the routed experts
+    live in a narrower space between two shared projections, ``(sum_j
+    w_j expert_j(x W_lat_in)) W_lat_out`` with experts ``[latent, f]`` /
+    ``[f, latent]``; the router and the shared expert read ``x`` itself.
 
     The load-balancing loss (Switch-style) is stored in the state pytree
     under the reserved ``AUX_LOSS_KEY`` leaf so the training loop adds
@@ -162,8 +169,12 @@ class MoE(Module):
                  gated: bool = False, scoring: str = "softmax",
                  router_experts: int = None, expert_offset: int = 0,
                  router_bias: bool = False, route_norm: bool = True,
-                 route_scale: float = 1.0, shared_size: int = 0):
+                 route_scale: float = 1.0, shared_size: int = 0,
+                 latent_size: int = 0):
         super().__init__()
+        if activation not in _ACTIVATIONS:
+            raise ValueError(f"activation {activation!r}: one of "
+                             f"{sorted(_ACTIVATIONS)}")
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring must be softmax|sigmoid, "
                              f"got {scoring}")
@@ -184,6 +195,7 @@ class MoE(Module):
         self.route_norm = route_norm
         self.route_scale = route_scale
         self.shared_size = shared_size
+        self.latent_size = latent_size
 
     def init(self, rng):
         dtype = Engine.default_dtype()
@@ -191,28 +203,38 @@ class MoE(Module):
         s_in = 1.0 / math.sqrt(self.hidden_size)
         s_ffn = 1.0 / math.sqrt(self.ffn_size)
         e, h, f = self.num_experts, self.hidden_size, self.ffn_size
+        # the width the experts read and write
+        w = self.latent_size or h
+        s_w = 1.0 / math.sqrt(w)
         p = {
             "router": jax.random.uniform(
                 k1, (h, self.router_experts), dtype, -s_in, s_in),
-            "w_up": jax.random.uniform(k2, (e, h, f), dtype, -s_in, s_in),
-            "w_down": jax.random.uniform(k3, (e, f, h), dtype,
+            "w_up": jax.random.uniform(k2, (e, w, f), dtype, -s_w, s_w),
+            "w_down": jax.random.uniform(k3, (e, f, w), dtype,
                                          -s_ffn, s_ffn),
         }
         if self.gated:
-            p["w_gate"] = jax.random.uniform(k4, (e, h, f), dtype,
-                                             -s_in, s_in)
+            p["w_gate"] = jax.random.uniform(k4, (e, w, f), dtype,
+                                             -s_w, s_w)
         if self.router_bias:
             p["router_bias"] = jnp.zeros((self.router_experts,), dtype)
         if self.shared_size:
             ks = jax.random.split(k5, 3)
             g, s_sh = self.shared_size, 1.0 / math.sqrt(self.shared_size)
             p["shared"] = {
-                "w_gate": jax.random.uniform(ks[0], (h, g), dtype,
-                                             -s_in, s_in),
                 "w_up": jax.random.uniform(ks[1], (h, g), dtype,
                                            -s_in, s_in),
                 "w_down": jax.random.uniform(ks[2], (g, h), dtype,
                                              -s_sh, s_sh)}
+            if self.gated:
+                p["shared"]["w_gate"] = jax.random.uniform(
+                    ks[0], (h, g), dtype, -s_in, s_in)
+        if self.latent_size:
+            kl = jax.random.split(jax.random.fold_in(k5, 1), 2)
+            p["w_lat_in"] = jax.random.uniform(kl[0], (h, w), dtype,
+                                               -s_in, s_in)
+            p["w_lat_out"] = jax.random.uniform(kl[1], (w, h), dtype,
+                                                -s_w, s_w)
         return p
 
     def initial_state(self):
@@ -258,10 +280,17 @@ class MoE(Module):
                 # an id past the router's width is held by no one
                 idx = jnp.where(token_mask.reshape(b * s, 1), idx,
                                 self.router_experts)
+        xe = x
+        if "w_lat_in" in params:
+            with jax.named_scope("moe/latent"):
+                xe = x @ params["w_lat_in"]
         out, stats = routed_experts(
-            params, x, idx, w, offset=self.expert_offset,
+            params, xe, idx, w, offset=self.expert_offset,
             router_experts=self.router_experts,
             activation=self.activation)
+        if "w_lat_out" in params:
+            with jax.named_scope("moe/latent"):
+                out = out @ params["w_lat_out"]
         if "shared" in params:
             with jax.named_scope("moe/shared"):
                 out = out + gated_ffn(params["shared"], x, self.activation)

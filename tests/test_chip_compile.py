@@ -382,7 +382,7 @@ def _compile_program(lm, one_chip, slots, kind="decode"):
         before = kernels.dispatch.taken_in_thread()
         compiled = jitted.lower(*args).compile()
         taken = kernels.dispatch.taken_in_thread() - before
-    return compiled, jax.tree.leaves(args[2:4]), taken
+    return compiled, jax.tree.leaves(args[2]), taken
 
 
 def test_decode_step_holds_the_kernel(one_chip, lm):
@@ -689,24 +689,23 @@ def pattern_programs(one_chip):
     engine = DecodeEngine(CompileCache(),
                           BucketLadder(PD["max_len"], PD["rungs"]),
                           PD["slots"], 4)
-    k_spec, v_spec = KVCache.spec_for_model(model, PD["slots"],
-                                            PD["max_len"])
+    spec = KVCache.spec_for_model(model, PD["slots"], PD["max_len"])
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape,
                                                     np.dtype(dtype))
     out = {"pieces": engine.prefill_shape(model, 4096),   # interpreting
-           "cache": k_spec + v_spec}
+           "cache": tuple(e[n] for n in "kv" for e in spec)}
     with kernels.use(KernelConfig(decode_attention=True, int8_matmul=True,
                                   grouped_matmul=True, interpret=False)):
         rows, chunk = out["shape"] = engine.prefill_shape(model, 4096)
         calls = {
             "prefill": (engine._prefill_jit(model, 4096, lambda: None,
                                             chunk == 4096),
-                        (params, state, k_spec, v_spec,
+                        (params, state, spec,
                          sds((rows, chunk), "int32"),
                          sds((rows,), "int32"), sds((rows,), "int32"),
                          sds((rows,), "int32"))),
             "decode": (engine._decode_jit(model, 6144, lambda: None),
-                       (params, state, k_spec, v_spec,
+                       (params, state, spec,
                         sds((PD["slots"],), "int32"),
                         sds((PD["slots"],), "int32"),
                         sds((PD["slots"],), "bool")))}
@@ -801,3 +800,127 @@ def test_pattern_prefill_holds_no_scores_and_one_row_of_logits(
     assert not big, big
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 << 30
+
+
+# ------------------------------------- the state-space decode step
+
+@pytest.mark.parametrize("slots,hq,n,lanes,groups", [
+    (128, 64, 128, 128, 8),      # the Nemotron cell's layer
+    (16, 8, 64, 128, 1)])        # one group, all rows a program
+def test_ssm_decode_compiles(one_chip, slots, hq, n, lanes, groups):
+    """``bigdl_ssm_decode`` at the serve cell's state ``[128, 64, 128,
+    128]`` float32 (two heads a lane tile): the state aliased to its
+    output, and a block of rows that fits the kernel's VMEM."""
+    from bigdl_tpu.kernels.ssm_decode import ssm_decode_pallas
+
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,
+                                          sharding=one_chip)
+    compiled = ssm_decode_pallas.lower(
+        sds(slots, hq, n, lanes), sds(slots, hq, lanes),
+        sds(slots, hq, lanes), sds(slots, n, 2 * groups)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "bigdl_ssm_decode" in text
+    assert "output_to_operand_aliasing={{1}: (0, {})}" in text
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(one_chip):
+    """The Nemotron cell's decode step (128 slots, rung 4096) and its
+    one-shot prefill of ``[1, 2048]``, at the configuration's full
+    widths and eleven layers, compiled at the policy a TPU gets."""
+    import json
+    import os
+    import sys
+
+    from bigdl_tpu import kernels
+    from bigdl_tpu.generation.engine import DecodeEngine
+    from bigdl_tpu.generation.kv_cache import KVCache
+    from bigdl_tpu.kernels import KernelConfig
+    from bigdl_tpu.serving.compile_cache import (BucketLadder,
+                                                 CompileCache)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.models import nemotron_h
+
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "nemotron3-super-ep4.json")) as f:
+        cfg = json.load(f)
+    slots = 128
+    model = nemotron_h.build_program_model(cfg).evaluate()
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    model.set_parameters(params)
+    state = jax.eval_shape(model.initial_state)
+    engine = DecodeEngine(CompileCache(), BucketLadder(4096, [2048, 4096]),
+                          slots, 4)
+    spec = KVCache.spec_for_model(model, slots, 4096)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape,
+                                                    np.dtype(dtype))
+    out = {"spec": spec}
+    with kernels.use(KernelConfig.all_on(interpret=False)):
+        rows, chunk = out["shape"] = engine.prefill_shape(model, 2048)
+        calls = {
+            "prefill": (engine._prefill_jit(model, 2048, lambda: None,
+                                            chunk == 2048),
+                        (params, state, spec, sds((rows, chunk), "int32"),
+                         sds((rows,), "int32"), sds((rows,), "int32"),
+                         sds((rows,), "int32"))),
+            "decode": (engine._decode_jit(model, 4096, lambda: None),
+                       (params, state, spec, sds((slots,), "int32"),
+                        sds((slots,), "int32"), sds((slots,), "bool")))}
+        for name, (jitted, args) in calls.items():
+            args = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            out[name] = jitted.lower(*args).compile()
+    return out
+
+
+def test_hybrid_decode_step_updates_the_state_in_place(hybrid_programs):
+    """Five ``[128, 64, 128, 128]`` float32 states, five convolution
+    tails and one K/V entry in ONE donated pytree: the compiled step
+    aliases all of it, keeps temporaries under a tenth of one layer's
+    state, holds the three kernels (5 state updates, 1 attention, 5 x 2
+    grouped products) and nothing but the kernel produces an array of a
+    state's shape - no copy, no select over 5.4 GB."""
+    from bigdl_tpu.analysis.hlo import parse_hlo
+
+    compiled, spec = hybrid_programs["decode"], hybrid_programs["spec"]
+    leaves = jax.tree.leaves(spec)
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in leaves)
+    state_bytes = 128 * 64 * 128 * 128 * 4
+    assert [sorted(e) for e in spec] == [
+        ["conv", "ssm"], [], ["conv", "ssm"], [], ["conv", "ssm"], [],
+        ["conv", "ssm"], ["k", "v"], [], ["conv", "ssm"], []]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 10, \
+        mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 16
+    for name in ("bigdl_ssm_decode", "bigdl_ragged_decode",
+                 "bigdl_moe_gmm"):
+        assert name in text
+    module = parse_hlo(text)
+    made = [(op.name, op.opcode) for _, op in module.find_ops()
+            if "f32[128,64,128,128]" in op.result_type.split("{")[0]
+            and op.opcode not in ("parameter", "custom-call", "tuple",
+                                  "get-tuple-element")]
+    assert not made, made
+
+
+def test_hybrid_programs_fit_the_chip(hybrid_programs):
+    """Weights 9.3 GB + cache 3.26 GB + the larger program's
+    temporaries stay inside 16 GiB; the prefill is one row in one shot
+    and attends through the flash kernel."""
+    assert hybrid_programs["shape"] == (1, 2048)
+    text = hybrid_programs["prefill"].as_text()
+    assert "flash_attention" in text and "bigdl_moe_gmm" in text
+    worst = max(m.argument_size_in_bytes + m.temp_size_in_bytes
+                for m in (hybrid_programs[k].memory_analysis()
+                          for k in ("prefill", "decode")))
+    assert worst < 14.5e9, worst

@@ -97,9 +97,10 @@ def test_kv_cache_geometry_and_occupancy():
     assert [a.shape for a in kv.k] == [(4, 4, 8, 16)] * 2
     assert [a.shape for a in kv.v] == [(4, 4, 8, 16)] * 2
     assert kv.nbytes() == 2 * 2 * 4 * 4 * 8 * 16 * 4
-    k_spec, v_spec = KVCache.spec_for_model(m, slots=4, max_len=16)
-    assert [(a.shape, a.dtype) for a in k_spec + v_spec] \
-        == [(a.shape, a.dtype) for a in kv.k + kv.v]
+    import jax
+    spec = KVCache.spec_for_model(m, slots=4, max_len=16)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), spec) \
+        == jax.tree.map(lambda a: (a.shape, a.dtype), kv.entries)
     assert kv.lengths.tolist() == [0, 0, 0, 0]
     assert kv.occupancy() == 0.0
     kv.allocator.alloc()
@@ -483,7 +484,7 @@ def test_decode_step_records_its_cache_columns(attend_len, positions,
     from bigdl_tpu.generation.kv_cache import KVCache
     from bigdl_tpu.kernels.ragged_decode import block_columns, kv_tile
 
-    layout = [(2, 16, 4)] * 2 + [(2, 16, 512)] * 2
+    layout = [("kv", 2, 16, 4)] * 2 + [("kv", 2, 16, 512)] * 2
     kv = KVCache(4, 512, layout, dtype="float32")
     model = SimpleNamespace(num_heads=4)
     positions = np.asarray(positions, np.int32)

@@ -164,7 +164,8 @@ def test_prefill_then_decode_through_both_kinds_of_cache(cut, policy):
     assert [a.shape for a in kv.k] == [(4, 2, 16, 8)] * 4 \
         + [(4, 2, 16, 32)]
     assert kv.kind_bytes() == {"window": 4 * 2 * 4 * 2 * 16 * 8 * 4,
-                               "global": 2 * 4 * 2 * 16 * 32 * 4}
+                               "global": 2 * 4 * 2 * 16 * 32 * 4,
+                               "state": 0}
     for g, r in zip(got, _reference_rows(cfg, prompts, feed, 13)):
         np.testing.assert_allclose(g, r, atol=ATOL, rtol=0)
 
@@ -281,14 +282,30 @@ def _tiny_gpt():
     return model
 
 
-@pytest.mark.parametrize("family", ["gpt2", "pattern"])
+def _tiny_hybrid():
+    """State-space layers, latent experts and an attention layer, one
+    mixer or one FFN a layer (``tests/test_hybrid_decoder.py``)."""
+    import json
+
+    from benchmarks.models import nemotron_h
+
+    with open(os.path.join(ROOT, "benchmarks", "tests", "tiny", "configs",
+                           "tiny-nemotron-h.json")) as f:
+        cfg = json.load(f)
+    model = nemotron_h.build_program_model(cfg).evaluate()
+    model.set_parameters(nemotron_h.make_program_params(cfg, SEED))
+    return model
+
+
+@pytest.mark.parametrize("family", ["gpt2", "pattern", "hybrid"])
 def test_a_decoder_is_served_on_the_written_contract_alone(family):
     """``KVCache.for_model`` and the engine's three programs ask a
     model for what the contract lists and nothing else: each family,
     shown through :class:`_ContractOnly`, loads and serves the tokens
     it serves unwrapped; without ``cache_layout`` the load fails with
     the plain ``AttributeError`` that names it."""
-    model = _tiny_gpt() if family == "gpt2" else build(tiny())
+    model = {"gpt2": _tiny_gpt, "hybrid": _tiny_hybrid,
+             "pattern": lambda: build(tiny())}[family]()
     prompt = np.random.RandomState(4).randint(0, 64, 11).astype(np.int32)
 
     def served(decoder):

@@ -318,6 +318,48 @@ def test_scopes_name_roles_not_layers(train_step_text, serving_programs):
         assert not any("block_" in p for p in _op_names(text))
 
 
+# ---------------------------------------- a hybrid decoder's scopes
+
+HYBRID_SCOPES = ("ssm/in_proj", "ssm/conv", "ssm/scan", "ssm/gate_norm",
+                 "ssm/out_proj", "moe/latent")
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs():
+    """The lowered prefill and decode of a tiny decoder with a
+    state-space layer, latent experts and an attention layer."""
+    from bigdl_tpu.generation.engine import DecodeEngine
+    from bigdl_tpu.models import PatternDecoderLM
+    from bigdl_tpu.serving.compile_cache import BucketLadder, CompileCache
+
+    RandomGenerator.set_seed(3)
+    model = PatternDecoderLM(
+        64, 32, [("ssm", "none"), ("none", "experts"), ("global", "none")],
+        2, 1, 16, 0, window=0, max_len=16, rope_layers="none",
+        expert_size=24, shared_size=40, router_experts=4, top_k=2,
+        block_style="prenorm", qk_norm=False, attn_gate=False,
+        expert_activation="relu2", expert_gated=False, latent_size=16,
+        ssm=dict(num_heads=2, head_dim=8, state_size=8, groups=1,
+                 chunk=4)).evaluate()
+    engine = DecodeEngine(CompileCache(), BucketLadder(16), 2, 1)
+    programs = engine.abstract_programs(
+        model, model.get_parameters(), model.get_state())
+    return {name.split("/")[0]: jitted.lower(*args).as_text(debug_info=True)
+            for name, jitted, args in programs}
+
+
+@pytest.mark.parametrize("scope", HYBRID_SCOPES)
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_a_hybrid_decoder_holds_its_scopes(hybrid_programs, kind, scope):
+    paths = _op_names(hybrid_programs[kind])
+    assert any(p.startswith(f"jit(serving_{kind})/")
+               and f"/{scope}/" in p for p in paths), scope
+
+
+def test_a_recurrent_model_has_no_verify_program(hybrid_programs):
+    assert sorted(hybrid_programs) == ["decode", "prefill"]
+
+
 # ------------------------------------------------------- kernel names
 
 def _pallas_names(jaxpr):
@@ -347,6 +389,7 @@ def _kernel_cases():
         blockwise_flash_attention, flash_attention)
     from bigdl_tpu.kernels.int8_gemm import pallas_quantized_matmul
     from bigdl_tpu.kernels.ragged_decode import ragged_decode_attention
+    from bigdl_tpu.kernels.ssm_decode import ssm_decode_pallas
 
     def grad_of(attn):
         return jax.grad(lambda q, k, v: attn(q, k, v).sum(),
@@ -366,6 +409,12 @@ def _kernel_cases():
                 interpret=True),
             (jnp.zeros((2, 2, 8)), cache, cache),
             {"bigdl_ragged_decode"}),
+        "ssm_decode": (
+            lambda state, dec, dtx, bc: ssm_decode_pallas(
+                state, dec, dtx, bc, interpret=True),
+            (jnp.zeros((2, 2, 8, 32)), jnp.ones((2, 2, 32)),
+             jnp.ones((2, 2, 32)), jnp.ones((2, 8, 4))),
+            {"bigdl_ssm_decode"}),
         "int8_gemm": (
             lambda x, w, xs, ws: pallas_quantized_matmul(
                 x, w, xs, ws, interpret=True),
@@ -385,7 +434,7 @@ def _kernel_cases():
 
 
 @pytest.mark.parametrize("kernel", [
-    "ragged_decode", "int8_gemm", "flash_fwd", "flash_grad",
+    "ragged_decode", "ssm_decode", "int8_gemm", "flash_fwd", "flash_grad",
     "blockwise_fwd", "blockwise_grad"])
 def test_every_pallas_call_has_its_name(kernel):
     fn, args, names = _kernel_cases()[kernel]
@@ -394,8 +443,9 @@ def test_every_pallas_call_has_its_name(kernel):
 
 
 def test_no_pallas_call_site_is_left_unnamed():
-    """The walk above meets eight sites (the eighth: the expert layer's
-    ``bigdl_moe_gmm``); a ninth added to ``kernels/`` without a
+    """The walk above meets nine sites (the eighth: the expert layer's
+    ``bigdl_moe_gmm``; the ninth: the state-space decode step's
+    ``bigdl_ssm_decode``); a tenth added to ``kernels/`` without a
     ``name=`` shows here."""
     import ast
 
@@ -412,7 +462,7 @@ def test_no_pallas_call_site_is_left_unnamed():
                 assert named and named[0].startswith("bigdl_"), \
                     f"{path}:{node.lineno}: pallas_call without name="
                 sites.append(named[0])
-    assert len(sites) == len(set(sites)) == 8
+    assert len(sites) == len(set(sites)) == 9
 
 
 # -------------------------------------------------- the train window
