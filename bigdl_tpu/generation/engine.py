@@ -22,8 +22,15 @@ model declared — keys and values ``{"k", "v"}`` ``[slots, heads,
 head_dim, columns]`` (time last: ``head_dim`` 64 on the lanes would pad
 2x), a recurrent state's named arrays ``[slots, ...]`` with no time
 axis, or ``{}`` — donated and written in place: a decode step holds no
-copy of it. A prefill gathers its rows' entries by slot id and scatters
-them back (the column slice only where an entry has columns).
+copy of it. A prefill scatters its rows' entries back by slot id (the
+column slice only where an entry has columns). What it hands the model
+depends on the call: a chunked call (a ``prefill_chunk`` piece, a rung
+the engine cut into pieces itself, a start past a seeded prefix) gathers
+the rows from their slots, because chunk ``c`` attends what chunks
+``0..c-1`` wrote; a one-shot (``fresh``) prefill reads NOTHING of the
+cache and makes its rows in the program, zeros of the gathered rows'
+shapes (nothing attends what the slot held, and a gather of four rows
+cost a copy of every layer's whole K and V).
 
 **A prefill's shape follows from the model.** A rung whose one-shot
 ``[prefill_rows, heads, rung, rung]`` float32 scores would pass
@@ -191,6 +198,28 @@ def _record_kv(model, kv: KVCache, positions, active,
               "kernel_written_columns": len(c) * kernel_layers})
 
 
+def _record_prefill_kv(kv: KVCache, rows: int, attend_len: int,
+                       fresh: bool) -> None:
+    """With the span tracer on, one prefill call's rows into a ring
+    record (``serving/prefill/kv``): ``rows_bytes``, the bytes of the
+    call's ``rows`` rows over every entry as the model is handed them
+    (K/V cut to the rung's columns, a state whole: :func:`_gather`'s
+    shapes), and ``unread_bytes``, those of them the program made
+    itself instead of reading them from the cache - all of them for a
+    ``fresh`` program, none for a chunk. Host arithmetic on shapes
+    only."""
+    if not telemetry.enabled():
+        return
+    rows_bytes = rows * sum(
+        a.nbytes // kv.slots // a.shape[3] * min(attend_len, a.shape[3])
+        if n in ("k", "v") else a.nbytes // kv.slots
+        for e in kv.entries for n, a in e.items())
+    telemetry.tracer().record(
+        "serving/prefill/kv", 0.0,
+        args={"rows_bytes": rows_bytes,
+              "unread_bytes": rows_bytes if fresh else 0})
+
+
 def _record_ssm(kv: KVCache, rows: int, kind: str) -> None:
     """With the span tracer on, one program call's recurrent-state
     traffic into a ring record (``serving/ssm/step``): ``slot_layers``,
@@ -212,6 +241,17 @@ def _gather(cache, ids, attend_len: int):
     return tuple(
         {n: a[ids, :, :, :min(attend_len, a.shape[3])] if n in ("k", "v")
          else a[ids] for n, a in e.items()} for e in cache)
+
+
+def _blank_rows(cache, ids, attend_len: int):
+    """Zeros of :func:`_gather`'s shapes and dtypes: the rows of a
+    ``fresh`` prefill, which reads nothing of the cache."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda c, i: _gather(c, i, attend_len), cache, ids))
 
 
 def _scatter(cache, ids, rows, attend_len: int):
@@ -305,16 +345,21 @@ class DecodeEngine:
 
         Offset-aware: ``tokens [Bp, Sq]`` is one CHUNK of each row's
         prompt, placed at per-row cache position ``offsets`` with
-        attention over the first ``attend_len`` cache lanes — each
-        row's earlier chunks are gathered from its slot's cache rows,
-        so chunk ``c`` attends everything chunks ``0..c-1`` wrote.
-        Single-shot prefill is the ``offsets == 0, Sq == attend_len``
-        special case: every attended lane is written by the chunk
-        itself (the causal mask covers the rest), so the gathered
-        stale lanes — exactly like the zero rows the pre-chunking
-        program fed — contribute exact zeros to the softmax. ``fresh``
-        says so to the model: the new tokens attend only each other,
-        through a flash kernel where one fits."""
+        attention over the first ``attend_len`` cache lanes. In a
+        chunked call (not ``fresh``) each row's earlier chunks are
+        gathered from its slot's cache rows, so chunk ``c`` attends
+        everything chunks ``0..c-1`` wrote. Single-shot prefill is the
+        ``offsets == 0, Sq == attend_len`` special case, and ``fresh``
+        (static) says so, to the model - the new tokens attend only
+        each other, through a flash kernel where one fits - and to
+        this program, which then reads NOTHING of the cache: every
+        column the model is handed it overwrites or leaves past the
+        row's length, and a recurrent entry starts from zero at offset
+        0, so the rows are zeros made here (:func:`_blank_rows`) and
+        only the scatter touches the donated entries. (A gather of
+        ``prefill_rows`` 4 rows compiled to copies of every layer's
+        whole K and V, 10 of a 26 ms GPT-2 prefill call: PERF.md, PR
+        37.)"""
         import jax
         import jax.numpy as jnp
 
@@ -323,12 +368,14 @@ class DecodeEngine:
             on_trace()
             ids = slot_ids.astype(jnp.int32)
             last_at = last_in_chunk.astype(jnp.int32) - 1
-            # gather each row's slot window, layer by layer, as wide as
-            # the layer's entry reaches into the rung (OOB padding rows
-            # clamp to the last slot; their garbage output is never
-            # read and their write-back below is dropped)
+            # each row's slot window, layer by layer, as wide as the
+            # layer's entry reaches into the rung: gathered for a chunk
+            # (OOB padding rows clamp to the last slot; their garbage
+            # output is never read and their write-back below is
+            # dropped), blank for a one-shot prompt
             with jax.named_scope("attn/kv_write"):
-                rows = _gather(cache, ids, attend_len)
+                rows = (_blank_rows if fresh else _gather)(
+                    cache, ids, attend_len)
             logits, new_state, rows = model.apply(
                 params, state, tokens, training=False, cache=rows,
                 positions=offsets.astype(jnp.int32),
@@ -608,6 +655,7 @@ class DecodeEngine:
                                 and (lens[i] - 1) // sq == c):
                             out[i] = np.asarray(logits[r])
                 _record_moe(stats, "prefill")
+                _record_prefill_kv(kv, rows, bucket, sq == bucket)
                 _record_ssm(kv, int((ids != self.slots).sum()), "prefill")
         for i, slot in enumerate(slot_ids):
             kv.lengths[slot] = lens[i]

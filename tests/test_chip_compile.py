@@ -748,8 +748,8 @@ def test_pattern_decode_step_aliases_both_kinds_of_cache(pattern_programs):
 def gpt2_prefill(one_chip, lm):
     """The GPT-2 serve cell's prefill program (4 rows x rung 1024, 2
     layers of GPT-2-small's widths) at the policy a TPU gets."""
-    compiled, _, taken = _compile_program(lm, one_chip, 64, "prefill")
-    return {"prefill": compiled, "taken": taken}
+    compiled, leaves, taken = _compile_program(lm, one_chip, 64, "prefill")
+    return {"prefill": compiled, "taken": taken, "cache": leaves}
 
 
 @pytest.mark.parametrize("family,rows,vocab,hidden", [
@@ -911,6 +911,53 @@ def test_hybrid_decode_step_updates_the_state_in_place(hybrid_programs):
             and op.opcode not in ("parameter", "custom-call", "tuple",
                                   "get-tuple-element")]
     assert not made, made
+
+
+@pytest.mark.parametrize("family,fixture", [
+    ("gpt2", "gpt2_prefill"), ("pattern", "pattern_programs"),
+    ("hybrid", "hybrid_programs")])
+def test_a_fresh_prefill_holds_no_copy_of_the_cache(family, fixture,
+                                                    request):
+    """A one-shot (``fresh``) prefill reads nothing of the cache, in all
+    three served families: the compiled program aliases every donated
+    leaf to its output, holds no operation under ``kv_write/gather``,
+    and no ``slice``, ``copy``, ``gather`` or ``while`` (alone, as a
+    fusion's root or as an asynchronous pair) results in an array of a
+    layer's slots - only the aliased ``kv_write/scatter`` writes touch
+    an entry. *gpt2* is the case that paid: XLA served the gather of
+    FOUR rows of ``f32[64,12,64,1024]`` by copying each whole layer
+    entry in column pieces (a ``slice-done f32[64,12,64,256]``, a
+    two-output ``f32[64,12,64,384]`` fusion and two ``while`` loops a
+    leaf; 256.8 MB of temporaries). *pattern* and *hybrid* prefill ONE
+    row, which already compiled to a ``dynamic-slice``: they are the
+    guard (no copy of a ``[128, 64, 128, 128]`` state either)."""
+    from bigdl_tpu.analysis.hlo import parse_hlo
+
+    programs = request.getfixturevalue(fixture)
+    compiled = programs["prefill"]
+    leaves = (programs["cache"] if "cache" in programs
+              else jax.tree.leaves(programs["spec"]))
+    if family == "gpt2":
+        assert [a.shape for a in leaves] == [(64, 12, 64, 1024)] * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves)
+    module = parse_hlo(compiled.as_text())
+    ops = [op for _, op in module.find_ops()]
+    gathers = [(op.name, op.opcode) for op in ops
+               if op.metadata.get("op_name", "").endswith("kv_write/gather")]
+    assert not gathers, gathers
+    # an array of a layer's slots: a leaf's shape up to its last axis,
+    # at any width of that (the copies came in column pieces)
+    marks = {"[" + ",".join(map(str, a.shape[:-1])) + "," for a in leaves}
+    moved = [(op.name, _root_opcode(module, op), op.result_type)
+             for op in ops
+             if _root_opcode(module, op).split("-")[0] in (
+                 "slice", "copy", "gather", "while")
+             and any(m in op.result_type for m in marks)]
+    assert not moved, moved
+    if family == "gpt2":
+        assert mem.temp_size_in_bytes < 240e6, mem.temp_size_in_bytes
 
 
 def test_hybrid_programs_fit_the_chip(hybrid_programs):

@@ -604,13 +604,14 @@ def test_the_cell_is_the_issues():
              "moe_pairs_per_expert_max.serve", "gqa_decode_attn_roofline",
              "decode_kv_valid_share.serve",
              "decode_kv_write_in_kernel_share.serve", "ssm_decode_roofline",
-             "ssm_state_gb_per_step.serve")
+             "ssm_state_gb_per_step.serve",
+             "prefill_cache_unread_share.serve")          # PR 37's
     by = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
     listed = {n for n, m in by.items() if cell["name"] in m.get(
         "workloads", ())}
     assert listed == set(lists)
     assert all(by[n]["workloads"][-1] == cell["name"] for n in lists)
-    new = [m for m in bench["per_layer"][-2:]]
+    new = [by["ssm_decode_roofline"], by["ssm_state_gb_per_step.serve"]]
     assert [(m["name"], m["unit"], m["better"], m["source"], m["layer"],
              m["moves"]) for m in new] == [
         ("ssm_decode_roofline", "%", "higher", "device_trace", "kernels",

@@ -94,9 +94,9 @@ def test_full_forward_logits_match_the_reference(held, offset):
 
 # ------------------------------------- prefill, then decode through it
 
-def _engine(rungs, slots=4, rows=2):
+def _engine(rungs, slots=4, rows=2, chunk=None):
     return DecodeEngine(CompileCache(), BucketLadder(rungs[-1], rungs),
-                        slots=slots, prefill_rows=rows)
+                        slots=slots, prefill_rows=rows, prefill_chunk=chunk)
 
 
 def _serve_logits(model, eng, prompts, steps, feed):
@@ -297,15 +297,18 @@ def _tiny_hybrid():
     return model
 
 
-@pytest.mark.parametrize("family", ["gpt2", "pattern", "hybrid"])
+_FAMILIES = {"gpt2": _tiny_gpt, "hybrid": _tiny_hybrid,
+             "pattern": lambda: build(tiny())}
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
 def test_a_decoder_is_served_on_the_written_contract_alone(family):
     """``KVCache.for_model`` and the engine's three programs ask a
     model for what the contract lists and nothing else: each family,
     shown through :class:`_ContractOnly`, loads and serves the tokens
     it serves unwrapped; without ``cache_layout`` the load fails with
     the plain ``AttributeError`` that names it."""
-    model = {"gpt2": _tiny_gpt, "hybrid": _tiny_hybrid,
-             "pattern": lambda: build(tiny())}[family]()
+    model = _FAMILIES[family]()
     prompt = np.random.RandomState(4).randint(0, 64, 11).astype(np.int32)
 
     def served(decoder):
@@ -322,6 +325,167 @@ def test_a_decoder_is_served_on_the_written_contract_alone(family):
     assert len(tokens) == 8 and tokens == served(model)
     with pytest.raises(AttributeError, match="cache_layout"):
         served(_ContractOnly(model, without=("cache_layout",)))
+
+
+# ------------------------- a one-shot prefill reads nothing of the cache
+
+def _leftovers(kv, seed):
+    """Every array of the cache filled with another request's values."""
+    rng = np.random.RandomState(seed)
+    kv.entries = jax.tree.map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+        kv.entries)
+    return jax.tree.map(np.asarray, kv.entries)
+
+
+def _below_length(entries, slot, length):
+    """What a later step may attend of ``slot``: a K/V entry's columns
+    below the row's length (all of a ring the row has filled), a state
+    whole."""
+    return [np.asarray(a[slot, :, :, :min(length, a.shape[3])]
+                       if n in ("k", "v") else a[slot])
+            for e in entries for n, a in sorted(e.items())]
+
+
+def _prefill_then_decode(model, eng, kv, prompt, slot, feed):
+    """One prompt into ``slot`` (the batch's other row is padding), then
+    a decode step a fed token with that slot alone live. Returns the
+    logits of the prefill and of each step, and the cache's host copy
+    right after the prefill."""
+    sv = ModelRegistry().load("m", model)
+    logits, _ = eng.prefill(sv, kv, [prompt], [slot])
+    out = [logits[0]]
+    after_prefill = jax.tree.map(np.asarray, kv.entries)
+    for token in feed:
+        tokens = np.zeros(eng.slots, np.int32)
+        positions = np.zeros(eng.slots, np.int32)
+        active = np.zeros(eng.slots, bool)
+        tokens[slot], positions[slot], active[slot] = (
+            token, kv.lengths[slot], True)
+        logits, _ = eng.decode(sv, kv, tokens, positions, active)
+        kv.lengths[slot] += 1
+        out.append(logits[slot])
+    return np.stack(out), after_prefill
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_a_fresh_prefill_over_leftovers_equals_one_over_zeros(family):
+    """A slot that holds another request's leftovers (here: every array
+    of the cache random) is prefilled in one shot (rung 16, a prompt of
+    11; the second row of the batch is padding, ``slot_ids == slots``)
+    and decoded 8 steps. The prefill's logits, every step's, and the
+    cache below the row's length are BITWISE those of the same run on a
+    cache of zeros: the ``fresh`` program reads nothing of the slot.
+    Right after the prefill the three neighbours - the last among them
+    the slot a padding row's id clamps to - hold their leftovers to the
+    byte."""
+    model = _FAMILIES[family]()
+    rng = np.random.RandomState(6)
+    prompt = rng.randint(0, 64, 11).astype(np.int32)
+    feed = rng.randint(0, 64, 8).astype(np.int32)
+    runs = {}
+    for name in ("zeros", "leftovers"):
+        eng = _engine((16, 32))
+        assert eng.prefill_shape(model, 16) == (2, 16)      # one shot
+        kv = KVCache.for_model(model, eng.slots, 32)
+        before = _leftovers(kv, 7) if name == "leftovers" else None
+        logits, after = _prefill_then_decode(model, eng, kv, prompt, 1,
+                                             feed)
+        runs[name] = (logits, _below_length(kv.entries, 1, 11 + 8))
+        if before is not None:
+            for b, a in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+                np.testing.assert_array_equal(a[[0, 2, 3]], b[[0, 2, 3]])
+                assert not np.array_equal(a[1], b[1])
+    np.testing.assert_array_equal(runs["leftovers"][0], runs["zeros"][0])
+    assert len(runs["zeros"][1]) > 0
+    for got, want in zip(runs["leftovers"][1], runs["zeros"][1]):
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_a_chunked_prefill_still_gathers_and_a_fresh_one_does_not(family):
+    """``prefill_chunk`` 8 at rung 16: the second piece attends what the
+    first wrote, so the program gathers its rows from the slots and the
+    logits are the one-shot program's (over leftovers too: a piece at
+    offset 0 masks what the slot held). Program by program, from the
+    traced equations: the chunked one reads every cache array through a
+    ``gather``; in the ``fresh`` one a cache array is the operand of its
+    ``scatter`` and of nothing else."""
+    model = _FAMILIES[family]()
+    rng = np.random.RandomState(8)
+    prompt = rng.randint(0, 64, 11).astype(np.int32)
+    feed = rng.randint(0, 64, 3).astype(np.int32)
+    logits = {}
+    for name, chunk in (("one_shot", None), ("chunked", 8)):
+        eng = _engine((16, 32), chunk=chunk)
+        assert eng.prefill_shape(model, 16) == (2, chunk or 16)
+        kv = KVCache.for_model(model, eng.slots, 32)
+        _leftovers(kv, 9)
+        logits[name], _ = _prefill_then_decode(model, eng, kv, prompt, 2,
+                                               feed)
+    np.testing.assert_allclose(logits["chunked"], logits["one_shot"],
+                               atol=ATOL, rtol=0)
+
+    spec = KVCache.spec_for_model(model, 4, 32)
+    leaves = len(jax.tree.leaves(spec))
+    rows = jax.ShapeDtypeStruct((2,), np.int32)
+    for fresh, width in ((True, 16), (False, 8)):
+        jaxpr = jax.make_jaxpr(
+            DecodeEngine._prefill_jit(model, 16, lambda: None, fresh))(
+                model.get_parameters(), model.get_state(), spec,
+                jax.ShapeDtypeStruct((2, width), np.int32), rows, rows,
+                rows).jaxpr
+        (call,) = jaxpr.eqns                          # the jit itself
+        body = call.params["jaxpr"].jaxpr
+        first = len(jax.tree.leaves((model.get_parameters(),
+                                     model.get_state())))
+        cache_vars = set(body.invars[first:first + leaves])
+        readers = [e.primitive.name for e in body.eqns
+                   if cache_vars & {v for v in e.invars
+                                    if not hasattr(v, "val")}]
+        if fresh:
+            assert readers == ["scatter"] * leaves, readers
+        else:
+            assert sorted(set(readers)) == ["gather", "scatter"], readers
+            assert readers.count("gather") == leaves
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_a_prefill_call_records_what_it_did_not_read(family):
+    """``serving/prefill/kv``, one ring record a prefill call while the
+    tracer is on: ``rows_bytes``, the program's rows over every entry at
+    the rung's width (a ring at its own), and ``unread_bytes``, all of
+    them for a one-shot call and none for a chunk; nothing with the
+    tracer off."""
+    model = _FAMILIES[family]()
+    prompt = np.arange(11, dtype=np.int32)
+    sv = ModelRegistry().load("m", model)
+
+    def records(chunk):
+        eng = _engine((16, 32), chunk=chunk)
+        kv = KVCache.for_model(model, eng.slots, 32)
+        eng.prefill(sv, kv, [prompt], [0])
+        want = 2 * sum(
+            a.dtype.itemsize * int(np.prod(
+                a.shape[1:3] + (min(16, a.shape[3]),) if n in ("k", "v")
+                else a.shape[1:]))
+            for e in kv.entries for n, a in e.items())
+        return want, [r.args for r in telemetry.tracer().spans()
+                      if r.name == "serving/prefill/kv"]
+
+    telemetry.tracer().clear()
+    assert records(None)[1] == []
+    telemetry.enable()
+    try:
+        want, recs = records(None)
+        assert want > 0
+        assert recs == [{"rows_bytes": want, "unread_bytes": want}]
+        telemetry.tracer().clear()
+        want, recs = records(8)
+        assert recs == [{"rows_bytes": want, "unread_bytes": 0}] * 2
+    finally:
+        telemetry.disable()
+        telemetry.tracer().clear()
 
 
 # ------------------------------------------------------ the expert layer
