@@ -110,19 +110,6 @@ from bigdl_tpu.kernels.ragged_decode import block_columns, kv_tile
 #: tokens x rung x 4`` bytes: past it the engine chunks the rung
 _PREFILL_SCORE_BYTES = 512 << 20
 
-_H_TOUCHED = telemetry.histogram(
-    "serving/moe/experts_touched",
-    "experts touched by a decode step, of those held, mean over the "
-    "expert layers")
-_H_PAIRS = telemetry.histogram(
-    "serving/moe/local_pairs",
-    "token-expert pairs of a decode step that fell on held experts, "
-    "summed over the expert layers")
-_H_PAIRS_MAX = telemetry.histogram(
-    "serving/moe/pairs_per_expert_max",
-    "the most pairs one expert took in a decode step, over the expert "
-    "layers")
-
 
 def _moe_stats(state):
     """The model's ``MOE_STATS_KEY`` leaves stacked ``[expert layers,
@@ -146,26 +133,19 @@ def _moe_stats(state):
 
 
 def _record_moe(stats, kind: str) -> None:
-    """One program call's expert statistics into the always-on
-    histograms (decode steps) and, with the span tracer on, into a ring
-    record that carries the call's totals (``serving/moe/step``: what a
-    traced window sums, decode and prefill alike)."""
-    if stats is None:
+    """With the span tracer on, one program call's expert statistics
+    into a ring record that carries the call's totals
+    (``serving/moe/step``: what a traced window sums, decode and prefill
+    alike). With it off, nothing leaves the device."""
+    if stats is None or not telemetry.enabled():
         return
     stats = np.asarray(stats)
-    touched, pairs, most = (float(stats[:, 0].sum()),
-                            float(stats[:, 1].sum()),
-                            float(stats[:, 2].max()))
-    if kind == "decode":
-        _H_TOUCHED.observe(touched / len(stats))
-        _H_PAIRS.observe(pairs)
-        _H_PAIRS_MAX.observe(most)
-    if telemetry.enabled():
-        telemetry.tracer().record(
-            "serving/moe/step", 0.0,
-            args={"kind": kind, "layers": len(stats),
-                  "experts_touched": touched, "local_pairs": pairs,
-                  "pairs_per_expert_max": most})
+    telemetry.tracer().record(
+        "serving/moe/step", 0.0,
+        args={"kind": kind, "layers": len(stats),
+              "experts_touched": float(stats[:, 0].sum()),
+              "local_pairs": float(stats[:, 1].sum()),
+              "pairs_per_expert_max": float(stats[:, 2].max())})
 
 
 def _record_kv(model, kv: KVCache, positions, active,
@@ -605,7 +585,15 @@ class DecodeEngine:
         ``0..c-1`` from the cache rows); each row's logits are taken
         from the chunk holding its last prompt token. ``start[i]``
         (chunk-aligned; prefix-cache seeding) skips chunks a seeded
-        prefix already wrote."""
+        prefix already wrote.
+
+        Each call's host side ends in two spans under the caller's
+        ``serving/prefill``: the wait on the device alone
+        (``device_wait``; nothing to wait for in a chunk that finishes
+        no row), then the rows' logits copied to the host and the
+        call's ring records (``logits_d2h``)."""
+        import jax
+
         n = len(prompts)
         if n == 0 or n > self.prefill_rows:
             raise ValueError(f"prefill batch of {n} rows "
@@ -649,14 +637,21 @@ class DecodeEngine:
                 logits, kv.entries, stats = prog(
                     servable.params, servable.state, kv.entries, tokens,
                     last_in, ids, offsets)
+                # the rows whose last prompt token is in this chunk, cut
+                # on the device behind the program; their copies would
+                # wait for them anyway, so the wait has a span of its own
+                done = [(i, logits[r]) for r, i in enumerate(group)
+                        if ids[r] != self.slots and (lens[i] - 1) // sq == c]
                 with telemetry.span("serving/prefill/device_wait"):
-                    for r, i in enumerate(group):
-                        if (ids[r] != self.slots
-                                and (lens[i] - 1) // sq == c):
-                            out[i] = np.asarray(logits[r])
-                _record_moe(stats, "prefill")
-                _record_prefill_kv(kv, rows, bucket, sq == bucket)
-                _record_ssm(kv, int((ids != self.slots).sum()), "prefill")
+                    # bigdl: disable=sync-in-loop
+                    jax.block_until_ready([row for _, row in done])
+                with telemetry.span("serving/prefill/logits_d2h"):
+                    for i, row in done:
+                        out[i] = np.asarray(row)
+                    _record_moe(stats, "prefill")
+                    _record_prefill_kv(kv, rows, bucket, sq == bucket)
+                    _record_ssm(kv, int((ids != self.slots).sum()),
+                                "prefill")
         for i, slot in enumerate(slot_ids):
             kv.lengths[slot] = lens[i]
         return np.stack(out), bucket

@@ -23,7 +23,6 @@ caches exist only during the overlap).
 """
 from __future__ import annotations
 
-import contextlib
 import itertools
 import threading
 import time
@@ -39,6 +38,10 @@ from bigdl_tpu.generation.sampling import Sampler, SamplingParams
 from bigdl_tpu.generation.stream import TokenStream
 from bigdl_tpu.serving.batcher import (DeadlineExceeded, QueueFull,
                                        WorkerDied)
+from bigdl_tpu.telemetry.tracer import TENURE
+
+#: a group's identity in the trace, unique in the process
+_GROUP_IDS = itertools.count(1)
 
 
 def register_generation_instruments(r) -> Dict[str, object]:
@@ -99,7 +102,7 @@ class _Gen:
     """One in-flight generation (driver-private)."""
 
     __slots__ = ("prompt", "stream", "sampler", "max_new", "deadline",
-                 "last", "produced", "slot", "prefix_entry")
+                 "last", "produced", "slot", "prefix_entry", "tenure")
 
     def __init__(self, prompt: np.ndarray, stream: TokenStream,
                  sampler: Sampler, max_new: int,
@@ -115,18 +118,54 @@ class _Gen:
         #: pinned fleet.PrefixCache entry this gen seeded from (hit
         #: path); released when the slot frees
         self.prefix_entry = None
+        #: args of its tenure record while the tracer is on: the decode
+        #: steps of its group it takes part in (``last_step`` set at
+        #: release)
+        self.tenure = None
 
 
 class _Group:
     """Live decode state pinned to ONE servable snapshot (hot-swap
     isolation: a decode batch never mixes versions)."""
 
-    __slots__ = ("servable", "kv", "gens")
+    __slots__ = ("servable", "kv", "gens", "id", "steps")
 
     def __init__(self, servable, kv: KVCache):
         self.servable = servable
         self.kv = kv
         self.gens: Dict[int, _Gen] = {}
+        #: the group's identity in the trace and its decode steps so
+        #: far: a ``serving/decode`` span carries both
+        self.id = next(_GROUP_IDS)
+        self.steps = 0
+
+
+class _Phase:
+    """The engine thread's one open top-level span: :meth:`to` closes
+    it and opens the next, so that one phase begins where the last
+    ended and together they tile the thread's time. With the tracer off
+    each is the shared no-op span."""
+
+    __slots__ = ("_model", "_name", "_span")
+
+    def __init__(self, model: str):
+        self._model = model
+        self._name = None
+        self._span = telemetry.NOOP_SPAN
+
+    def to(self, name: str, **args):
+        """Close the open phase and open ``name`` (a phase without args
+        that is already open stays open); returns its span."""
+        if name != self._name or args:
+            self._span.__exit__(None, None, None)
+            self._name = name
+            self._span = telemetry.span(name, model=self._model, **args)
+            self._span.__enter__()
+        return self._span
+
+    def end(self) -> None:
+        self._span.__exit__(None, None, None)
+        self._name, self._span = None, telemetry.NOOP_SPAN
 
 
 class DecodeLoop:
@@ -333,26 +372,41 @@ class DecodeLoop:
                     return
 
     def _loop(self) -> None:
-        while True:
-            with self._cond:
-                while (not self._queue and not self._has_live_locked()
-                       and not self._stopping):
-                    # going idle: drop drained groups NOW — a stale
-                    # post-swap cache must not stay pinned in device
-                    # memory just because traffic paused ("two caches
-                    # exist only during the overlap")
-                    self._groups.clear()
-                    with telemetry.span("serving/idle", model=self._name):
+        """One iteration: housekeep (idle while there is no work),
+        admit, prepare, then decode and sample a live group - each
+        phase a top-level span that opens where the one before closes
+        (:class:`_Phase`), so that the six tile the engine thread's
+        time."""
+        phase = _Phase(self._name)
+        try:
+            while True:
+                # serving/housekeep: from the last step's gauges through
+                # the lock, the expiry and the admission decision
+                phase.to("serving/housekeep")
+                with self._cond:
+                    while (not self._queue and not self._has_live_locked()
+                           and not self._stopping):
+                        # going idle: drop drained groups NOW — a stale
+                        # post-swap cache must not stay pinned in device
+                        # memory just because traffic paused ("two
+                        # caches exist only during the overlap")
+                        self._groups.clear()
+                        phase.to("serving/idle")
                         self._cond.wait()
-                if self._stopping:
-                    if not self._drain:
-                        self._abort_locked()
-                        return
-                    if not self._queue and not self._has_live_locked():
-                        return
-                self._expire_queued_locked(time.monotonic())
-            self._admit()
-            self._decode_step()
+                        phase.to("serving/housekeep")
+                    if self._stopping:
+                        if not self._drain:
+                            self._abort_locked()
+                            return
+                        if not self._queue and not self._has_live_locked():
+                            return
+                    self._expire_queued_locked(time.monotonic())
+                    seated = self._seat_locked(phase)
+                if seated is not None:
+                    self._admit_popped(*seated)
+                self._decode_step(phase)
+        finally:
+            phase.end()
 
     def _abort_locked(self) -> None:
         """drain=False shutdown: fail queued AND live promptly (typed),
@@ -387,45 +441,41 @@ class DecodeLoop:
             self._g_depth.set(len(self._queue), **self._labels)
 
     # ------------------------------------------------------ admission
-    def _admit(self) -> None:
-        """Admit queued requests into free slots of the CURRENT
-        version's cache — runs every step, so admission never waits
-        for the batch to drain."""
-        # the span opens under the lock, once there is something to
-        # admit, and closes after the last first-token emit, outside it
-        with contextlib.ExitStack() as admission:
-            with self._cond:
-                if not self._queue:
-                    return
-                servable = self._registry.current(self._name)
-                group = self._groups.get(servable.key)
-                if group is None:
-                    group = _Group(servable,
-                                   self._cache_provider(servable))
-                    self._groups[servable.key] = group
-                n = min(group.kv.allocator.free_count,
-                        self._engine.prefill_rows, len(self._queue))
-                if n == 0:
-                    return  # full cache queues; eviction frees slots
-                admission.enter_context(telemetry.span(
-                    "serving/admit", model=self._name, rows=n))
-                gens = [self._queue.popleft() for _ in range(n)]
-                self._g_depth.set(len(self._queue), **self._labels)
-                # enter the group BEFORE the prefill dispatch: a
-                # prefill that raises must find these gens in
-                # group.gens so the supervisor fails their streams
-                # typed instead of stranding popped-but-unprefilled
-                # requests forever
-                for g in gens:
-                    g.slot = group.kv.allocator.alloc()
-                    group.gens[g.slot] = g
-            self._admit_popped(servable, group, gens)
+    def _seat_locked(self, phase: "_Phase"):
+        """Seat queued requests in free slots of the CURRENT version's
+        cache — runs every step, so admission never waits for the batch
+        to drain. Returns ``(servable, group, gens)`` for
+        :meth:`_admit_popped`, or None when nothing fits; where
+        something does, ``phase`` moves to ``serving/admit`` here,
+        under the lock, and on after the last first-token emit."""
+        if not self._queue:
+            return None
+        servable = self._registry.current(self._name)
+        group = self._groups.get(servable.key)
+        if group is None:
+            group = _Group(servable, self._cache_provider(servable))
+            self._groups[servable.key] = group
+        n = min(group.kv.allocator.free_count,
+                self._engine.prefill_rows, len(self._queue))
+        if n == 0:
+            return None  # full cache queues; eviction frees slots
+        phase.to("serving/admit", rows=n)
+        gens = [self._queue.popleft() for _ in range(n)]
+        self._g_depth.set(len(self._queue), **self._labels)
+        # enter the group BEFORE the prefill dispatch: a prefill that
+        # raises must find these gens in group.gens so the supervisor
+        # fails their streams typed instead of stranding
+        # popped-but-unprefilled requests forever
+        for g in gens:
+            g.slot = group.kv.allocator.alloc()
+            group.gens[g.slot] = g
+        return servable, group, gens
 
     def _admit_popped(self, servable, group: _Group,
                       gens: List[_Gen]) -> None:
-        """The unlocked part of :meth:`_admit`: prefix lookups, the
-        prefill batch and the first tokens of ``gens``, already seated
-        in ``group``."""
+        """The unlocked part of admission: prefix lookups, the prefill
+        batch and the first tokens of ``gens``, already seated in
+        ``group`` by :meth:`_seat_locked`."""
         # prefix/KV reuse (bigdl_tpu.fleet.prefix): a full-prompt hit
         # seeds its slot's cache rows by device copy and goes straight
         # to decode — only the misses pay a prefill program. Under
@@ -498,7 +548,7 @@ class DecodeLoop:
         for i, g in enumerate(misses):
             self._emit(group, g, g.sampler.sample(logits[i]))
         if telemetry.enabled():
-            self._request_tracks_prefill(gens, t0, t1,
+            self._request_tracks_prefill(group, gens, t0, t1,
                                          time.monotonic())
         self._g_occupancy.set(group.kv.occupancy(), **self._labels)
 
@@ -512,14 +562,16 @@ class DecodeLoop:
             model, bucket, [int(g.prompt.shape[0]) for g in misses],
             starts)
 
-    def _request_tracks_prefill(self, gens: List[_Gen], t0: float,
-                                t1: float, t2: float) -> None:
+    def _request_tracks_prefill(self, group: _Group, gens: List[_Gen],
+                                t0: float, t1: float, t2: float) -> None:
         """Per-request trace spans for one admission: queue wait
         (submit -> prefill dispatch), the prefill itself (flow-linked
-        back to this decode thread's ``serving/prefill`` span), and
-        the first token — which the prefill program computed — so a
-        request's token count equals its ``serving/request/decode``
-        span count in the export."""
+        back to this decode thread's ``serving/prefill`` span), the
+        first token — which the prefill program computed — and the
+        request's tenure record, which the export expands into one
+        ``serving/request/decode`` event a decode step it takes part in
+        (``telemetry/tracer.py``), so a request's token count equals
+        its ``serving/request/decode`` span count in the export."""
         tr = telemetry.tracer()
         tok_dur = (t2 - t1) / max(len(gens), 1)
         for i, g in enumerate(gens):
@@ -537,13 +589,46 @@ class DecodeLoop:
                            t1 + i * tok_dur, tok_dur, tid=tid,
                            args=dict(args, token=0, phase="prefill",
                                      ttft_ms=g.stream.ttft_ms))
+            # from the group's next step on; a request its first token
+            # finished was released already and takes no step
+            g.tenure = dict(
+                args, group=group.id, first_step=group.steps + 1,
+                last_step=(None if group.gens.get(g.slot) is g
+                           else group.steps))
+            tr.record_span(TENURE, t2, 0.0, tid=tid, args=g.tenure)
 
     # ---------------------------------------------------- decode step
-    def _decode_step(self) -> None:
+    def _decode_step(self, phase: "_Phase") -> None:
+        phase.to("serving/prepare")
+        for group, live, tokens, positions, active, greedy, real in \
+                self._prepare():
+            span = phase.to("serving/decode", slots=len(live),
+                            group=group.id, step=group.steps)
+            t0 = time.monotonic()
+            logits, attend_len = self._engine.decode(
+                group.servable, group.kv, tokens, positions, active,
+                ids_only=greedy)
+            now = time.monotonic()
+            span.set_args(attend_len=attend_len)
+            phase.to("serving/sample", slots=len(live))
+            self._sample_and_emit(group, live, logits, now, greedy)
+            phase.to("serving/housekeep")
+            self._h_token.observe((now - t0) * 1000.0 / len(live),
+                                  **self._labels)
+            self._g_padding.set(real / (len(live) * attend_len),
+                                **self._labels)
+            self._g_occupancy.set(group.kv.occupancy(), **self._labels)
+
+    def _prepare(self) -> list:
+        """Every live group's next decode step, built on the host:
+        ``(group, live slots, tokens, positions, active, greedy, real
+        cached tokens)``; numbers the step (``group.steps``) and drops
+        the groups that drained."""
         with self._cond:
-            # snapshot under the lock: submit/_admit mutate the group
-            # map concurrently with this driver-thread sweep
+            # snapshot under the lock: submit/admission mutate the
+            # group map concurrently with this driver-thread sweep
             groups = list(self._groups.items())
+        steps = []
         for key, group in groups:
             if not group.gens:
                 # an old version's slots drained after a hot-swap (or
@@ -570,40 +655,16 @@ class DecodeLoop:
             # injects into (PR-5 supervision contract)
             faults.point("serving/decode", model=self._name,
                          slots=len(live))
-            t0 = time.monotonic()
             # a step whose requests are all greedy takes the program's
             # own argmax: [slots] ids cross to the host, not the
             # [slots, V] logits (sampling parameters stay operands of
             # nothing: a sampled request simply asks for the logits)
             greedy = all(group.gens[s].sampler.params.temperature <= 0.0
                          for s in live)
-            with telemetry.span("serving/decode", model=self._name,
-                                slots=len(live)):
-                logits, attend_len = self._engine.decode(
-                    group.servable, kv, tokens, positions, active,
-                    ids_only=greedy)
-            now = time.monotonic()
-            per_token_ms = (now - t0) * 1000.0 / len(live)
-            self._h_token.observe(per_token_ms, **self._labels)
-            real = int(kv.lengths[live].sum()) + len(live)
-            self._g_padding.set(real / (len(live) * attend_len),
-                                **self._labels)
-            if telemetry.enabled():
-                # one token span per live request on its own track —
-                # the per-token decode cadence of a single trace_id
-                tr = telemetry.tracer()
-                for slot in live:
-                    g = group.gens[slot]
-                    tr.record_span(
-                        "serving/request/decode", t0, now - t0,
-                        tid=tr.track(f"req {g.stream.trace_id}"),
-                        args={"trace_id": g.stream.trace_id,
-                              "model": self._name, "token": g.produced,
-                              "attend_len": attend_len})
-            with telemetry.span("serving/sample", model=self._name,
-                                slots=len(live)):
-                self._sample_and_emit(group, live, logits, now, greedy)
-            self._g_occupancy.set(group.kv.occupancy(), **self._labels)
+            group.steps += 1
+            steps.append((group, live, tokens, positions, active, greedy,
+                          int(kv.lengths[live].sum()) + len(live)))
+        return steps
 
     def _sample_and_emit(self, group: _Group, live: List[int], logits,
                          now: float, ids: bool = False) -> None:
@@ -659,6 +720,8 @@ class DecodeLoop:
         self._release(group, g)
 
     def _release(self, group: _Group, g: _Gen) -> None:
+        if g.tenure is not None:
+            g.tenure["last_step"] = group.steps
         group.gens.pop(g.slot, None)
         group.kv.lengths[g.slot] = 0
         group.kv.allocator.free(g.slot)

@@ -33,9 +33,16 @@ gap of the device be put down to what the host was doing in it. With no
 session on, the annotation is one atomic load. The ring keeps
 ``time.monotonic()``; pre-measured ``record()`` / ``record_span()``
 intervals (virtual request tracks) stay ring-only.
+
+**A request's tenure.** The decode loop writes no record a token: one
+``serving/request/tenure`` record a request (``generation/loop.py``)
+names the decode steps it took part in, and the export expands it into
+one ``serving/request/decode`` event per ``serving/decode`` span of
+those steps, on the request's track (:func:`_tenure_events`).
 """
 from __future__ import annotations
 
+import bisect
 import json
 import os
 import threading
@@ -96,6 +103,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_args(self, **args):
+        pass
+
 
 NOOP_SPAN = _NoopSpan()
 
@@ -111,25 +121,33 @@ class _Span:
         self.name = name
         self.args = args
 
+    # the clock is read first on the way in and last on the way out: the
+    # tracer's own bookkeeping is counted in the span that pays for it,
+    # so that spans which follow each other leave no gap of the
+    # tracer's making between them
     def __enter__(self):
+        self._t0 = time.monotonic()
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
         self._ann = _annotation()(self.name)
         self._ann.__enter__()
-        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        dur = time.monotonic() - self._t0
         self._ann.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
+        tid = threading.get_ident()
         self._tracer._record(SpanRecord(
-            self.name, self._t0, dur, threading.get_ident(),
+            self.name, self._t0, time.monotonic() - self._t0, tid,
             self._depth, self.args))
         return False
+
+    def set_args(self, **args):
+        """Add args known only inside the span (recorded as it ends)."""
+        self.args = dict(self.args or (), **args)
 
 
 class SpanTracer:
@@ -268,7 +286,7 @@ class SpanTracer:
             events.append({"ph": "M", "pid": pid, "tid": tid,
                            "name": "thread_name",
                            "args": {"name": tname}})
-        for s in spans:
+        for s in _tenure_events(spans):
             ev: Dict[str, Any] = {
                 "ph": "X", "pid": pid, "tid": s.tid, "name": s.name,
                 "cat": s.name.split("/")[0],
@@ -301,6 +319,43 @@ class SpanTracer:
             json.dump({"traceEvents": events,
                        "displayTimeUnit": "ms"}, f)
         return sum(1 for e in events if e["ph"] == "X")
+
+
+TENURE = "serving/request/tenure"
+
+
+def _tenure_events(spans: List[SpanRecord]):
+    """``spans`` with each ``serving/request/tenure`` record replaced by
+    the per-token events it stands for: one ``serving/request/decode``
+    on the request's track per ``serving/decode`` span of its group
+    (``args["group"]``) whose ``step`` lies in ``first_step ..
+    last_step`` (None: still decoding), with that span's ``ts`` and
+    ``dur``; the token is the step's place in the tenure, from 1 (the
+    prefill's token is 0)."""
+    steps: Dict[Any, List[SpanRecord]] = {}
+    for s in spans:
+        if s.name == "serving/decode" and s.args and "step" in s.args:
+            steps.setdefault(s.args["group"], []).append(s)
+    numbers = {}
+    for group, held in steps.items():
+        held.sort(key=lambda s: s.args["step"])
+        numbers[group] = [s.args["step"] for s in held]
+    for t in spans:
+        if t.name != TENURE:
+            yield t
+            continue
+        a = t.args
+        first, last = a["first_step"], a["last_step"]
+        nums = numbers.get(a["group"], [])
+        stop = len(nums) if last is None else bisect.bisect_right(nums,
+                                                                  last)
+        for s in steps.get(a["group"], [])[
+                bisect.bisect_left(nums, first):stop]:
+            yield SpanRecord(
+                "serving/request/decode", s.ts, s.dur, t.tid, 0,
+                {"trace_id": a["trace_id"], "model": a["model"],
+                 "token": s.args["step"] - first + 1,
+                 "attend_len": s.args.get("attend_len")})
 
 
 def _jsonable(v):

@@ -605,7 +605,9 @@ def test_the_cell_is_the_issues():
              "decode_kv_valid_share.serve",
              "decode_kv_write_in_kernel_share.serve", "ssm_decode_roofline",
              "ssm_state_gb_per_step.serve",
-             "prefill_cache_unread_share.serve")          # PR 37's
+             "prefill_cache_unread_share.serve",          # PR 37's
+             "host_launch_pct.serve", "host_emit_pct.serve",
+             "host_admit_pct.serve")                      # PR 38's
     by = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
     listed = {n for n, m in by.items() if cell["name"] in m.get(
         "workloads", ())}

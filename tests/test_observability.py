@@ -304,6 +304,85 @@ class TestRequestTracing:
         ts = [e["ts"] for e in decodes]
         assert ts == sorted(ts)
 
+    @pytest.mark.parametrize("swap", [False, True])
+    def test_the_export_gives_what_per_token_records_gave(self, swap,
+                                                           monkeypatch):
+        """The loop writes one tenure record a request, not one record
+        a token. Beside it, this run writes the records the loop wrote
+        until PR 38 - one a live slot a decode step, taken as the step's
+        ``serving/decode`` span closes, the token the request had
+        produced by then - and the export's ``serving/request/decode``
+        events equal them record for record: track, ``ts``, ``dur``,
+        token, args. With ``swap`` a hot-swap puts two groups' steps
+        under one model, and requests that end at their first token
+        take no step."""
+        import threading
+
+        from bigdl_tpu import faults
+        from bigdl_tpu.generation.loop import DecodeLoop
+        from bigdl_tpu.models import TransformerLM
+
+        sweep = DecodeLoop._sample_and_emit
+
+        def per_token_records(loop, group, live, logits, now, ids=False):
+            tr = telemetry.tracer()
+            step = [s for s in tr.spans() if s.name == "serving/decode"
+                    and s.tid == threading.get_ident()][-1]
+            for slot in live:
+                g = group.gens[slot]
+                tr.record_span(
+                    "old/request/decode", step.ts, step.dur,
+                    tid=tr.track(f"req {g.stream.trace_id}"),
+                    args={"trace_id": g.stream.trace_id,
+                          "model": loop._name, "token": g.produced,
+                          "attend_len": step.args["attend_len"]})
+            return sweep(loop, group, live, logits, now, ids)
+
+        monkeypatch.setattr(DecodeLoop, "_sample_and_emit",
+                            per_token_records)
+        telemetry.tracer().clear()
+        telemetry.enable()
+        svc = _tiny_generation_service(slots=2)
+        try:
+            with faults.armed("serving/decode=delay:5,times:1000"):
+                streams = [svc.generate("lm", np.array([1 + i, 2, 3]),
+                                        max_new_tokens=n)
+                           for i, n in enumerate((5, 1, 3, 6, 1, 2))]
+                if swap:
+                    streams[0].first(timeout=60)
+                    m2 = TransformerLM(vocab_size=64, hidden_size=32,
+                                       num_layers=1, num_heads=2,
+                                       max_len=16).evaluate()
+                    m2.ensure_initialized()
+                    svc.load("lm", m2)
+                    streams += [svc.generate("lm", np.array([9, 8]),
+                                             max_new_tokens=n)
+                                for n in (4, 1, 3)]
+                for st in streams:
+                    st.result(timeout=60)
+            events = telemetry.tracer().chrome_trace_events()
+        finally:
+            svc.shutdown()
+            telemetry.disable()
+            telemetry.tracer().clear()
+        if swap:
+            groups = {e["args"]["group"] for e in events
+                      if e["name"] == "serving/decode"}
+            assert len(groups) == 2
+
+        def key(e):
+            return (e["tid"], e["ts"], e["dur"], e["cat"],
+                    tuple(sorted(e["args"].items())))
+
+        new = sorted(key(e) for e in events
+                     if e["name"] == "serving/request/decode"
+                     and "phase" not in e["args"])
+        old = sorted(key(e) for e in events
+                     if e["name"] == "old/request/decode")
+        assert new == [(t, ts, d, "serving", a)
+                       for t, ts, d, _, a in old]
+        assert len(new) == sum(len(st.tokens()) - 1 for st in streams)
+
     def test_microbatcher_trace_id_on_future_and_track(self):
         """MicroBatcher.submit assigns a trace_id carried to the
         response future; with tracing on the request's queue wait and

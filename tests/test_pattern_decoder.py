@@ -199,12 +199,13 @@ def test_generation_service_serves_it_and_records_the_expert_counts(cut):
     """``load`` / ``generate`` with nothing but slots, ``max_len`` and
     the ladder: the cache's kinds and dtype come from the model, the
     served tokens are the reference's best (``ref_token_gaps`` reads 0),
-    and every decode step leaves the expert counts in the always-on
-    instruments and the cache's bytes by kind in the gauges."""
+    every decode step leaves the expert counts in a ``serving/moe/step``
+    ring record while the tracer is on (no always-on instrument holds
+    them), and the cache's bytes by kind are in the gauges."""
     cfg, model = cut
     reg = telemetry.registry()
-    before = len(reg.get("serving/moe/experts_touched").samples())
-    before_max = len(reg.get("serving/moe/pairs_per_expert_max").samples())
+    telemetry.tracer().clear()
+    telemetry.enable()
     svc = GenerationService(config=GenerationConfig(
         slots=4, max_len=32, length_buckets=[8, 16, 32],
         max_new_tokens=16))
@@ -218,20 +219,34 @@ def test_generation_service_serves_it_and_records_the_expert_counts(cut):
                 "lm", p, max_new_tokens=new).result(120))))
     finally:
         svc.shutdown()
+        telemetry.disable()
+    steps = [s.args for s in telemetry.tracer().spans()
+             if s.name == "serving/moe/step" and s.args["kind"] == "decode"]
+    telemetry.tracer().clear()
     served, _ = afmoe.ref_token_gaps(
         dict(cfg, reference={"route_tie_margin": 1e-5}), SEED, rows)
     assert sum(len(g) for g in served) >= 30
     assert max(float(g.max()) for g in served) <= ATOL
-    touched = reg.get("serving/moe/experts_touched").samples()[before:]
-    assert len(touched) == 9 + 13 + 9        # one a decode step
-    assert all(0 < t <= 4 for t in touched)
+    assert len(steps) == 9 + 13 + 9        # one a decode step
+    assert all(0 < a["experts_touched"] / a["layers"] <= 4 for a in steps)
     # one request decodes at a time here: 2 pairs a step, on 2 experts
-    assert max(reg.get("serving/moe/pairs_per_expert_max")
-               .samples()[before_max:]) <= 1
+    assert max(a["pairs_per_expert_max"] for a in steps) <= 1
+    assert not [n for n in reg.names() if n.startswith("serving/moe/")]
     assert reg.get("serving/cache/window_bytes").value(model="lm") \
         == 4 * 2 * 4 * 2 * 16 * 8 * 4
     assert reg.get("serving/cache/global_bytes").value(model="lm") \
         == 2 * 4 * 2 * 16 * 32 * 4
+
+
+def test_expert_counts_stay_on_the_device_while_the_tracer_is_off():
+    from bigdl_tpu.generation.engine import _record_moe
+
+    class OnTheDevice:
+        def __array__(self, *args, **kwargs):
+            raise AssertionError("the expert counts were copied")
+
+    assert not telemetry.enabled()
+    _record_moe(OnTheDevice(), "decode")
 
 
 def test_the_prefix_cache_refuses_entries_of_several_kinds(cut):

@@ -91,10 +91,14 @@ class TestSpanTracer:
 
     def test_nesting_preserved_across_threads(self):
         tr = SpanTracer()
+        # both threads live at once: a thread that ended before the
+        # other started would hand it its ident, and so its track
+        both = threading.Barrier(2)
 
         def work(tag):
             with tr.span(f"worker/{tag}/outer", None):
                 with tr.span(f"worker/{tag}/inner", None):
+                    both.wait(timeout=30)
                     time.sleep(0.002)
 
         threads = [threading.Thread(target=work, args=(t,),

@@ -24,8 +24,8 @@ from bigdl_tpu.utils.random import RandomGenerator
 #: docs/telemetry.md "Scopes": by role, never by layer index
 SCOPES = ("embed", "attn/qkv", "attn/core", "attn/out", "attn/kv_write",
           "mlp", "norm", "lm_head", "loss", "optim_update")
-LOOP_SPANS = ("serving/idle", "serving/admit", "serving/decode",
-              "serving/sample")
+LOOP_SPANS = ("serving/idle", "serving/housekeep", "serving/admit",
+              "serving/prepare", "serving/decode", "serving/sample")
 STEP_CHILDREN = ("serving/decode/dispatch", "serving/decode/device_wait",
                  "serving/decode/logits_d2h")
 
@@ -93,10 +93,12 @@ def _inside(child, parent):
 
 
 @pytest.mark.parametrize("span,parent", [
-    ("serving/idle", None), ("serving/admit", None),
+    ("serving/idle", None), ("serving/housekeep", None),
+    ("serving/admit", None),
     ("serving/prefill", "serving/admit"),
     ("serving/prefill/device_wait", "serving/prefill"),
-    ("serving/decode", None),
+    ("serving/prefill/logits_d2h", "serving/prefill"),
+    ("serving/prepare", None), ("serving/decode", None),
     ("serving/decode/dispatch", "serving/decode"),
     ("serving/decode/device_wait", "serving/decode"),
     ("serving/decode/logits_d2h", "serving/decode"),
@@ -139,18 +141,34 @@ def _loop_coverage(spans):
     return covered / (last - first)
 
 
-def test_four_spans_tile_the_engine_threads_time(traced_serve):
-    """idle + admit + decode + sample leave under 5% of the thread's
-    time between its first and last span unnamed. A busy machine takes
-    the thread off the CPU between two spans and can only lower the
-    reading, so the best of three runs is judged."""
+def test_six_spans_tile_the_engine_threads_time(traced_serve):
+    """idle + housekeep + admit + prepare + decode + sample leave under
+    1% of the thread's time between its first and last span unnamed. A
+    busy machine takes the thread off the CPU between two spans and can
+    only lower the reading, so the best of three runs is judged."""
     best = _loop_coverage(traced_serve[0])
     for _ in range(2):
-        if best >= 0.95:
+        if best >= 0.99:
             break
         best = max(best, _loop_coverage(_traced_serve()[0]))
-    assert best >= 0.95, (
-        f"the four spans cover {best:.1%} of the engine thread's time")
+    assert best >= 0.99, (
+        f"the six spans cover {best:.1%} of the engine thread's time")
+
+
+def test_the_ring_holds_no_record_a_token(traced_serve):
+    """A request leaves its token-0 record and one tenure record, never
+    one a decode step; the decode span carries what the export needs
+    to expand the tenure (``step``, ``group``, ``attend_len``)."""
+    spans, _ = traced_serve
+    requests = sum(s.name == "serving/request/queue_wait" for s in spans)
+    assert requests == 7
+    assert sum(s.name == "serving/request/decode" for s in spans) \
+        == requests
+    assert sum(s.name == "serving/request/tenure" for s in spans) \
+        == requests
+    for s in spans:
+        if s.name == "serving/decode":
+            assert {"step", "group", "attend_len"} <= set(s.args)
 
 
 def test_disabled_the_same_run_records_nothing():
@@ -565,3 +583,36 @@ def test_a_traced_window_observes_the_attention_share(config, share):
     assert hist.count() == before + 1
     assert hist.samples()[-1] == share
 
+
+
+# ------------------------------------- the benchmark's reader of phases
+
+_OF = ["serving/idle", "serving/housekeep", "serving/admit",
+       "serving/prepare", "serving/decode", "serving/sample"]
+_PHASES = {"serving/idle": [0.5], "serving/housekeep": [0.01] * 10,
+           "serving/admit": [0.05] * 4, "serving/prepare": [0.02] * 10,
+           "serving/decode": [0.2] * 10, "serving/sample": [0.03] * 10,
+           "serving/decode/dispatch": [0.01] * 10,
+           "serving/prefill/device_wait": [0.03] * 4}   # whole: 3.3 s
+
+
+@pytest.mark.parametrize("spans,plus,minus,want", [
+    (_PHASES, ["serving/prepare", "serving/decode/dispatch"], (),
+     100 * 0.3 / 3.3),
+    (_PHASES, ["serving/admit"], ["serving/prefill/device_wait"],
+     100 * (0.2 - 0.12) / 3.3),
+    # the parent commit: no serving/prepare span, so no launch share
+    ({k: v for k, v in _PHASES.items() if k != "serving/prepare"},
+     ["serving/prepare", "serving/decode/dispatch"], (), None),
+    # nothing of the whole on record
+    ({"serving/decode/dispatch": [0.01]}, ["serving/decode/dispatch"],
+     (), None)], ids=["plus", "minus", "no-plus", "no-whole"])
+def test_span_balance_pct_reads_a_phase_of_the_tiling(spans, plus, minus,
+                                                      want):
+    import types
+
+    from benchmarks.readers import span_balance_pct
+
+    ctx = types.SimpleNamespace(spans=spans)
+    got = span_balance_pct.read(ctx, plus, _OF, minus)
+    assert got == (None if want is None else pytest.approx(want))
